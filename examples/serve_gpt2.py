@@ -104,6 +104,7 @@ def main(argv=None) -> int:
     import jax
     import jax.numpy as jnp
 
+    from pytorch_distributed_tpu.compile_cache import enable_compile_cache
     from pytorch_distributed_tpu.models import GPT2, GPT2Config
     from pytorch_distributed_tpu.serving import (
         InferenceEngine,
@@ -115,6 +116,7 @@ def main(argv=None) -> int:
         serving_mesh,
     )
 
+    enable_compile_cache()
     on_tpu = jax.devices()[0].platform == "tpu"
     cfg = GPT2Config(
         vocab_size=args.vocab,

@@ -48,10 +48,12 @@ def parse_args(argv=None):
                         "little-endian uint16 tokens); default synthetic")
     p.add_argument("--num-workers", type=int, default=0,
                    help="DataLoader worker processes")
-    p.add_argument("--mp-context", default="fork",
+    p.add_argument("--mp-context", default="spawn",
                    choices=["fork", "spawn"],
-                   help="worker start method; use spawn when jax/libtpu "
-                        "initialized before loading (fork-safety)")
+                   help="worker start method. This script initialises JAX "
+                        "before it builds the loader, so the default is "
+                        "spawn: fork()ing a process that holds the chip "
+                        "copies its runtime threads' locks into the child")
     p.add_argument("--chunked-loss", type=int, default=0, metavar="N",
                    help="use the vocab-chunked CE with N chunks (memory "
                         "path: long-T / big-V / B beyond the dense-loss "
@@ -82,6 +84,7 @@ def main(argv=None) -> int:
 
     import pytorch_distributed_tpu as ptd
     from pytorch_distributed_tpu.checkpoint import CheckpointManager
+    from pytorch_distributed_tpu.compile_cache import enable_compile_cache
     from pytorch_distributed_tpu.data import (
         DataLoader,
         DistributedSampler,
@@ -92,6 +95,7 @@ def main(argv=None) -> int:
     from pytorch_distributed_tpu.parallel import FullyShardedDataParallel
     from pytorch_distributed_tpu.trainer import Trainer, lm_loss
 
+    enable_compile_cache()
     nproc = jax.process_count()
     pid = jax.process_index()
     restart_count = int(os.environ.get("TPURUN_RESTART_COUNT", "0"))

@@ -57,10 +57,12 @@ def parse_args(argv=None):
                         "real decode+augment path; default is synthetic")
     p.add_argument("--num-workers", type=int, default=0,
                    help="DataLoader worker processes (JPEG decode)")
-    p.add_argument("--mp-context", default="fork",
+    p.add_argument("--mp-context", default="spawn",
                    choices=["fork", "spawn"],
-                   help="worker start method; use spawn when jax/libtpu "
-                        "initialized before loading (fork-safety)")
+                   help="worker start method. This script initialises JAX "
+                        "before it builds the loader, so the default is "
+                        "spawn: fork()ing a process that holds the chip "
+                        "copies its runtime threads' locks into the child")
     p.add_argument("--log-every", type=int, default=10)
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--prefetch", type=int, default=2,
@@ -93,6 +95,7 @@ def main(argv=None) -> int:
 
     import pytorch_distributed_tpu as ptd
     from pytorch_distributed_tpu.checkpoint import CheckpointManager
+    from pytorch_distributed_tpu.compile_cache import enable_compile_cache
     from pytorch_distributed_tpu.data import (
         DataLoader,
         DistributedSampler,
@@ -105,11 +108,15 @@ def main(argv=None) -> int:
     from pytorch_distributed_tpu.parallel import DataParallel
     from pytorch_distributed_tpu.trainer import Trainer, classification_loss
 
+    enable_compile_cache()
     nproc = jax.process_count()
     pid = jax.process_index()
     restart_count = int(os.environ.get("TPURUN_RESTART_COUNT", "0"))
 
     mesh = ptd.init_device_mesh((len(jax.devices()),), ("dp",))
+    print(f"[rank {pid}] {nproc} process(es), {len(jax.devices())} "
+          f"{jax.devices()[0].platform} device(s), local "
+          f"{[d.id for d in jax.local_devices()]}", flush=True)
 
     on_tpu = jax.devices()[0].platform == "tpu"
     dtype = jnp.bfloat16 if (on_tpu and args.policy != "fp32") else jnp.float32

@@ -5,14 +5,21 @@ engine + TCP server/client; flightrecorder.cpp: collective ring buffer). They
 compile to one shared library, ``_lib/libtpudist.so``, loaded via ctypes (no
 pybind11 in the image — SURVEY.md environment notes).
 
-Build is on-demand and cached by source mtime; a lock file serializes
-concurrent builders (multi-process test runs).
+Build is on-demand. Freshness is decided from the CONTENT of
+``native/*.cpp``: a sha256 of the sources is stored beside the library
+(``libtpudist.so.sha256``) and the library is loaded only when that stamp
+matches the sources on disk. File times say nothing here — ``_lib/`` is
+ignored by git, so a checkout copied to another machine can carry a
+library built from other sources with any timestamps. A lock file
+serializes concurrent builders (multi-process test runs).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import shutil
 import subprocess
 import tempfile
 from pathlib import Path
@@ -23,17 +30,25 @@ _REPO_ROOT = _PKG_DIR.parent
 _SRC_DIR = _REPO_ROOT / "native"
 _LIB_DIR = _PKG_DIR / "_lib"
 _LIB_PATH = _LIB_DIR / "libtpudist.so"
+_STAMP_PATH = _LIB_DIR / "libtpudist.so.sha256"
 
 _lib: Optional[ctypes.CDLL] = None
 
 
+def _source_digest() -> str:
+    h = hashlib.sha256()
+    for src in sorted(_SRC_DIR.glob("*.cpp")):
+        h.update(src.name.encode())
+        h.update(b"\0")
+        h.update(src.read_bytes())
+        h.update(b"\0")
+    return h.hexdigest()
+
+
 def _needs_build() -> bool:
-    if not _LIB_PATH.exists():
+    if not (_LIB_PATH.exists() and _STAMP_PATH.exists()):
         return True
-    lib_mtime = _LIB_PATH.stat().st_mtime
-    return any(
-        src.stat().st_mtime > lib_mtime for src in _SRC_DIR.glob("*.cpp")
-    )
+    return _STAMP_PATH.read_text().strip() != _source_digest()
 
 
 def build(force: bool = False) -> Path:
@@ -44,6 +59,14 @@ def build(force: bool = False) -> Path:
     sources = sorted(str(p) for p in _SRC_DIR.glob("*.cpp"))
     if not sources:
         raise FileNotFoundError(f"no C++ sources under {_SRC_DIR}")
+    if shutil.which("g++") is None:
+        raise RuntimeError(
+            "the native runtime (TCPStore, native backend, flight recorder "
+            "— what tpurun and the eager process groups use) must be "
+            f"compiled from {_SRC_DIR} and no `g++` is on PATH; install a "
+            "C++17 compiler. The single-process train and serve paths do "
+            "not need it."
+        )
     lock = _LIB_DIR / ".build.lock"
     import fcntl
 
@@ -52,6 +75,7 @@ def build(force: bool = False) -> Path:
         try:
             if not force and not _needs_build():  # built while we waited
                 return _LIB_PATH
+            digest = _source_digest()
             with tempfile.NamedTemporaryFile(
                 suffix=".so", dir=_LIB_DIR, delete=False
             ) as tmp:
@@ -60,8 +84,18 @@ def build(force: bool = False) -> Path:
                 "g++", "-std=c++17", "-O2", "-fPIC", "-shared", "-pthread",
                 "-Wall", "-o", tmp_path, *sources,
             ]
-            subprocess.run(cmd, check=True, capture_output=True, text=True)
-            os.replace(tmp_path, _LIB_PATH)  # atomic publish
+            try:
+                subprocess.run(
+                    cmd, check=True, capture_output=True, text=True
+                )
+                # stamp last: a crash between the two leaves a mismatch,
+                # which rebuilds — never a stamp vouching for the wrong
+                # library
+                os.replace(tmp_path, _LIB_PATH)  # atomic publish
+                _STAMP_PATH.write_text(digest + "\n")
+            finally:
+                if os.path.exists(tmp_path):  # the build did not publish
+                    os.unlink(tmp_path)
         except subprocess.CalledProcessError as e:
             raise RuntimeError(
                 f"native build failed:\n{e.stderr}"

@@ -286,7 +286,7 @@ def runner_audit(
             f"{runner.dispatch_count} program dispatches for {submits} "
             f"submits — the step is not one fused program",
         ))
-    if runner.executable_count not in (1, -1):
+    if runner.executable_count != 1:
         findings.append(_finding(
             "ir-program-count", name,
             f"{runner.executable_count} compiled executables behind the "

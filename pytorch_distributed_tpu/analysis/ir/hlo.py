@@ -87,10 +87,14 @@ class CollectiveOp:
 
 # `%name = <result-type> all-reduce(...)`; result-type is one
 # `dtype[dims]{layout}` or a tuple of them for -start variants and
-# variadic (combined) collectives
+# variadic (combined) collectives. TPU layouts carry their own
+# parentheses (`f32[64]{0:T(128)S(1)}`), so the tuple form admits one
+# nested level — without it every combined collective of a TPU module
+# goes uncounted.
 _SHAPE = re.compile(r"([a-z]\d*[a-z0-9]*)\[([\d,]*)\]")
 _INSTR = re.compile(
-    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\([^)]*\)|\S+)\s+"
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*"
+    r"(\((?:[^()]|\([^()]*\))*\)|\S+)\s+"
     r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
     r"(-start)?\("
 )

@@ -122,9 +122,12 @@ class DataLoader:
         self.prefetch_factor = int(prefetch_factor)
         #: worker processes for __getitem__+collate (0 = in-process). The
         #: default "fork" context lets datasets/transforms be closures;
-        #: "spawn" needs them picklable. Keep workers numpy/PIL-only —
-        #: forking after heavy jax/XLA use is the usual fork-safety caveat
-        #: (same as torch's CUDA-and-fork rule).
+        #: "spawn" needs them picklable. Workers are numpy/PIL-only and must
+        #: never touch JAX: the chip belongs to the parent. A process whose
+        #: JAX backend is already live (it holds the chip and its runtime
+        #: threads) passes mp_context="spawn" — the example mains and the
+        #: from-disk benchmark do — because fork() copies those threads'
+        #: locks into the child.
         self.num_workers = int(num_workers)
         self.mp_context = mp_context
         self._epoch = 0

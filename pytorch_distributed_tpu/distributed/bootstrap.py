@@ -39,6 +39,35 @@ def is_jax_distributed_initialized() -> bool:
     return _initialized
 
 
+#: how libtpu lays N one-chip processes over the chips of one host
+_TPU_PROCESS_BOUNDS = {2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+
+
+def _pin_tpu_chip(local_rank: int, local_world_size: int) -> None:
+    """One chip per co-hosted process, and the processes wired back into
+    one slice: libtpu's multi-process-per-host environment. Visibility
+    alone (``TPU_VISIBLE_CHIPS``) gives each process a chip but no ICI
+    peers; the bounds, addresses and task id let the N runtimes find each
+    other. Must be in the environment before the backend initializes;
+    ``setdefault`` respects an operator's explicit topology. Ports follow
+    MASTER_PORT so that every worker derives the same list."""
+    env = {"TPU_VISIBLE_CHIPS": str(local_rank)}
+    bounds = _TPU_PROCESS_BOUNDS.get(local_world_size)
+    if bounds is not None:  # another count: visibility only, as before
+        base = int(os.environ["MASTER_PORT"]) + 16
+        env.update({
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": bounds,
+            "TPU_PROCESS_ADDRESSES": ",".join(
+                f"localhost:{base + i}" for i in range(local_world_size)
+            ),
+            "TPU_PROCESS_PORT": str(base + local_rank),
+            "CLOUD_TPU_TASK_ID": str(local_rank),
+        })
+    for key, value in env.items():
+        os.environ.setdefault(key, value)
+
+
 def initialize_jax_distributed(
     coordinator_address: Optional[str] = None,
     num_processes: Optional[int] = None,
@@ -76,19 +105,13 @@ def initialize_jax_distributed(
     local_ws = int(os.environ.get("LOCAL_WORLD_SIZE", "1"))
     if local_ws > 1 and "LOCAL_RANK" in os.environ:
         # Co-hosted workers (tpurun nproc-per-node > 1): each process must
-        # pin its LOCAL_RANK-th accelerator, else every process claims all
-        # local chips (libtpu device-already-in-use). Two mechanisms:
-        #   * local_device_ids — honored by the CUDA backend;
-        #   * TPU_VISIBLE_CHIPS — libtpu's own visibility knob (must be in
-        #     the env before the backend initializes; setdefault respects
-        #     an operator's explicit topology config, and dense multi-chip
-        #     topologies may additionally need the TPU_PROCESS_* family —
-        #     see libtpu docs).
-        # The CPU backend ignores both, harmlessly: its virtual devices
-        # are private per process, so there is no contention to avoid.
+        # take its LOCAL_RANK-th accelerator, else every process claims all
+        # local chips. local_device_ids is what the CUDA backend honors;
+        # libtpu reads its own environment (below). The CPU backend ignores
+        # both, harmlessly: its virtual devices are private per process.
         if local_device_ids is None:
             local_device_ids = [int(os.environ["LOCAL_RANK"])]
-        os.environ.setdefault("TPU_VISIBLE_CHIPS", os.environ["LOCAL_RANK"])
+        _pin_tpu_chip(int(os.environ["LOCAL_RANK"]), local_ws)
     if local_device_ids is not None:
         kwargs["local_device_ids"] = list(local_device_ids)
     jax.distributed.initialize(

@@ -191,21 +191,31 @@ class SubMesh:
 def _topology_aware_devices(
     mesh_shape: tuple, devices=None, *, allow_split_physical_axes: bool = False
 ) -> np.ndarray:
-    """ICI-topology-aware device placement (mesh_utils when shapes allow)."""
+    """ICI-topology-aware device placement (mesh_utils when shapes allow).
+
+    Linear device order is the fallback for exactly the two ways
+    ``mesh_utils`` says "this logical mesh does not map onto this physical
+    torus": ``NotImplementedError`` (an axis size that is no product of
+    physical axis sizes) and ``AssertionError`` (a device subset that is
+    not a box of the torus, e.g. chips 1 and 2 of a 2x2). Anything else —
+    a backend that failed, a wrong argument — propagates. A whole v5e 2x2
+    host and its one-chip subset place without the fallback (checked on
+    the chip by ``chip_smoke.py --chips 4``, which makes this warning an
+    error)."""
+    from jax.experimental import mesh_utils
+
     if devices is None:
         devices = jax.devices()
     n = math.prod(mesh_shape)
     if n != len(devices):
         raise ValueError(f"mesh of {n} devices but {len(devices)} available")
     try:
-        from jax.experimental import mesh_utils
-
         return mesh_utils.create_device_mesh(
             mesh_shape,
             devices=devices,
             allow_split_physical_axes=allow_split_physical_axes,
         )
-    except Exception as e:  # pragma: no cover - depends on physical topology
+    except (NotImplementedError, AssertionError) as e:
         warnings.warn(
             f"topology-aware mesh placement failed ({e}); falling back to "
             "linear device order — ICI locality may be suboptimal",

@@ -24,9 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from pytorch_distributed_tpu._compat import shard_map as _shard_map
-from pytorch_distributed_tpu._compat import axis_size as _axis_size
-
 from pytorch_distributed_tpu.mesh import DeviceMesh, SubMesh
 
 AxisLike = Union[str, Sequence[str]]
@@ -67,9 +64,9 @@ def axis_size(axis) -> int:
     if isinstance(a, tuple):
         out = 1
         for name in a:
-            out *= _axis_size(name)
+            out *= lax.axis_size(name)
         return out
-    return _axis_size(a)
+    return lax.axis_size(a)
 
 
 def all_reduce(x, axis, op: str = "sum"):
@@ -147,7 +144,7 @@ def send_to(x, axis, *, dst_offset: int = 1):
     (P2P send/recv analog — torch ``send:2713/recv:2757`` — expressed as the
     SPMD ppermute pattern)."""
     a = _axis(axis)
-    n = _axis_size(a)
+    n = lax.axis_size(a)
     return lax.ppermute(x, a, perm=_ring_perm(n, dst_offset))
 
 
@@ -156,7 +153,7 @@ def recv_from(x, axis, *, src_offset: int = 1):
     the mirror of :func:`send_to` (``recv_from(src_offset=k)`` receives what
     ``send_to(dst_offset=-k)`` delivers)."""
     a = _axis(axis)
-    n = _axis_size(a)
+    n = lax.axis_size(a)
     return lax.ppermute(x, a, perm=_ring_perm(n, -src_offset))
 
 
@@ -171,6 +168,6 @@ def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
     """``jax.shard_map`` accepting a DeviceMesh (per-device SPMD regions where
     the collectives above are used)."""
     m = mesh.jax_mesh if isinstance(mesh, DeviceMesh) else mesh
-    return _shard_map(
+    return jax.shard_map(
         f, mesh=m, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
     )

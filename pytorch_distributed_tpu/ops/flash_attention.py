@@ -36,18 +36,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from pytorch_distributed_tpu._compat import pallas_compiler_params as _compiler_params
-
 __all__ = ["flash_attention", "flash_attention_with_lse"]
 
 _NEG_INF = -1e30
 
 
 def _interpret_default() -> bool:
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:  # backend not initialized yet
-        return True
+    # a backend that fails to initialise raises here: it must never turn
+    # the kernel into the interpreter
+    return jax.devices()[0].platform != "tpu"
 
 
 def _fit_block(t: int, want: int) -> int:
@@ -282,7 +279,7 @@ def _fwd_pruned(q, k, v, *, block_q, block_k, interpret, out_dtype=None):
             jax.ShapeDtypeStruct((B, H, Tq, D), out_dtype or q.dtype),
             jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -425,7 +422,7 @@ def _bwd_pruned(q, k, v, out, lse, do, *, block_q, block_k, interpret):
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -450,7 +447,7 @@ def _bwd_pruned(q, k, v, out, lse, do, *, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((B, H, Tk, D), k.dtype),
             jax.ShapeDtypeStruct((B, H, Tk, D), v.dtype),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -510,7 +507,7 @@ def _fwd(q, k, v, q_pos, kv_pos, *, block_q, block_k, interpret,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -666,7 +663,7 @@ def _bwd(q, k, v, q_pos, kv_pos, out, lse, do, *, block_q, block_k,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -696,7 +693,7 @@ def _bwd(q, k, v, q_pos, kv_pos, out, lse, do, *, block_q, block_k,
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

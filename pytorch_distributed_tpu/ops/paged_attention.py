@@ -15,13 +15,17 @@ Two implementations share one contract:
   the block table, gathers the referenced pages into a dense ``[B, S, H,
   D]`` view and runs exactly the slotted op's einsum/mask/softmax, so the
   paged path is bit-identical to ``cached_attention`` whenever the page
-  chain covers the same positions. Import-light (no Pallas) — this is the
-  CPU tier-1 path and the prefill path.
+  chain covers the same positions. Import-light (no Pallas). This is what
+  the serving path runs today on EVERY platform, prefill and decode alike:
+  ``models/gpt2.py`` calls it for any paged cache.
 * ``paged_decode_attention`` — Pallas TPU kernel for the T=1 decode step
   that gathers pages *in-kernel* via scalar-prefetched block tables (one
   grid step per table entry, online softmax across pages), so decode never
-  materializes the dense gather in HBM. Lazy-exported from ops like the
-  flash kernels; Pallas imports happen inside the function.
+  materializes the dense gather in HBM. NOT wired into the model or the
+  engine yet: only tests/test_paging.py (interpret-mode parity) and
+  tests/test_chip_compile.py (Mosaic compile for a described v5e) call
+  it. Lazy-exported from ops like the flash kernels; Pallas imports happen
+  inside the function.
 
 Trash-page invariant: page id 0 is reserved by serving.paging and never
 allocated. Evicted / inactive slots have an all-zero table row, so their
@@ -119,10 +123,9 @@ def paged_cached_attention(
 # Pallas decode kernel: in-kernel gather through the block table
 # -------------------------------------------------------------------------
 def _interpret_default() -> bool:
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:  # backend not initialized yet
-        return True
+    # a backend that fails to initialise raises here: it must never turn
+    # the kernel into the interpreter
+    return jax.devices()[0].platform != "tpu"
 
 
 def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -207,10 +210,6 @@ def paged_decode_attention(
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from pytorch_distributed_tpu._compat import (
-        pallas_compiler_params as _compiler_params,
-    )
-
     B, T, H, D = q.shape
     if T != 1:
         raise ValueError(f"paged_decode_attention is decode-only (T=1), got T={T}")
@@ -258,7 +257,7 @@ def paged_decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -39,8 +39,6 @@ import jax.numpy as jnp
 import jax.tree_util as jtu
 from jax import lax
 
-from pytorch_distributed_tpu._compat import axis_size as _axis_size
-
 __all__ = [
     "allreduce_hook",
     "bf16_compress",
@@ -85,7 +83,7 @@ def _make_bucketed_hook(cap_bytes: int, reduce_flat):
     scatter the result back into leaf shapes."""
 
     def hook(grads, axis_name: str):
-        n = _axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         leaves, treedef = jtu.tree_flatten(grads)
         synced: list = [None] * len(leaves)
 

@@ -80,8 +80,6 @@ import jax.tree_util as jtu
 from jax import lax
 from jax.sharding import PartitionSpec
 
-from pytorch_distributed_tpu._compat import shard_map as _shard_map
-
 from pytorch_distributed_tpu.mesh import DeviceMesh
 
 P = PartitionSpec
@@ -219,7 +217,7 @@ def gpipe_spmd(
     )
     param_spec = P(axis)  # leading stage dim sharded (prefix over the pytree)
     if with_rng:
-        rng_runner = _shard_map(
+        rng_runner = jax.shard_map(
             per_device,
             mesh=jmesh,
             in_specs=(param_spec, mb_spec, P()),
@@ -233,7 +231,7 @@ def gpipe_spmd(
 
         return run
 
-    runner = _shard_map(
+    runner = jax.shard_map(
         functools.partial(per_device, rng=None),
         mesh=jmesh,
         in_specs=(param_spec, mb_spec),

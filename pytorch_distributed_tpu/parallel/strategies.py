@@ -85,7 +85,7 @@ def _shard_largest_divisible_dim(
     shape: Tuple[int, ...], axis_name: str, axis_size: int, min_size: int
 ) -> PartitionSpec:
     """Spec sharding the largest dim divisible by ``axis_size`` (else
-    replicate); see ``shard_spec_with_reason`` for the fallback taxonomy."""
+    replicate); see ``shard_spec_with_reason`` for the named fallbacks."""
     return shard_spec_with_reason(shape, axis_name, axis_size, min_size)[0]
 
 

@@ -1,9 +1,9 @@
 """Pipelined step execution — keep the device queue full.
 
-BENCH_r03–r05 pinned the training gap (ROADMAP item 1): XLA delivers
-~48 ms of pipelined compute per ResNet-50 step but the measured step was
-~164 ms, with ~115 ms of ``blocking_extra_ms`` from host dispatch and the
-per-step ``float(metrics["loss"])`` sync that closes each step. The fix
+A loop that reads ``float(metrics["loss"])`` every step makes the host
+wait for the device and the device wait for the host: one dispatch +
+fetch round trip per step (its size is not measured on the current
+machine; ROADMAP item 1). The fix
 is structural, not a kernel: never put a device→host read on the hot
 path. :class:`AsyncRunner` composes the trainer's raw step with an
 on-device :class:`MetricRing` so the jitted program itself accumulates

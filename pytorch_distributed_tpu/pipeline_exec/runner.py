@@ -114,14 +114,10 @@ class AsyncRunner:
         """Distinct compiled executables behind the pipelined step (the
         jit cache size). 1 after any number of same-shape submits; a
         second entry is a recompile hazard the structural audit flags.
-        -1 when unknown (no pstep yet, or the jit wrapper stopped
-        exposing its cache size)."""
+        0 before the first :meth:`start`."""
         if self._pstep is None:
             return 0
-        try:
-            return int(self._pstep._cache_size())
-        except AttributeError:
-            return -1
+        return int(self._pstep._cache_size())
 
     @property
     def sharded_update(self) -> bool:

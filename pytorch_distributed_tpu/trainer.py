@@ -44,8 +44,6 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec
 
-from pytorch_distributed_tpu._compat import shard_map as _shard_map
-
 from pytorch_distributed_tpu.amp import GradScaler, Policy, get_policy
 from pytorch_distributed_tpu.data.sharding import shard_batch_for_mesh
 from pytorch_distributed_tpu.parallel import (
@@ -462,7 +460,7 @@ class Trainer:
                 )
             else:
                 comm_spec = P()
-            compute = _shard_map(
+            compute = jax.shard_map(
                 hooked, mesh=mesh,
                 in_specs=(P(), P(), batch_spec, P(), P(), comm_spec, P()),
                 out_specs=(P(), P(), P(), P(), comm_spec),

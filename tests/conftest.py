@@ -11,6 +11,11 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+# The suite never uses the persistent compilation cache: entry points turn
+# it on (compile_cache.enable_compile_cache), and the example scripts the
+# tests start inherit this environment. Set before jax is imported.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+
 from __graft_entry__ import _provision_virtual_devices  # noqa: E402
 
 _provision_virtual_devices(8)

@@ -10,7 +10,7 @@ import optax
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from pytorch_distributed_tpu._compat import shard_map
+from jax import shard_map
 
 import pytorch_distributed_tpu as ptd
 from pytorch_distributed_tpu.data import DataLoader, pad_batch

@@ -71,6 +71,25 @@ def test_collective_inventory_families_and_bytes():
     assert "all-reduce f32[256,10]" in ar.describe()
 
 
+def test_collective_inventory_reads_tpu_tiled_tuple_layouts():
+    """Lines as the TPU compiler prints them (from the ResNet-50 DP step
+    compiled for a described v5e:2x2): a combined all-reduce has a TUPLE
+    result whose tiled layouts carry their own parentheses. A tuple
+    pattern that stops at the first ``)`` counts none of them."""
+    text = textwrap.dedent("""\
+      %all-reduce.415 = (f32[64]{0:T(128)S(1)}, f32[64]{0:T(128)S(1)}) all-reduce(%gte.1372, %gte.1371), channel_id=2, replica_groups=[1,4]<=[4], use_global_device_ids=true, to_apply=%region_1.0.clone
+      %all-reduce.7 = bf16[7,7,3,64]{3,2,1,0:T(8,128)(2,1)} all-reduce(%fusion.9), channel_id=9, replica_groups=[1,4]<=[4], to_apply=%add
+      %ag = (bf16[192,768]{1,0:T(8,128)(2,1)}, bf16[768,768]{1,0:T(8,128)(2,1)}) all-gather-start(%p), dimensions={0}
+    """)
+    ops = collective_inventory(text)
+    assert [(op.family, op.bytes) for op in ops] == [
+        ("all-reduce", 2 * 64 * 4),
+        ("all-reduce", 7 * 7 * 3 * 64 * 2),
+        ("all-gather", (192 + 768) * 768 * 2),
+    ]
+    assert not any(op.scalar for op in ops)
+
+
 def test_summarize_separates_scalar_grade():
     summary = summarize_collectives(collective_inventory(SAMPLE_HLO))
     assert summary["tensor"]["all-reduce"] == {

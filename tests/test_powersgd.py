@@ -6,7 +6,7 @@ integration with state threading (VERDICT r3 #6)."""
 import jax
 import jax.numpy as jnp
 
-from pytorch_distributed_tpu._compat import shard_map
+from jax import shard_map
 import numpy as np
 import optax
 import pytest
