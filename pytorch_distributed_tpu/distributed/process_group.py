@@ -30,15 +30,11 @@ import numpy as np
 
 from pytorch_distributed_tpu.distributed.store import PrefixStore, Store
 
-from pytorch_distributed_tpu.observability.logging_utils import (
+from pytorch_distributed_tpu.observability import (
     put_metric,
     record_event,
+    span,
 )
-
-try:  # profiler regions for eager collectives; absent on minimal installs
-    from jax.profiler import TraceAnnotation as _trace_annotation
-except Exception:  # pragma: no cover
-    _trace_annotation = None
 
 
 __all__ = [
@@ -404,18 +400,13 @@ class ProcessGroup:
 
         def run():
             # per-collective trace events (ParamCommsUtils role, SURVEY
-            # §5.1): a named profiler region + a structured event with op,
-            # bytes, and group metadata, and a per-op counter metric.
-            # (_trace_annotation/record_event/put_metric resolved once at
-            # module import — this is the eager communication hot loop.)
+            # §5.1): a host span in the profiler's trace + a structured
+            # event with op, bytes, and group metadata, and a per-op
+            # counter metric.
             t0 = time.perf_counter()
             try:
-                if _trace_annotation is not None:
-                    with _trace_annotation(
-                        f"pg::{op_name}[{self.group_name}]"
-                    ):
-                        out = fn()
-                else:
+                with span(f"pg.{op_name}", group=self.group_name,
+                          nbytes=nbytes):
                     out = fn()
             except Exception:
                 if fr:

@@ -269,7 +269,8 @@ class GPT2(nn.Module):
             (cfg.n_positions, cfg.n_embd),
             cfg.param_dtype,
         )
-        x = wte[tokens].astype(cfg.dtype) + wpe[:T].astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            x = wte[tokens].astype(cfg.dtype) + wpe[:T].astype(cfg.dtype)
         if cfg.dropout > 0:
             x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
 
@@ -305,16 +306,19 @@ class GPT2(nn.Module):
                 return x, cfg.moe_aux_weight * aux_total
             return x
         # weight-tied LM head; logits in fp32 for a stable softmax/loss
-        if cfg.head_in_fp32:
-            logits = jnp.einsum(
-                "btc,vc->btv", x.astype(jnp.float32),
-                wte.astype(jnp.float32),
-            )
-        else:
-            logits = jnp.einsum(
-                "btc,vc->btv", x, wte.astype(cfg.dtype),
-                preferred_element_type=jnp.float32,
-            )
+        # (a param is no submodule, so Flax scopes neither this nor the
+        # embedding lookup: "head" names it for the device trace)
+        with jax.named_scope("head"):
+            if cfg.head_in_fp32:
+                logits = jnp.einsum(
+                    "btc,vc->btv", x.astype(jnp.float32),
+                    wte.astype(jnp.float32),
+                )
+            else:
+                logits = jnp.einsum(
+                    "btc,vc->btv", x, wte.astype(cfg.dtype),
+                    preferred_element_type=jnp.float32,
+                )
         if cfg.moe_experts > 0:
             # weighted router load-balance loss, consumed by lm_loss
             return logits, cfg.moe_aux_weight * aux_total

@@ -1,10 +1,17 @@
 """Observability & debug — SURVEY.md §2.6 / §5.1-§5.5 parity.
 
+  * ``span``            — the ONE way to open a host span in the program:
+    ``pdt.<name>`` in the profiler's trace, a no-op with no profiler
+    session (the runner, the scheduler, the engine and the process group
+    say what the host was doing; ``jax.named_scope`` says which section
+    the device was in)
+  * ``profile_trace``   — a profiler session around a block: turns the
+    spans on and writes the ``.xplane.pb``
+  * ``register_program`` / ``programs`` — lazy thunks to the compiled
+    programs (``"step"``, ``"decode"``, ``"prefill/<bucket>"``)
   * ``FlightRecorder``  — C++ ring buffer of eager collectives + stall
     watchdog with dump-on-hang (c10d FlightRecorder + NCCL watchdog roles)
   * ``fr_trace``        — dump analyzer (torch ``flight_recorder/fr_trace.py``)
-  * ``exception_logger`` / ``time_logger`` — structured API-call logging
-    decorators (``c10d_logger.py:79,93``)
   * ``Event`` / ``record_event`` / ``put_metric`` — structured events +
     counters (torch ``elastic/events``, ``elastic/metrics``)
   * ``debug_level``     — OFF/INFO/DETAIL from $TPU_DISTRIBUTED_DEBUG
@@ -12,7 +19,8 @@
     wrapper in pytorch_distributed_tpu.distributed)
   * ``nan_check``       — host-side NaN scan hook (NanCheck.hpp role)
   * ``IterationLogger`` — per-iteration DDP-style stats (C++ logger.hpp role)
-  * ``profiler``        — jax.profiler trace/annotate wrappers
+  * ``LatencyTracker`` / ``RatioTracker`` — the scheduler's running
+    percentiles and ratios (``Scheduler.stats()``)
 """
 
 from pytorch_distributed_tpu.observability.flight_recorder import (
@@ -27,31 +35,26 @@ from pytorch_distributed_tpu.observability.logging_utils import (
     LatencyTracker,
     RatioTracker,
     debug_level,
-    exception_logger,
     get_metrics,
     nan_check,
     put_metric,
     recent_events,
     record_event,
-    time_logger,
 )
 from pytorch_distributed_tpu.observability.profiler import (
-    StepProfiler,
-    annotate,
-    memory_breakdown,
     profile_trace,
-    trace_op_breakdown,
+    programs,
+    register_program,
+    shapes_of,
+    span,
 )
 
 __all__ = [
-    "StepProfiler", "memory_breakdown", "trace_op_breakdown",
     "FlightRecorder",
     "get_flight_recorder",
     "fr_trace",
     "DebugLevel",
     "debug_level",
-    "exception_logger",
-    "time_logger",
     "Event",
     "record_event",
     "recent_events",
@@ -61,6 +64,9 @@ __all__ = [
     "IterationLogger",
     "LatencyTracker",
     "RatioTracker",
-    "annotate",
+    "span",
     "profile_trace",
+    "register_program",
+    "programs",
+    "shapes_of",
 ]

@@ -5,7 +5,6 @@ stats — the Python observability roles of SURVEY.md §2.6/§5.5.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import json
 import logging
 import os
@@ -20,8 +19,6 @@ logger = logging.getLogger("pytorch_distributed_tpu")
 __all__ = [
     "DebugLevel",
     "debug_level",
-    "exception_logger",
-    "time_logger",
     "Event",
     "record_event",
     "recent_events",
@@ -47,42 +44,6 @@ def debug_level() -> DebugLevel:
         return DebugLevel(raw)
     except ValueError:
         return DebugLevel.OFF
-
-
-# -- API-call logging decorators (c10d_logger.py:79,93) --------------------
-def exception_logger(fn: Callable) -> Callable:
-    """Log exceptions from public distributed APIs with call metadata."""
-
-    @functools.wraps(fn)
-    def wrapper(*args, **kwargs):
-        try:
-            return fn(*args, **kwargs)
-        except Exception:
-            logger.exception(
-                "distributed API %s failed (args=%d, kwargs=%s)",
-                fn.__qualname__, len(args), sorted(kwargs),
-            )
-            raise
-
-    return wrapper
-
-
-def time_logger(fn: Callable) -> Callable:
-    """Log wall time of public distributed APIs at INFO debug level."""
-
-    @functools.wraps(fn)
-    def wrapper(*args, **kwargs):
-        if debug_level() is DebugLevel.OFF:
-            return fn(*args, **kwargs)
-        t0 = time.perf_counter()
-        out = fn(*args, **kwargs)
-        logger.info(
-            "%s took %.3f ms", fn.__qualname__,
-            (time.perf_counter() - t0) * 1e3,
-        )
-        return out
-
-    return wrapper
 
 
 # -- structured events (elastic/events role) -------------------------------

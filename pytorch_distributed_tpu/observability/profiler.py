@@ -1,38 +1,40 @@
-"""Profiler integration — jax.profiler as the Kineto/torch.profiler analog
-(SURVEY.md §5.1): XPlane traces viewable in TensorBoard/Perfetto, named
-annotation scopes matching the reference's ``record_function`` regions,
-a step-budget analyzer over captured traces (the DDP Logger per-iteration
-stats role), and compiled-program memory analysis (torch.profiler memory
-profiler role).
+"""Profiler integration: jax.profiler as the one tracing system.
+
+The profiler's session is the switch, its buffer the in-memory store, its
+``.xplane.pb`` what is written out at the end, and its clock the one the
+device trace is on. The program says what the host is doing with
+:func:`span`; device-side sections are ``jax.named_scope`` (metadata on the
+compiled ops, nothing at run time). :func:`register_program` /
+:func:`programs` are the lazy way to the compiled programs whose op names
+carry those scopes.
 """
 
 from __future__ import annotations
 
-import collections
 import contextlib
-import glob
-import gzip
-import json
-import os
-import re
-from typing import Dict, Iterator, Optional
+from typing import Any, Callable, Dict, Iterator
+
+import jax
 
 __all__ = [
     "profile_trace",
-    "annotate",
-    "trace_op_breakdown",
-    "memory_breakdown",
-    "StepProfiler",
+    "span",
+    "SPAN_PREFIX",
+    "register_program",
+    "programs",
+    "shapes_of",
 ]
+
+#: every host span of the program is named ``pdt.<layer>.<what>``
+SPAN_PREFIX = "pdt."
 
 
 @contextlib.contextmanager
-def profile_trace(log_dir: str, *, host_tracer_level: int = 2) -> Iterator[None]:
+def profile_trace(log_dir: str) -> Iterator[None]:
     """Capture a jax.profiler trace to ``log_dir`` (torch.profiler.profile
-    role). View with TensorBoard or xprof, or post-process with
-    :func:`trace_op_breakdown`."""
-    import jax
-
+    role). While it runs every :func:`span` is in the trace, on the clock
+    of the device's own events. View with TensorBoard or xprof, or read
+    the ``.xplane.pb`` with ``jax.profiler.ProfileData``."""
     jax.profiler.start_trace(log_dir, create_perfetto_link=False)
     try:
         yield
@@ -40,164 +42,44 @@ def profile_trace(log_dir: str, *, host_tracer_level: int = 2) -> Iterator[None]
         jax.profiler.stop_trace()
 
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named region visible in profiles AND in compiled HLO metadata
-    (record_function / named_scope role). Usable inside jit."""
-    import jax
-
-    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
-        yield
-
-
-def trace_op_breakdown(log_dir: str, *, top: int = 20) -> Dict:
-    """Aggregate device op time from a captured trace (the analysis the
-    round-3 perf work ran by hand — perf/ scripts — promoted to the
-    library): per-op-type totals and the top individual ops.
-
-    Reads the ``*.trace.json.gz`` the profiler writes; returns
-    ``{total_ms, by_type: {name: ms}, top_ops: [(ms, name)]}``.
-    """
-    paths = sorted(glob.glob(
-        os.path.join(log_dir, "plugins/profile/*/*.trace.json.gz")
-    ))
-    if not paths:
-        raise FileNotFoundError(f"no trace under {log_dir}")
-    with gzip.open(paths[-1]) as f:
-        tr = json.load(f)
-    ev = tr["traceEvents"]
-    pids = {
-        e["pid"]: e["args"].get("name", "")
-        for e in ev
-        if e.get("ph") == "M" and e.get("name") == "process_name"
-    }
-    tids = {
-        (e["pid"], e.get("tid")): e["args"].get("name", "")
-        for e in ev
-        if e.get("ph") == "M" and e.get("name") == "thread_name"
-    }
-    device_pids = {
-        pid for pid, n in pids.items()
-        if "TPU" in n or "/device" in n.lower()
-    }
-    # Prefer the "XLA Ops" trace line: device pids also carry envelope
-    # lines (XLA Modules, framework name scopes) whose spans NEST the op
-    # events — summing those would double-count device time.
-    op_tids = {
-        key for key, n in tids.items()
-        if key[0] in device_pids and "XLA Ops" in n
-    }
-    dur: collections.Counter = collections.Counter()
-    by_type: collections.Counter = collections.Counter()
-    for e in ev:
-        if e.get("ph") != "X" or "dur" not in e:
-            continue
-        if e["pid"] not in device_pids:
-            continue
-        if op_tids and (e["pid"], e.get("tid")) not in op_tids:
-            continue
-        name = e["name"]
-        if re.fullmatch(r"\d+", name) or name.startswith("jit_"):
-            continue  # step envelopes, not ops
-        dur[name] += e["dur"]
-        by_type[re.sub(r"\.\d+$", "", name)] += e["dur"]
-    return {
-        "total_ms": round(sum(dur.values()) / 1e3, 3),
-        "by_type_ms": {
-            k: round(v / 1e3, 3) for k, v in by_type.most_common(top)
-        },
-        "top_ops_ms": [
-            (round(v / 1e3, 3), k) for k, v in dur.most_common(top)
-        ],
-    }
+def span(name: str, **stats: Any):
+    """A host span ``pdt.<name>`` in the profiler's trace; with no profiler
+    session a no-op of about half a microsecond. ``stats`` arrive as the
+    event's statistics: values already at hand (an ``int``, a Python
+    counter), never a device read. Request-scoped spans carry
+    ``request_id``, step-scoped ones ``step``; the cause of a span is the
+    span that encloses it. ``with span(...) as s`` gives the annotation:
+    ``s.set_metadata(...)`` adds a stat known only when the work is done."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **stats)
 
 
-def memory_breakdown(compiled) -> Dict:
-    """Memory analysis of a compiled function (torch memory-profiler
-    role): argument/output/temp/generated-code sizes in bytes. Pass the
-    result of ``jax.jit(f).lower(*args).compile()`` (or a Trainer's
-    ``_step_fn`` compiled the same way)."""
-    ma = compiled.memory_analysis()
-    out = {}
-    for field in (
-        "argument_size_in_bytes",
-        "output_size_in_bytes",
-        "temp_size_in_bytes",
-        "generated_code_size_in_bytes",
-        "alias_size_in_bytes",
-    ):
-        v = getattr(ma, field, None)
-        if v is not None:
-            out[field.replace("_in_bytes", "")] = int(v)
-    return out
+def shapes_of(tree):
+    """``tree`` with every leaf replaced by its ``jax.ShapeDtypeStruct`` (a
+    committed array keeps its sharding): what a :func:`register_program`
+    thunk saves in place of the arrays, so that it holds no memory."""
+    def one(a):
+        if isinstance(a, jax.Array):
+            return jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=a.sharding if a.committed else None)
+        return jax.ShapeDtypeStruct(jax.numpy.shape(a), jax.numpy.result_type(a))
+
+    return jax.tree_util.tree_map(one, tree)
 
 
-class StepProfiler:
-    """Capture a trace around N training steps and summarize it — the
-    per-iteration stats collector role of torch DDP's C++ Logger, but
-    driven by real profiler data::
+_PROGRAMS: Dict[str, Callable[[], Any]] = {}
 
-        sp = StepProfiler("/tmp/prof", n_steps=5, warmup=2)
-        for batch in loader:
-            with sp.step():
-                state, m = trainer.step(state, batch)
-        print(sp.summary())   # populated once n_steps were captured
-    """
 
-    def __init__(self, log_dir: str, *, n_steps: int = 5, warmup: int = 2):
-        self.log_dir = log_dir
-        self.n_steps = n_steps
-        self.warmup = warmup
-        self._seen = 0
-        self._captured = 0
-        self._tracing = False
-        self._summary: Optional[Dict] = None
+def register_program(name: str, thunk: Callable[[], Any]) -> None:
+    """Name a way to a compiled program: ``thunk()`` lowers and compiles it
+    from saved shapes when somebody asks, and nothing before. The newest
+    registration of a name wins (one runner, one engine a process is the
+    deployed case)."""
+    _PROGRAMS[name] = thunk
 
-    @contextlib.contextmanager
-    def step(self) -> Iterator[None]:
-        import jax
 
-        self._seen += 1
-        if self._seen == self.warmup + 1 and self._summary is None:
-            jax.profiler.start_trace(self.log_dir)
-            self._tracing = True
-        try:
-            yield
-        except BaseException:
-            # a failing step must not leave the process-global profiler
-            # session running (a later start_trace would raise)
-            self.close()
-            raise
-        else:
-            if self._tracing:
-                self._captured += 1
-            if self._tracing and self._captured >= self.n_steps:
-                self.close()
-
-    def close(self) -> None:
-        """Stop a live capture and summarize. Idempotent; called
-        automatically when n_steps were captured or a step raised — call
-        it yourself when the loop may end early (fewer batches than
-        warmup + n_steps)."""
-        if not self._tracing:
-            return
-        import jax
-
-        self._tracing = False
-        try:
-            jax.profiler.stop_trace()
-        except Exception:
-            return
-        try:  # best-effort analysis: never crash the training loop
-            bd = trace_op_breakdown(self.log_dir)
-            bd["steps_captured"] = self._captured
-            self._summary = bd
-        except Exception as e:
-            self._summary = {
-                "error": f"trace analysis failed: {type(e).__name__}",
-                "steps_captured": self._captured,
-            }
-
-    def summary(self) -> Optional[Dict]:
-        self.close()
-        return self._summary
+def programs() -> Dict[str, Callable[[], Any]]:
+    """The registered thunks by name: ``programs()["step"]()`` is the
+    compiled train step (``as_text()`` carries each op's ``op_name`` with
+    its named scopes, ``memory_analysis()`` its bytes); the engine gives
+    ``"decode"`` and ``"prefill/<bucket>"``."""
+    return dict(_PROGRAMS)

@@ -35,7 +35,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-from pytorch_distributed_tpu.observability import record_event
+from pytorch_distributed_tpu.observability import (
+    record_event,
+    register_program,
+    shapes_of,
+    span,
+)
 from pytorch_distributed_tpu.pipeline_exec.metric_ring import MetricRing
 
 __all__ = ["AsyncRunner", "MetricHistory"]
@@ -156,8 +161,10 @@ class AsyncRunner:
 
         def pstep(state, ring, batch, rng):
             new_state, metrics = raw(state, batch, rng)
-            new_ring = ring.push(metrics)
-            return new_state, new_ring, new_ring.stacked()
+            with jax.named_scope("metric_ring"):
+                new_ring = ring.push(metrics)
+                snapshot = new_ring.stacked()
+            return new_state, new_ring, snapshot
 
         # sharding prefixes: the ring and its snapshot are replicated
         # scalars; the state keeps the strategy's pinned layout exactly
@@ -200,6 +207,11 @@ class AsyncRunner:
         self._state = state
         self._rng = rng
         self._started = True
+        # the lazy way to the compiled step: shapes only, so the thunk holds
+        # no array and compiles nothing until somebody calls it
+        pstep = self._pstep
+        shapes = shapes_of((state, self._ring, placed, rng))
+        register_program("step", lambda: pstep.lower(*shapes).compile())
         return self
 
     # -- the hot path ------------------------------------------------------
@@ -209,26 +221,33 @@ class AsyncRunner:
         window) once the pipeline is full."""
         if not self._started:
             raise RuntimeError("AsyncRunner.start(state, batch) first")
-        batch = self.trainer._place_batch(batch)
-        self._state, self._ring, snap = self._pstep(
-            self._state, self._ring, batch, self._rng
-        )
-        self._n += 1
-        self._dispatches += 1
-        self._last_snap = snap
-        self._fences.append(snap)
-        if len(self._fences) > self.depth:
-            old = self._fences.popleft()
-            # backpressure fence, not a step sync: this blocks on the
-            # snapshot of step i-depth (long since dispatched) so the
-            # host stays exactly `depth` steps ahead; the current step
-            # is never waited on.
-            old.block_until_ready()  # graftlint: disable=host-sync-in-hot-loop -- bounded K-deep in-flight window: waits on the step `depth` behind, keeping dispatch ahead of compute; removing it lets the host run unboundedly ahead
-        if self._n % self.drain_every == 0:
-            # non-blocking drain: start the D2H transfer of the full
-            # window and keep the handle; values are read at finish()
-            snap.copy_to_host_async()
-            self._drains.append(snap)
+        step = self._n
+        with span("runner.submit", step=step):
+            with span("runner.place_batch"):
+                batch = self.trainer._place_batch(batch)
+            with span("runner.dispatch", step=step) as dispatch:
+                self._state, self._ring, snap = self._pstep(
+                    self._state, self._ring, batch, self._rng
+                )
+                # after the call, so the step that recompiled shows it
+                dispatch.set_metadata(executables=self.executable_count)
+            self._n += 1
+            self._dispatches += 1
+            self._last_snap = snap
+            self._fences.append(snap)
+            if len(self._fences) > self.depth:
+                old = self._fences.popleft()
+                # backpressure fence, not a step sync: this blocks on the
+                # snapshot of step i-depth (long since dispatched) so the
+                # host stays exactly `depth` steps ahead; the current step
+                # is never waited on.
+                with span("runner.fence", step=step - self.depth):
+                    old.block_until_ready()  # graftlint: disable=host-sync-in-hot-loop -- bounded K-deep in-flight window: waits on the step `depth` behind, keeping dispatch ahead of compute; removing it lets the host run unboundedly ahead
+            if self._n % self.drain_every == 0:
+                # non-blocking drain: start the D2H transfer of the full
+                # window and keep the handle; values are read at finish()
+                snap.copy_to_host_async()
+                self._drains.append(snap)
 
     def step_artifacts(self, batch):
         """``(lowered, compiled)`` IR artifacts of the pipelined step —
@@ -248,8 +267,9 @@ class AsyncRunner:
         call — use it as the compile/warmup barrier before a timed
         region (the warm submit's compile must not leak into the clock);
         the pipeline keeps running afterwards."""
-        if self._last_snap is not None:
-            self._last_snap.block_until_ready()
+        with span("runner.sync", steps=self._n):
+            if self._last_snap is not None:
+                self._last_snap.block_until_ready()
 
     # -- the one sync ------------------------------------------------------
     def finish(self):
@@ -259,23 +279,24 @@ class AsyncRunner:
         if not self._started:
             raise RuntimeError("AsyncRunner.start(state, batch) first")
         t0 = time.perf_counter()
-        series = {k: np.zeros(self._n, np.float32) for k in self._names}
-        tail = None
-        if self._n:
-            # the final snapshot depends (through the donated state
-            # chain) on every prior step: reading it IS the honest
-            # end-of-chain barrier
-            tail = np.asarray(self._last_snap)
-        for w, snap in enumerate(self._drains):
-            arr = np.asarray(snap)  # transfer already started async
-            lo = w * self.drain_every
-            for i, k in enumerate(self._names):
-                series[k][lo:lo + self.drain_every] = arr[i]
-        rem = self._n % self.drain_every
-        if rem and tail is not None:
-            lo = self._n - rem
-            for i, k in enumerate(self._names):
-                series[k][lo:lo + rem] = tail[i, :rem]
+        with span("runner.finish", steps=self._n):
+            series = {k: np.zeros(self._n, np.float32) for k in self._names}
+            tail = None
+            if self._n:
+                # the final snapshot depends (through the donated state
+                # chain) on every prior step: reading it IS the honest
+                # end-of-chain barrier
+                tail = np.asarray(self._last_snap)
+            for w, snap in enumerate(self._drains):
+                arr = np.asarray(snap)  # transfer already started async
+                lo = w * self.drain_every
+                for i, k in enumerate(self._names):
+                    series[k][lo:lo + self.drain_every] = arr[i]
+            rem = self._n % self.drain_every
+            if rem and tail is not None:
+                lo = self._n - rem
+                for i, k in enumerate(self._names):
+                    series[k][lo:lo + rem] = tail[i, :rem]
         record_event(
             "pipeline_exec.step_budget",
             steps=self._n,

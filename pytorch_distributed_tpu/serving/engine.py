@@ -49,6 +49,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from pytorch_distributed_tpu.observability import (
+    register_program,
+    shapes_of,
+    span,
+)
 from pytorch_distributed_tpu.redistribute import plan_tree, redistribute_tree
 from pytorch_distributed_tpu.serving.kv_cache import KVCache
 from pytorch_distributed_tpu.serving.paging import PagedKVCache
@@ -458,6 +463,35 @@ class InferenceEngine:
         else:
             self._spec = None
             self._draft_prefill = None
+        self._register_programs()
+
+    def _register_programs(self) -> None:
+        """The lazy way to the compiled decode and prefill programs
+        (``observability.programs()``): thunks over saved shapes, so they
+        hold no array and nothing is lowered or compiled until somebody
+        asks."""
+        decode, prefill = self._decode, self._prefill
+        cache = jax.eval_shape(self.init_cache)
+        if self.cache_sharding is not None:
+            def placed(a):
+                return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=self.cache_sharding)
+
+            cache = cache.replace(k=placed(cache.k), v=placed(cache.v))
+        params, rng = shapes_of(self.params), shapes_of(self._rng)
+        i32 = jax.ShapeDtypeStruct((), jnp.int32)
+        slots = jax.ShapeDtypeStruct((self.n_slots,), jnp.int32)
+        active = jax.ShapeDtypeStruct((self.n_slots,), jnp.bool_)
+        register_program("decode", lambda: decode.lower(
+            params, cache, slots, active, rng).compile())
+        # slot, (start,) n_real
+        scalars = (i32,) * (3 if self.cache_kind == "paged" else 2)
+        for bucket in self.prefill_buckets:
+            tokens = jax.ShapeDtypeStruct((1, bucket), jnp.int32)
+            register_program(
+                f"prefill/{bucket}",
+                lambda tokens=tokens: prefill.lower(
+                    params, cache, tokens, *scalars, rng).compile())
 
     # -- state -------------------------------------------------------------
     def init_cache(self):
@@ -593,7 +627,8 @@ class InferenceEngine:
         return padded, n
 
     def prefill(
-        self, cache, slot: int, prompt: np.ndarray, *, cached_len: int = 0
+        self, cache, slot: int, prompt: np.ndarray, *, cached_len: int = 0,
+        request_id: Optional[int] = None,
     ) -> Tuple[Any, int]:
         """Admit ``prompt`` (1-D int tokens) into ``slot``; returns the
         updated cache and the FIRST generated token.
@@ -603,7 +638,8 @@ class InferenceEngine:
         attached page chain, so only the tail ``prompt[cached_len:]`` runs
         through the prefill program (padded to ITS bucket — a hit on a long
         prompt prefills through a much smaller compiled bucket, which is
-        the cached-prefix TTFT win)."""
+        the cached-prefix TTFT win). ``request_id`` only labels the
+        ``pdt.engine.prefill`` span."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         n = prompt.shape[0]
         cached_len = int(cached_len)
@@ -624,20 +660,28 @@ class InferenceEngine:
                     f"prompt length {n} leaves no room to generate "
                     f"(max_len {self.max_len})"
                 )
-        padded, n_real = self._pad_prompt(prompt[cached_len:])
         if not (0 <= slot < self.n_slots):
             raise ValueError(f"slot {slot} out of range")
-        if self.cache_kind == "paged":
-            cache, tok = self._prefill(
-                self.params, cache, jnp.asarray(padded), jnp.int32(slot),
-                jnp.int32(cached_len), jnp.int32(n_real), self._next_rng(),
-            )
-        else:
-            cache, tok = self._prefill(
-                self.params, cache, jnp.asarray(padded),
-                jnp.int32(slot), jnp.int32(n_real), self._next_rng(),
-            )
-        return cache, int(tok)
+        stats = {} if request_id is None else {"request_id": request_id}
+        with span("engine.prefill", **stats) as whole:
+            with span("engine.prefill.dispatch") as dispatch:
+                padded, n_real = self._pad_prompt(prompt[cached_len:])
+                whole.set_metadata(bucket=padded.shape[1], n_real=n_real)
+                if self.cache_kind == "paged":
+                    cache, tok = self._prefill(
+                        self.params, cache, jnp.asarray(padded),
+                        jnp.int32(slot), jnp.int32(cached_len),
+                        jnp.int32(n_real), self._next_rng(),
+                    )
+                else:
+                    cache, tok = self._prefill(
+                        self.params, cache, jnp.asarray(padded),
+                        jnp.int32(slot), jnp.int32(n_real), self._next_rng(),
+                    )
+                dispatch.set_metadata(executables=self._prefill._cache_size())
+            with span("engine.prefill.read"):
+                tok = int(tok)  # waits for the device
+        return cache, tok
 
     def prefill_draft(
         self, draft_cache: KVCache, slot: int, prompt: np.ndarray
@@ -661,13 +705,18 @@ class InferenceEngine:
         tail or last sample); ``active [S]`` bool. Returns the updated
         cache and the sampled tokens ``[S]`` (garbage at inactive slots —
         the scheduler ignores them)."""
-        cache, toks = self._decode(
-            self.params, cache,
-            jnp.asarray(np.asarray(last_tokens, np.int32)),
-            jnp.asarray(np.asarray(active, bool)),
-            self._next_rng(),
-        )
-        return cache, np.asarray(toks)
+        with span("engine.decode"):
+            with span("engine.decode.dispatch") as dispatch:
+                cache, toks = self._decode(
+                    self.params, cache,
+                    jnp.asarray(np.asarray(last_tokens, np.int32)),
+                    jnp.asarray(np.asarray(active, bool)),
+                    self._next_rng(),
+                )
+                dispatch.set_metadata(executables=self._decode._cache_size())
+            with span("engine.decode.read"):
+                toks = np.asarray(toks)  # waits for the device, copies back
+        return cache, toks
 
     def spec_decode(
         self,
@@ -689,19 +738,24 @@ class InferenceEngine:
         catch-up consumes it)."""
         if self._spec is None:
             raise RuntimeError("spec_k=0 — speculative decoding disabled")
-        last = jnp.asarray(np.asarray(last_tokens, np.int32))
-        prev = jnp.asarray(np.asarray(prev_tokens, np.int32))
-        act = jnp.asarray(np.asarray(active, bool))
-        rng = self._next_rng()
-        if self.draft_model is None:
-            cache, emitted, counts, prev_next = self._spec(
-                self.params, cache, last, act, rng
-            )
-            dcache = draft_cache
-        else:
-            cache, dcache, emitted, counts, prev_next = self._spec(
-                self.params, self.draft_params, cache, draft_cache,
-                last, prev, act, rng,
-            )
-        return (cache, dcache, np.asarray(emitted), np.asarray(counts),
-                np.asarray(prev_next))
+        with span("engine.decode"):
+            with span("engine.decode.dispatch") as dispatch:
+                last = jnp.asarray(np.asarray(last_tokens, np.int32))
+                prev = jnp.asarray(np.asarray(prev_tokens, np.int32))
+                act = jnp.asarray(np.asarray(active, bool))
+                rng = self._next_rng()
+                if self.draft_model is None:
+                    cache, emitted, counts, prev_next = self._spec(
+                        self.params, cache, last, act, rng
+                    )
+                    dcache = draft_cache
+                else:
+                    cache, dcache, emitted, counts, prev_next = self._spec(
+                        self.params, self.draft_params, cache, draft_cache,
+                        last, prev, act, rng,
+                    )
+                dispatch.set_metadata(executables=self._spec._cache_size())
+            with span("engine.decode.read"):
+                out = (np.asarray(emitted), np.asarray(counts),
+                       np.asarray(prev_next))
+        return (cache, dcache, *out)

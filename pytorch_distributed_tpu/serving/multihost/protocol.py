@@ -106,10 +106,14 @@ def announce_msg(host: str, chan: int, *, n_slots: int, prefill_len: int,
 
 
 def wire_request(request_id: int, route_id: int, prompt: List[int],
-                 max_new_tokens: int, eos_token: Optional[int]) -> Dict[str, Any]:
+                 max_new_tokens: int, eos_token: Optional[int],
+                 arrival_unix: Optional[float] = None) -> Dict[str, Any]:
+    """``arrival_unix`` is the wall clock (``time.time()``) at which the
+    router took the request in: the one clock two hosts share, so the
+    worker's queue wait includes the router's hop."""
     return {"request_id": request_id, "route_id": route_id,
             "prompt": prompt, "max_new_tokens": max_new_tokens,
-            "eos_token": eos_token}
+            "eos_token": eos_token, "arrival_unix": arrival_unix}
 
 
 def tokens_chunk(request_id: int, route_id: int, seq: int,
@@ -119,10 +123,12 @@ def tokens_chunk(request_id: int, route_id: int, seq: int,
 
 
 def finished_msg(request_id: int, route_id: int, seq: int, *, reason: str,
-                 n_tokens: int, ttft_s: float, total_s: float) -> Dict[str, Any]:
+                 n_tokens: int, ttft_s: float, total_s: float,
+                 queue_s: float = 0.0) -> Dict[str, Any]:
     return {"type": "finished", "request_id": request_id,
             "route_id": route_id, "seq": seq, "reason": reason,
-            "n_tokens": n_tokens, "ttft_s": ttft_s, "total_s": total_s}
+            "n_tokens": n_tokens, "ttft_s": ttft_s, "total_s": total_s,
+            "queue_s": queue_s}
 
 
 def load_msg(*, hb: int, active: int, queued: int, n_slots: int,

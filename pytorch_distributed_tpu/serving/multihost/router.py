@@ -82,6 +82,12 @@ class _InFlight:
         self.max_new_tokens = int(req.max_new_tokens)
         self.eos_token = req.eos_token
         self.submitted_at = now
+        # wall clock of the request's arrival (the front end's, where it
+        # gave one): what the worker counts its queue wait from
+        self.arrival_unix = time.time() - (
+            0.0 if req.arrival_s is None
+            else max(0.0, time.perf_counter() - req.arrival_s))
+        self.queue_s = 0.0  # as the host that finished it reports
         self.committed: List[int] = []
         self.chan: Optional[int] = None
         self.route_id: Optional[int] = None
@@ -350,6 +356,7 @@ class Router:
                     f"reassembled {got}"
                 )
             hv.outstanding.discard(rid)
+            inf.queue_s = float(msg.get("queue_s", 0.0))
             self._finish(inf, msg["reason"], finished)
         else:
             raise RuntimeError(f"unknown outbox message type {msg['type']!r}")
@@ -364,6 +371,7 @@ class Router:
             reason=reason,
             ttft_s=inf.ttft_s if inf.ttft_s is not None else total,
             total_s=total,
+            queue_s=inf.queue_s,
         )
         del self._inflight[inf.request_id]
         self._completed.add(inf.request_id)
@@ -437,7 +445,7 @@ class Router:
             self.keys.inbox(hv.chan, n),
             protocol.dumps(protocol.wire_request(
                 inf.request_id, inf.route_id, refeed, remaining,
-                inf.eos_token,
+                inf.eos_token, inf.arrival_unix,
             )),
         )
         hv.outstanding.add(inf.request_id)

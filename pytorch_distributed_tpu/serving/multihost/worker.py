@@ -225,11 +225,16 @@ class HostWorker:
                 self._forget(rid)
                 n += 1
                 continue
+            # the router's wall clock, turned into an age on this host's
+            # own: the wait in the router and on the wire counts as queue
+            sent = msg.get("arrival_unix")
             self.scheduler.submit(Request(
                 prompt=prompt,
                 max_new_tokens=int(msg["max_new_tokens"]),
                 eos_token=msg["eos_token"],
                 request_id=rid,
+                arrival_s=None if sent is None else
+                time.perf_counter() - max(0.0, time.time() - sent),
             ))
             n += 1
 
@@ -254,7 +259,7 @@ class HostWorker:
         self._post(protocol.finished_msg(
             fin.request_id, route, self._chunk_seq[fin.request_id],
             reason=fin.reason, n_tokens=len(fin.tokens),
-            ttft_s=fin.ttft_s, total_s=fin.total_s,
+            ttft_s=fin.ttft_s, total_s=fin.total_s, queue_s=fin.queue_s,
         ))
         self._forget(fin.request_id)
 

@@ -20,6 +20,11 @@ per token changes. Accept-rate and tokens-per-target-forward accumulate in
 Per-request and per-step timings flow into ``observability``: structured
 ``serving.request_finished`` events carry TTFT and decode latency, and the
 scheduler's LatencyTrackers feed the decode benchmark's p50/p99 numbers.
+Under a profiler session the host spans ``pdt.sched.step`` > ``.admit`` /
+``.consume`` > ``.evict`` say where a step's host time went, with the
+request's id, its queue wait and the tokens consumed as their stats.
+Every request latency starts at ARRIVAL (``Request.arrival_s``), not at
+admission: an open loop's queue wait is part of its time to first token.
 """
 
 from __future__ import annotations
@@ -37,6 +42,7 @@ from pytorch_distributed_tpu.observability import (
     RatioTracker,
     put_metric,
     record_event,
+    span,
 )
 from pytorch_distributed_tpu.serving.engine import InferenceEngine
 from pytorch_distributed_tpu.serving.paging import (
@@ -61,6 +67,10 @@ class Request:
     max_new_tokens: int = 16
     eos_token: Optional[int] = None
     request_id: Optional[int] = None  # assigned by submit()
+    #: host clock (``time.perf_counter``) at which the request arrived;
+    #: ``submit()`` stamps it when left None. An open-loop front end passes
+    #: the instant the request was DUE, a router the instant it took it in.
+    arrival_s: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -69,8 +79,9 @@ class FinishedRequest:
     prompt: np.ndarray
     tokens: List[int]  # generated tokens (includes EOS if hit)
     reason: str  # "eos" | "length"
-    ttft_s: float  # prefill submit -> first token
-    total_s: float  # prefill submit -> eviction
+    ttft_s: float  # arrival -> first token (queue wait included)
+    total_s: float  # arrival -> eviction
+    queue_s: float = 0.0  # arrival -> admission
 
 
 @dataclasses.dataclass
@@ -78,7 +89,7 @@ class _SlotState:
     request: Request
     prompt: np.ndarray
     tokens: List[int]
-    admitted_at: float
+    queue_s: float
     ttft_s: float
 
 
@@ -105,9 +116,11 @@ class Scheduler:
         # catch-up refeed reads it; harmless otherwise)
         self.prev_tokens = np.zeros((engine.n_slots,), np.int32)
         self.active = np.zeros((engine.n_slots,), bool)
+        self._n_active = 0  # == active.sum(), kept beside it by admit/evict
         self.ttft = LatencyTracker()
         self.decode_step = LatencyTracker()  # per decode step (whole batch)
         self.tokens_generated = 0
+        self.steps = 0
         self.decode_steps = 0
         self.weight_swaps = 0
         # speculative-decoding efficiency counters
@@ -140,12 +153,14 @@ class Scheduler:
             self._next_id += 1
         else:
             self._next_id = max(self._next_id, request.request_id + 1)
+        if request.arrival_s is None:
+            request.arrival_s = time.perf_counter()
         self.queue.append(request)
         return request.request_id
 
     @property
     def n_active(self) -> int:
-        return int(self.active.sum())
+        return self._n_active
 
     @property
     def free_pages(self) -> int:
@@ -168,6 +183,12 @@ class Scheduler:
 
         Returns the requests that completed during this step.
         """
+        with span("sched.step", step=self.steps, n_active=self._n_active,
+                  queued=len(self.queue)):
+            self.steps += 1
+            return self._step()
+
+    def _step(self) -> List[FinishedRequest]:
         finished: List[FinishedRequest] = []
 
         # join: fill every free slot from the queue (lowest slot first so
@@ -188,7 +209,7 @@ class Scheduler:
             finished.extend(self._admit(slot, self.queue.popleft(), plan))
 
         # decode: one token (or a verified speculative span) per active slot
-        if self.active.any():
+        if self._n_active:
             if self.engine.spec_k > 0:
                 finished.extend(self._spec_step())
             else:
@@ -198,18 +219,22 @@ class Scheduler:
                     self.cache, self.last_tokens, self.active
                 )
                 dt = time.perf_counter() - t0
-                self.decode_step.add(dt)
-                self.decode_steps += 1
-                n_act = int(self.active.sum())
-                self.tokens_generated += n_act
-                self.tokens_per_forward.add(n_act)
-                put_metric("serving.tokens_generated", n_act)
-                for slot in map(int, np.flatnonzero(self.active)):
-                    st = self.slots[slot]
-                    tok = int(toks[slot])
-                    st.tokens.append(tok)
-                    self.last_tokens[slot] = tok
-                    finished.extend(self._maybe_finish(slot))
+                with span("sched.consume") as consume:
+                    self.decode_step.add(dt)
+                    self.decode_steps += 1
+                    n_act = self._n_active
+                    self.tokens_generated += n_act
+                    self.tokens_per_forward.add(n_act)
+                    put_metric("serving.tokens_generated", n_act)
+                    n_before = len(finished)
+                    for slot in map(int, np.flatnonzero(self.active)):
+                        st = self.slots[slot]
+                        tok = int(toks[slot])
+                        st.tokens.append(tok)
+                        self.last_tokens[slot] = tok
+                        finished.extend(self._maybe_finish(slot))
+                    consume.set_metadata(
+                        tokens=n_act, finished=len(finished) - n_before)
         return finished
 
     def _spec_step(self) -> List[FinishedRequest]:
@@ -226,52 +251,56 @@ class Scheduler:
             self.prev_tokens, self.active,
         )
         dt = time.perf_counter() - t0
-        self.decode_step.add(dt)
-        self.decode_steps += 1
-        active_slots = list(map(int, np.flatnonzero(self.active)))
-        n_act = len(active_slots)
-        accepted = int(counts[self.active].sum()) - n_act
-        self.accept_rate.add(accepted, k * n_act)
-        put_metric("serving.spec_proposed", k * n_act)
-        put_metric("serving.spec_accepted", accepted)
-        consumed_total = 0
-        step_counts = {}
-        for slot in active_slots:
-            st = self.slots[slot]
-            n = int(counts[slot])
-            consumed = 0
-            for j in range(n):
-                tok = int(emitted[slot, j])
-                st.tokens.append(tok)
-                self.last_tokens[slot] = tok
-                consumed += 1
-                done = self._maybe_finish(slot)
-                if done:
-                    finished.extend(done)
-                    break
-            else:
-                # survived the whole span: the engine's bookkeeping token
-                # at lengths-1 feeds the next draft catch-up
-                self.prev_tokens[slot] = int(prev_next[slot])
-                if self.allocator is not None:
-                    # page-granular rollback: pages acquired for the
-                    # rejected tail of the span go back to the free list
-                    # (position prompt+tokens-1 is the next write — its
-                    # page stays); the reservation credit they drew is
-                    # refunded so the same slot can re-acquire them
-                    new_len = st.prompt.shape[0] + len(st.tokens) - 1
-                    self.allocator.release_tail(slot, new_len)
-            consumed_total += consumed
-            step_counts[slot] = consumed
-        self.tokens_generated += consumed_total
-        self.tokens_per_forward.add(consumed_total)
-        put_metric("serving.tokens_generated", consumed_total)
-        if self.emit_events:
-            record_event(
-                "serving.spec_step", source="scheduler",
-                proposed=k * n_act, accepted=accepted,
-                consumed=step_counts,
-            )
+        with span("sched.consume") as consume:
+            n_before = len(finished)
+            self.decode_step.add(dt)
+            self.decode_steps += 1
+            active_slots = list(map(int, np.flatnonzero(self.active)))
+            n_act = len(active_slots)
+            accepted = int(counts[self.active].sum()) - n_act
+            self.accept_rate.add(accepted, k * n_act)
+            put_metric("serving.spec_proposed", k * n_act)
+            put_metric("serving.spec_accepted", accepted)
+            consumed_total = 0
+            step_counts = {}
+            for slot in active_slots:
+                st = self.slots[slot]
+                n = int(counts[slot])
+                consumed = 0
+                for j in range(n):
+                    tok = int(emitted[slot, j])
+                    st.tokens.append(tok)
+                    self.last_tokens[slot] = tok
+                    consumed += 1
+                    done = self._maybe_finish(slot)
+                    if done:
+                        finished.extend(done)
+                        break
+                else:
+                    # survived the whole span: the engine's bookkeeping token
+                    # at lengths-1 feeds the next draft catch-up
+                    self.prev_tokens[slot] = int(prev_next[slot])
+                    if self.allocator is not None:
+                        # page-granular rollback: pages acquired for the
+                        # rejected tail of the span go back to the free list
+                        # (position prompt+tokens-1 is the next write — its
+                        # page stays); the reservation credit they drew is
+                        # refunded so the same slot can re-acquire them
+                        new_len = st.prompt.shape[0] + len(st.tokens) - 1
+                        self.allocator.release_tail(slot, new_len)
+                consumed_total += consumed
+                step_counts[slot] = consumed
+            self.tokens_generated += consumed_total
+            self.tokens_per_forward.add(consumed_total)
+            put_metric("serving.tokens_generated", consumed_total)
+            if self.emit_events:
+                record_event(
+                    "serving.spec_step", source="scheduler",
+                    proposed=k * n_act, accepted=accepted,
+                    consumed=step_counts,
+                )
+            consume.set_metadata(
+                tokens=consumed_total, finished=len(finished) - n_before)
         return finished
 
     def swap_params(self, params, *, draft_params=None,
@@ -377,53 +406,61 @@ class Scheduler:
     def _admit(self, slot: int, req: Request,
                plan=None) -> List[FinishedRequest]:
         prompt = np.asarray(req.prompt, np.int32).reshape(-1)
-        t0 = time.perf_counter()
-        cached_len = 0
-        if self.allocator is not None:
-            if plan is None:
-                plan = self._plan_admission(req)
+        queue_s = time.perf_counter() - req.arrival_s
+        with span("sched.admit", request_id=req.request_id, slot=slot,
+                  prompt_len=int(prompt.shape[0]),
+                  queue_us=int(queue_s * 1e6)) as admit:
+            cached_len = 0
+            if self.allocator is not None:
                 if plan is None:
-                    raise RuntimeError(
-                        f"page reservation failed for request "
-                        f"{req.request_id}"
-                    )
-            cached_len = self._attach_pages(slot, plan)
-        self.cache, first_tok = self.engine.prefill(
-            self.cache, slot, prompt, cached_len=cached_len
-        )
-        if self.draft_cache is not None:
-            # the separate draft's slotted cache has no prefix sharing —
-            # it always prefills the full prompt
-            self.draft_cache = self.engine.prefill_draft(
-                self.draft_cache, slot, prompt
+                    plan = self._plan_admission(req)
+                    if plan is None:
+                        raise RuntimeError(
+                            f"page reservation failed for request "
+                            f"{req.request_id}"
+                        )
+                cached_len = self._attach_pages(slot, plan)
+            admit.set_metadata(cached_len=cached_len)
+            self.cache, first_tok = self.engine.prefill(
+                self.cache, slot, prompt, cached_len=cached_len,
+                request_id=req.request_id,
             )
-        if self.radix is not None:
-            # cache the prompt's full pages for future admissions (pins
-            # them in the allocator so they outlive this sequence)
-            self.radix.insert(prompt, self.allocator.chain(slot),
-                              self.allocator)
-        # token at position lengths-1 == the prompt tail (draft catch-up)
-        self.prev_tokens[slot] = int(prompt[-1])
-        ttft = time.perf_counter() - t0
-        self.ttft.add(ttft)
-        self.slots[slot] = _SlotState(
-            request=req, prompt=prompt, tokens=[first_tok],
-            admitted_at=t0, ttft_s=ttft,
-        )
-        self.last_tokens[slot] = first_tok
-        self.active[slot] = True
-        self.tokens_generated += 1
-        self.prefill_tokens_total += int(prompt.shape[0])
-        self.prefill_tokens_cached += cached_len
-        if self.emit_events:
-            record_event(
-                "serving.admit", source="scheduler",
-                request_id=req.request_id, slot=slot,
-                prompt_len=int(prompt.shape[0]), ttft_s=ttft,
-                cached_len=cached_len,
+            if self.draft_cache is not None:
+                # the separate draft's slotted cache has no prefix sharing —
+                # it always prefills the full prompt
+                self.draft_cache = self.engine.prefill_draft(
+                    self.draft_cache, slot, prompt
+                )
+            if self.radix is not None:
+                # cache the prompt's full pages for future admissions (pins
+                # them in the allocator so they outlive this sequence)
+                self.radix.insert(prompt, self.allocator.chain(slot),
+                                  self.allocator)
+            # token at position lengths-1 == the prompt tail (draft catch-up)
+            self.prev_tokens[slot] = int(prompt[-1])
+            # from ARRIVAL: the wait in the queue is part of the time to the
+            # first token (an open loop's requests wait for a free slot)
+            ttft = time.perf_counter() - req.arrival_s
+            self.ttft.add(ttft)
+            self.slots[slot] = _SlotState(
+                request=req, prompt=prompt, tokens=[first_tok],
+                queue_s=queue_s, ttft_s=ttft,
             )
-        # the prefill's own sampled token may already end the request
-        return self._maybe_finish(slot)
+            self.last_tokens[slot] = first_tok
+            self.active[slot] = True
+            self._n_active += 1
+            self.tokens_generated += 1
+            self.prefill_tokens_total += int(prompt.shape[0])
+            self.prefill_tokens_cached += cached_len
+            if self.emit_events:
+                record_event(
+                    "serving.admit", source="scheduler",
+                    request_id=req.request_id, slot=slot,
+                    prompt_len=int(prompt.shape[0]), ttft_s=ttft,
+                    queue_s=queue_s, cached_len=cached_len,
+                )
+            # the prefill's own sampled token may already end the request
+            return self._maybe_finish(slot)
 
     def _attach_pages(self, slot: int, plan) -> int:
         """Paged admission: attach the radix-matched chain by reference,
@@ -463,33 +500,39 @@ class Scheduler:
 
     def _evict(self, slot: int, reason: str) -> FinishedRequest:
         st = self.slots[slot]
-        total = time.perf_counter() - st.admitted_at
-        if self.allocator is not None:
-            # drop the slot's reference on every chain page: private pages
-            # go straight back to the free list; radix-pinned prompt pages
-            # stay resident for the next same-prefix admission
-            self.allocator.free_slot(slot)
-        self.cache = self.cache.evict(slot)
-        self.slots[slot] = None
-        self.active[slot] = False
-        fin = FinishedRequest(
-            request_id=st.request.request_id,
-            prompt=st.prompt,
-            tokens=list(st.tokens),
-            reason=reason,
-            ttft_s=st.ttft_s,
-            total_s=total,
-        )
-        if self.emit_events:
-            record_event(
-                "serving.request_finished", source="scheduler",
-                request_id=fin.request_id, slot=slot, reason=reason,
-                prompt_len=int(st.prompt.shape[0]),
-                new_tokens=len(fin.tokens),
-                ttft_s=fin.ttft_s, total_s=fin.total_s,
+        with span("sched.evict", request_id=st.request.request_id,
+                  new_tokens=len(st.tokens), reason=reason):
+            total = time.perf_counter() - st.request.arrival_s
+            if self.allocator is not None:
+                # drop the slot's reference on every chain page: private
+                # pages go straight back to the free list; radix-pinned
+                # prompt pages stay resident for the next same-prefix
+                # admission
+                self.allocator.free_slot(slot)
+            self.cache = self.cache.evict(slot)
+            self.slots[slot] = None
+            self.active[slot] = False
+            self._n_active -= 1
+            fin = FinishedRequest(
+                request_id=st.request.request_id,
+                prompt=st.prompt,
+                tokens=list(st.tokens),
+                reason=reason,
+                ttft_s=st.ttft_s,
+                total_s=total,
+                queue_s=st.queue_s,
             )
-        put_metric("serving.requests_finished")
-        return fin
+            if self.emit_events:
+                record_event(
+                    "serving.request_finished", source="scheduler",
+                    request_id=fin.request_id, slot=slot, reason=reason,
+                    prompt_len=int(st.prompt.shape[0]),
+                    new_tokens=len(fin.tokens),
+                    ttft_s=fin.ttft_s, total_s=fin.total_s,
+                    queue_s=fin.queue_s,
+                )
+            put_metric("serving.requests_finished")
+            return fin
 
     # -- stats -------------------------------------------------------------
     def stats(self) -> Dict[str, float]:

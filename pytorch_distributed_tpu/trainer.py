@@ -67,6 +67,9 @@ __all__ = [
 # -- built-in task losses --------------------------------------------------
 # signature: loss_fn(model, variables, batch, train, rngs)
 #   -> (loss, (new_model_state, metrics))
+# Each wraps what follows the model's forward in ``jax.named_scope("loss")``
+# so the device trace can tell the loss from the model (whose ops Flax
+# scopes by module name); a custom loss_fn does the same to be told apart.
 
 def classification_loss(model, variables, batch, train: bool, rngs=None):
     """Softmax cross-entropy on ``(images, labels)`` — the ResNet configs.
@@ -100,17 +103,18 @@ def classification_loss(model, variables, batch, train: bool, rngs=None):
     else:
         logits = model.apply(variables, x, train=False)
         new_model_state = {k: v for k, v in variables.items() if k != "params"}
-    per_ex = optax.softmax_cross_entropy_with_integer_labels(
-        logits.astype(jnp.float32), y
-    )
-    hit = (jnp.argmax(logits, -1) == y).astype(jnp.float32)
-    if mask is None:
-        loss = per_ex.mean()
-        acc = hit.mean()
-    else:
-        n = jnp.maximum(mask.sum(), 1.0)
-        loss = (per_ex * mask).sum() / n
-        acc = (hit * mask).sum() / n
+    with jax.named_scope("loss"):
+        per_ex = optax.softmax_cross_entropy_with_integer_labels(
+            logits.astype(jnp.float32), y
+        )
+        hit = (jnp.argmax(logits, -1) == y).astype(jnp.float32)
+        if mask is None:
+            loss = per_ex.mean()
+            acc = hit.mean()
+        else:
+            n = jnp.maximum(mask.sum(), 1.0)
+            loss = (per_ex * mask).sum() / n
+            acc = (hit * mask).sum() / n
     return loss, (new_model_state, {"accuracy": acc})
 
 
@@ -149,10 +153,11 @@ def lm_loss(model, variables, batch, train: bool, rngs=None):
     )
     # MoE models return (logits, weighted router aux loss)
     logits, moe_aux = out if isinstance(out, tuple) else (out, None)
-    per_tok = optax.softmax_cross_entropy_with_integer_labels(
-        logits.astype(jnp.float32), targets
-    )  # [B, T]
-    return _reduce_lm_loss(per_tok, mask, moe_aux, train)
+    with jax.named_scope("loss"):
+        per_tok = optax.softmax_cross_entropy_with_integer_labels(
+            logits.astype(jnp.float32), targets
+        )  # [B, T]
+        return _reduce_lm_loss(per_tok, mask, moe_aux, train)
 
 
 def make_chunked_lm_loss(n_chunks: int = 8) -> Callable:
@@ -183,11 +188,13 @@ def make_chunked_lm_loss(n_chunks: int = 8) -> Callable:
         )
         hidden, moe_aux = out if isinstance(out, tuple) else (out, None)
         B, T, C = hidden.shape
-        W = variables["params"]["wte"].astype(hidden.dtype)
-        per_tok = chunked_cross_entropy(
-            hidden.reshape(B * T, C), W, targets.reshape(-1), n_chunks
-        ).reshape(B, T)
-        return _reduce_lm_loss(per_tok, mask, moe_aux, train)
+        # the tied head lives inside the chunked loss on this path
+        with jax.named_scope("loss"):
+            W = variables["params"]["wte"].astype(hidden.dtype)
+            per_tok = chunked_cross_entropy(
+                hidden.reshape(B * T, C), W, targets.reshape(-1), n_chunks
+            ).reshape(B, T)
+            return _reduce_lm_loss(per_tok, mask, moe_aux, train)
 
     return lm_loss_chunked
 
@@ -358,7 +365,8 @@ class Trainer:
             loss, (new_ms, metrics) = loss_fn(
                 model, variables, batch, True, rngs
             )
-            scaled = loss * scale.astype(loss.dtype)
+            with jax.named_scope("loss"):
+                scaled = loss * scale.astype(loss.dtype)
             return scaled, (loss, new_ms, metrics)
 
         grad_fn = jax.grad(forward, has_aux=True)
@@ -429,10 +437,12 @@ class Trainer:
                 g, loss, ms, metrics = compute_grads(
                     params, model_state, batch, scale, step_rng
                 )
-                if stateful_hook:
-                    comm_state, g = hook.apply(comm_state, g, dp_axis, step)
-                else:
-                    g = hook(g, dp_axis)
+                with jax.named_scope("grad_sync"):
+                    if stateful_hook:
+                        comm_state, g = hook.apply(
+                            comm_state, g, dp_axis, step)
+                    else:
+                        g = hook(g, dp_axis)
                 loss = jax.lax.pmean(loss, dp_axis)
                 metrics = jtu.tree_map(
                     lambda m: jax.lax.pmean(m, dp_axis), metrics
@@ -505,21 +515,25 @@ class Trainer:
                 all_finite = jnp.bool_(True)
                 new_scaler = state.scaler
 
-            grad_norm = optax.global_norm(grads)
-            if clip_norm is not None:
-                factor = jnp.minimum(1.0, clip_norm / (grad_norm + 1e-6))
-                grads = jtu.tree_map(lambda g: g * factor, grads)
+            with jax.named_scope("grad_clip"):
+                grad_norm = optax.global_norm(grads)
+                if clip_norm is not None:
+                    factor = jnp.minimum(1.0, clip_norm / (grad_norm + 1e-6))
+                    grads = jtu.tree_map(lambda g: g * factor, grads)
 
-            if use_sharded_update:
-                # shard-local optimizer step + all-gather of updated params
-                new_params, new_opt_state = _zero.apply_sharded_update(
-                    optimizer, strategy, grads, state.opt_state, state.params
-                )
-            else:
-                updates, new_opt_state = optimizer.update(
-                    grads, state.opt_state, state.params
-                )
-                new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("optimizer"):
+                if use_sharded_update:
+                    # shard-local optimizer step + all-gather of updated
+                    # params
+                    new_params, new_opt_state = _zero.apply_sharded_update(
+                        optimizer, strategy, grads, state.opt_state,
+                        state.params,
+                    )
+                else:
+                    updates, new_opt_state = optimizer.update(
+                        grads, state.opt_state, state.params
+                    )
+                    new_params = optax.apply_updates(state.params, updates)
 
             # skip-on-inf: keep old state wherever the step was non-finite
             def pick(new, old):

@@ -321,6 +321,29 @@ def test_worker_rejects_oversized_inbox_entry(tiny):
     assert w.scheduler.n_active == 0
 
 
+def test_queue_wait_of_a_routed_request_counts_from_the_router(tiny):
+    """The router stamps the wall clock at which it took a request in; the
+    worker turns it into an age on its own clock, so the time a request
+    spent in the router and on the wire is part of its queue wait, and the
+    router hands the host's ``queue_s`` on."""
+    store = HashStore()
+    w = make_worker(store, tiny, "host0")
+    w.register()
+    router = Router(store, heartbeat_ttl_s=30.0)
+    router.submit(Request(prompt=[3, 1, 4], max_new_tokens=2,
+                          arrival_s=time.perf_counter() - 0.2))
+    router.step()                  # routes: the request is on the wire
+    time.sleep(0.1)
+    finished = []
+    deadline = time.monotonic() + 60
+    while not finished and time.monotonic() < deadline:
+        w.step()
+        finished.extend(router.step())
+    (fin,) = finished
+    assert fin.queue_s >= 0.3      # 0.2 s before the router, 0.1 s after
+    assert fin.total_s >= 0.1
+
+
 def test_duplicate_request_id_rejected(tiny):
     router = Router(HashStore())
     router.submit(Request(prompt=[1, 2], max_new_tokens=2, request_id=5))
