@@ -93,15 +93,18 @@ def default_attention(q, k, v, *, causal: bool = True):
 class SelfAttention(nn.Module):
     # ``layer_cache``/``position_offset`` switch on the serving decode path
     # (pytorch_distributed_tpu.serving): K/V for the T new tokens are
-    # scattered into the preallocated per-slot cache and attention runs
-    # densely over the whole slot (ops.decode_attention — the Pallas flash
-    # kernel's T x T blocking doesn't apply at T=1). With layer_cache=None
-    # the training path is untouched.
+    # scattered into the preallocated cache and attention runs densely over
+    # each slot (ops.decode_attention — the Pallas flash kernel's T x T
+    # blocking doesn't apply at T=1). The slotted cache arrives WHOLE,
+    # ``(k, v)`` of ``[L, S, Tmax, H*D]`` with ``cache_layer`` naming this
+    # block's layer, and goes back whole: rows are written where they lie.
+    # The paged cache arrives as this layer's ``(k_pages, v_pages,
+    # block_tables)``. With layer_cache=None the training path is untouched.
     cfg: GPT2Config
 
     @nn.compact
     def __call__(self, x, *, deterministic: bool = True, layer_cache=None,
-                 position_offset=None):
+                 cache_layer=None, position_offset=None):
         cfg = self.cfg
         B, T, C = x.shape
         H, D = cfg.n_head, cfg.n_embd // cfg.n_head
@@ -134,7 +137,8 @@ class SelfAttention(nn.Module):
             )
 
             y, ck, cv = cached_attention(
-                q, k, v, layer_cache[0], layer_cache[1], position_offset
+                q, k, v, layer_cache[0], layer_cache[1], cache_layer,
+                position_offset,
             )
             new_cache = (ck, cv)
         y = y.reshape(B, T, C)
@@ -173,7 +177,7 @@ class Block(nn.Module):
     # it static (static_argnums) — a traced boolean would crash nn.Dropout.
     @nn.compact
     def __call__(self, x, deterministic: bool = True, *, layer_cache=None,
-                 position_offset=None):
+                 cache_layer=None, position_offset=None):
         cfg = self.cfg
         ln = lambda name: nn.LayerNorm(
             epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
@@ -183,7 +187,8 @@ class Block(nn.Module):
             # configs), returns the updated cache beside the residual
             y, new_cache = SelfAttention(cfg, name="attn")(
                 ln("ln_1")(x), deterministic=deterministic,
-                layer_cache=layer_cache, position_offset=position_offset)
+                layer_cache=layer_cache, cache_layer=cache_layer,
+                position_offset=position_offset)
             x = x + y
             x = x + MLP(cfg, name="mlp")(
                 ln("ln_2")(x), deterministic=deterministic)
@@ -221,11 +226,12 @@ class GPT2(nn.Module):
     current length of each cache slot), each block attends over its cache
     slot instead of the T x T causal window, and the call returns
     ``(logits, new_kv_cache)``. Prefill is this path at T = padded prompt
-    length with offset 0; decode is T = 1 at offset = slot length, and the
-    speculative verify step is T = k+1 at the same offset (the cached
-    attention masks per-position, so a multi-token window is causal over
-    global positions for free). The training path (``kv_cache=None``) is
-    untouched.
+    length with NO offset (every sequence fresh, from position 0: the
+    slotted attention then never reads the cache); decode is T = 1 at
+    offset = slot length, and the speculative verify step is T = k+1 at
+    the same offset (the cached attention masks per-position, so a
+    multi-token window is causal over global positions for free). The
+    training path (``kv_cache=None``) is untouched.
 
     ``n_layers`` (cached path only) truncates the stack: run the first N
     blocks, then ``ln_f`` + the tied head — the self-drafting draft of
@@ -353,8 +359,6 @@ class GPT2(nn.Module):
             raise ValueError(
                 f"n_layers {nl} must be in [1, n_layer={cfg.n_layer}]"
             )
-        if position_offset is None:
-            position_offset = jnp.zeros((B,), jnp.int32)
         wte = self.param(
             "wte",
             nn.initializers.normal(0.02),
@@ -367,31 +371,43 @@ class GPT2(nn.Module):
             (cfg.n_positions, cfg.n_embd),
             cfg.param_dtype,
         )
+        # duck-typed cache dispatch: a paged cache carries block tables and
+        # each layer's K/V is a page pool the sequences index through them
+        paged = hasattr(kv_cache, "block_tables")
         # learned positional embedding at each token's GLOBAL position;
         # clamp guards the padded tail of an over-long prefill (those
-        # query rows are discarded by the engine)
-        pos = position_offset[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
+        # query rows are discarded by the engine). No offset = every
+        # sequence starts at 0, which the slotted attention is told as
+        # such (a fresh prefill never reads the cache).
+        pos = jnp.arange(T, dtype=jnp.int32)[None]
+        if position_offset is not None:
+            pos = position_offset[:, None] + pos
+        elif paged:
+            position_offset = jnp.zeros((B,), jnp.int32)
         pos = jnp.minimum(pos, cfg.n_positions - 1)
         x = wte[tokens].astype(cfg.dtype) + wpe[pos].astype(cfg.dtype)
 
         constrain = cfg.act_constraint or (lambda a: a)
         x = constrain(x)
-        # duck-typed cache dispatch: a paged cache carries block tables and
-        # each layer's K/V is a page pool the sequences index through them
-        paged = hasattr(kv_cache, "block_tables")
+        # the slotted cache threads through the blocks whole: each writes
+        # its own layer's rows into the one (donated) array
+        k, v = kv_cache.k, kv_cache.v
         new_k, new_v = [], []
         for i in range(nl):
             layer_cache = (
                 (kv_cache.k[i], kv_cache.v[i], kv_cache.block_tables)
-                if paged else (kv_cache.k[i], kv_cache.v[i])
+                if paged else (k, v)
             )
             x, (ck, cv) = Block(cfg, False, name=f"h_{i}")(
                 x, deterministic,
-                layer_cache=layer_cache,
+                layer_cache=layer_cache, cache_layer=i,
                 position_offset=position_offset,
             )
-            new_k.append(ck)
-            new_v.append(cv)
+            if paged:
+                new_k.append(ck)
+                new_v.append(cv)
+            else:
+                k, v = ck, cv
             x = constrain(x)
 
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
@@ -406,7 +422,10 @@ class GPT2(nn.Module):
                 "btc,vc->btv", x, wte.astype(cfg.dtype),
                 preferred_element_type=jnp.float32,
             )
-        if nl == cfg.n_layer:
+        if not paged:
+            # a truncated draft wrote only the first nl layers' rows
+            new_cache = kv_cache.replace(k=k, v=v)
+        elif nl == cfg.n_layer:
             new_cache = kv_cache.replace(
                 k=jnp.stack(new_k), v=jnp.stack(new_v)
             )

@@ -4,16 +4,23 @@ Compiled programs serve the whole session (the prefill/decode split of
 every production LLM server — Orca, vLLM, TGI):
 
   * ``prefill`` — one request's padded prompt ``[1, bucket]`` runs through
-    the cache-aware forward into ONE slot of the shared cache (sliced out
-    with ``dynamic_slice`` so compute is O(prompt), not O(slots x prompt)),
-    and the first generated token is sampled from the last real prompt
-    position's logits. Prompts pad to the smallest LENGTH BUCKET (powers
-    of two up to ``prefill_len``) so short prompts stop paying full-length
-    prefill compute; jit caches one program per bucket.
+    the cache-aware forward as a FRESH sequence (no position offset: its
+    tokens attend each other, O(bucket^2), and nothing of the resident
+    cache is read), its K/V rows land in ONE slot of the shared cache as
+    one ``[L, 1, bucket, H*D]`` block, and the first generated token is
+    sampled from the last real prompt position's logits. Prompts pad to
+    the smallest LENGTH BUCKET (powers of two up to ``prefill_len``) so
+    short prompts stop paying full-length prefill compute; jit caches one
+    program per bucket.
   * ``decode``  — ``[n_slots, 1]``: every slot advances one token per call,
     attention runs over each slot's cache, and only ACTIVE slots' lengths
     advance (free slots ride along as padding — the decode batch shape
-    never changes, so the program compiles exactly once).
+    never changes, so the program compiles exactly once). The slotted
+    cache is stored ``[L, S, max_len, H*D]`` (``serving.kv_cache``): the
+    step scatters ``2 * L * S`` rows into the donated arrays where they
+    lie and reads K and V once, as stored — no layer's slab is copied,
+    re-laid-out, sliced out or rebuilt (``tests/test_chip_compile.py``
+    holds the compiled program to it).
   * ``spec``    — speculative decoding (``spec_k > 0``): a cheap draft
     proposes k tokens per slot into scratch cache positions past each
     slot's length, then ONE target forward over the ``[S, k+1]`` window
@@ -25,8 +32,9 @@ every production LLM server — Orca, vLLM, TGI):
     1.0 to ``1 / (1 + E[accepts])``. Both the draft and verify programs
     compile once — no realloc, no shape churn.
 
-All step programs donate the cache pytree: K/V updates are in-place HBM
-writes.
+All step programs donate the cache pytree, and the cached forward threads
+the whole K/V arrays through its layers (``models.gpt2``), each layer
+writing its own rows: K/V updates are in-place HBM writes.
 
 Sampling (greedy / temperature / top-k / nucleus top-p) happens inside the
 jitted step — only sampled token ids cross the host boundary each step,
@@ -118,21 +126,25 @@ def _default_buckets(prefill_len: int) -> Tuple[int, ...]:
 
 def _slot_prefill(apply_fn, params, cache, tokens, slot, prompt_len):
     """Run ``tokens [1, bucket]`` through ``apply_fn`` into one slot of
-    ``cache`` (sliced out so compute is O(bucket), not O(slots x bucket));
-    returns ``(logits, cache)`` with ``lengths[slot] = prompt_len``."""
-    sub = KVCache(
-        k=jax.lax.dynamic_slice_in_dim(cache.k, slot, 1, axis=1),
-        v=jax.lax.dynamic_slice_in_dim(cache.v, slot, 1, axis=1),
-        lengths=jnp.zeros((1,), jnp.int32),
-    )
-    logits, new_sub = apply_fn(
+    ``cache``; returns ``(logits, cache)`` with ``lengths[slot] =
+    prompt_len``. The prompt is the slot's first occupant from position 0,
+    so nothing of the resident cache is read: the forward runs on a fresh
+    one-slot cache of exactly ``bucket`` positions with no position offset
+    (the new tokens attend each other, O(bucket^2)), and its rows land in
+    the resident cache as one ``[L, 1, bucket, H*D]`` block, in place."""
+    n_layers, _, _, width = cache.k.shape
+    rows = jnp.zeros((n_layers, 1, tokens.shape[1], width), cache.k.dtype)
+    sub = KVCache(k=rows, v=rows, lengths=jnp.zeros((1,), jnp.int32))
+    logits, sub = apply_fn(
         params, tokens, deterministic=True,
-        kv_cache=sub, position_offset=jnp.zeros((1,), jnp.int32),
+        kv_cache=sub, position_offset=None,
     )
-    k = jax.lax.dynamic_update_slice_in_dim(cache.k, new_sub.k, slot, axis=1)
-    v = jax.lax.dynamic_update_slice_in_dim(cache.v, new_sub.v, slot, axis=1)
-    lengths = cache.lengths.at[slot].set(prompt_len)
-    return logits, cache.replace(k=k, v=v, lengths=lengths)
+    at = (0, slot, 0, 0)
+    return logits, cache.replace(
+        k=jax.lax.dynamic_update_slice(cache.k, sub.k, at),
+        v=jax.lax.dynamic_update_slice(cache.v, sub.v, at),
+        lengths=cache.lengths.at[slot].set(prompt_len),
+    )
 
 
 class InferenceEngine:

@@ -1,11 +1,22 @@
 """Preallocated slotted KV cache — the serving engine's resident state.
 
 One cache = ``n_slots`` independent sequence slots, each ``max_len`` tokens
-deep, for every layer: ``k``/``v`` are ``[L, S, max_len, H, D]`` arrays that
+deep, for every layer: ``k``/``v`` are ``[L, S, max_len, H*D]`` arrays that
 live in device memory across the whole serving session and thread through
-the jitted prefill/decode steps as a donated pytree (in-place HBM updates,
-no realloc, no shape churn — the static-shape analogue of vLLM's paged
-pool with page size = max_len; per-slot lengths are the page table).
+the jitted prefill/decode steps as a donated pytree (no realloc, no shape
+churn — the static-shape analogue of vLLM's paged pool with page size =
+max_len; per-slot lengths are the page table).
+
+The heads are folded into the minor dimension so that a token's K (or V)
+of one layer is ONE contiguous ``H*D``-wide row. The TPU pads the minor
+dimension to 128 lanes: ``[..., H, D]`` with D = 64 made the compiler keep
+the cache positions-minor and copy every layer's slab to a head-dim-minor
+layout and back around each one-row write (37.8 of a 51 ms decode step,
+PERF.md PR 25). A 768-wide row needs no padding and serves both the row
+write and the attention read (``ops.decode_attention`` contracts against
+the rows as stored), so a decode step writes ``2 * L * S`` rows in place
+and reads K and V once: ``tests/test_chip_compile.py`` holds the compiled
+decode program to that.
 
 Slot lifecycle (driven by serving.scheduler):
   * admit   — prefill writes positions ``0..Tpad-1`` of a free slot and
@@ -31,11 +42,12 @@ __all__ = ["KVCache"]
 
 
 class KVCache(struct.PyTreeNode):
-    """Per-layer K/V arrays ``[L, S, T, H, D]`` + per-slot ``lengths [S]``.
+    """Per-layer K/V arrays ``[L, S, T, H*D]`` + per-slot ``lengths [S]``.
 
     A plain pytree: jit-carried, donatable, shardable (the serving TP plan
-    puts the head dim on the ``tp`` axis, matching the colwise-sharded
-    ``c_attn`` that produces it — see serving.sharding).
+    puts the folded head dim on the ``tp`` axis — whole heads per device
+    when ``tp`` divides ``H`` — matching the colwise-sharded ``c_attn``
+    that produces it; see serving.sharding).
     """
 
     k: jax.Array
@@ -63,8 +75,7 @@ class KVCache(struct.PyTreeNode):
             )
         if n_slots < 1:
             raise ValueError("n_slots must be >= 1")
-        H, D = cfg.n_head, cfg.n_embd // cfg.n_head
-        shape = (cfg.n_layer, n_slots, max_len, H, D)
+        shape = (cfg.n_layer, n_slots, max_len, cfg.n_embd)
         dtype = dtype or cfg.dtype
         return cls(
             k=jnp.zeros(shape, dtype),
@@ -88,8 +99,8 @@ class KVCache(struct.PyTreeNode):
     def bytes_per_slot(self) -> int:
         """HBM footprint of one slot (both K and V, all layers)."""
         per = self.k.dtype.itemsize
-        L, _, T, H, D = self.k.shape
-        return 2 * L * T * H * D * per
+        L, _, T, C = self.k.shape
+        return 2 * L * T * C * per
 
     def evict(self, slot) -> "KVCache":
         """Free a slot (host or traced int). K/V bytes stay — masked out."""

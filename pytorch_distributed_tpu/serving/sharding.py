@@ -16,9 +16,12 @@ glues the two with the checkpoint layer's reshard-on-load:
      read off disk, and no host ever materializes a full replica.
 
 The KV cache shards on the HEAD dim (``kv_cache_sharding``): colwise
-``c_attn`` emits head-sharded K/V, cached attention contracts per-head, and
-rowwise ``c_proj`` closes the block with the one all-reduce — decode runs
-the exact Megatron collective pattern of training.
+``c_attn`` emits head-sharded K/V, the slotted cache folds the heads into
+its minor ``H*D`` dimension and splits that over tp (whole heads per device
+when tp divides H), and rowwise ``c_proj`` closes the block with its
+all-reduce. Cached attention contracts against the folded rows
+(``ops.decode_attention``), so under tp the scores of the slotted path are
+summed across the shards as well (every head is non-zero on one shard).
 
 orbax is imported inside functions only: ``import
 pytorch_distributed_tpu.serving`` stays dependency-light.
@@ -120,10 +123,11 @@ def draft_param_shardings(
 def kv_cache_sharding(
     mesh: DeviceMesh, *, tp_axis: str = "tp", dp_axis: Optional[str] = None
 ) -> NamedSharding:
-    """Layout for the ``[L, S, T, H, D]`` K/V arrays: heads on tp (matching
-    the colwise c_attn that writes them); optionally slots on dp."""
+    """Layout for the ``[L, S, T, H*D]`` K/V arrays: the folded head dim on
+    tp (matching the colwise c_attn that writes them; tp must divide H so
+    that no head straddles two devices); optionally slots on dp."""
     return NamedSharding(
-        mesh.jax_mesh, P(None, dp_axis, None, tp_axis, None)
+        mesh.jax_mesh, P(None, dp_axis, None, tp_axis)
     )
 
 

@@ -14,10 +14,16 @@ forward+backward in both kernel families — the grid-pruned static-causal
 one, and the positional one ring attention hops through
 (``q_pos``/``kv_pos``; different ``pallas_call``s) — at T=1024 and at one
 long T=8192, and ``paged_decode_attention`` at page sizes 16 and 128.
+
+One whole program is held the same way: the serving engine's decode step at
+the shapes of the ``gpt2-125m.serve-chat`` cell must write the slotted KV
+cache where it lies (PERF.md, PR 25) — the compiled module is the counter
+of that mechanism, so it engages always or the test fails.
 """
 
 import functools
 import os
+import re
 
 import pytest
 
@@ -106,3 +112,89 @@ def test_paged_decode_attention_compiles_for_v5e(v5e_device, page_size,
          ((slots, max_pages), jnp.int32), ((slots,), jnp.int32)],
         v5e_device,
     )
+
+
+def _computations(hlo_text):
+    """``{computation: [(name, opcode, elements, line), ...]}`` of a
+    compiled module's text, and the names of the computations that are
+    bodies of fusions."""
+    fused = set(re.findall(r"kind=k\w+, calls=%?([\w.\-]+)", hlo_text))
+    found, body = {}, None
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):
+            head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+            body = found.setdefault(head.group(1), []) if head else None
+            continue
+        inst = re.match(
+            r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(",
+            line)
+        if inst and body is not None:
+            name, dims, opcode = inst.groups()
+            elements = 1
+            for d in filter(None, dims.split(",")):
+                elements *= int(d)
+            body.append((name, opcode, elements, line))
+    return found, fused
+
+
+def test_decode_program_writes_the_cache_in_place_for_v5e(v5e_device):
+    """64 slots x 1024 positions of GPT-2 125M in bf16, the cache donated:
+    the step keeps under a tenth of the cache's bytes in temporaries,
+    aliases every cache leaf to an output, and moves nothing the size of
+    a layer's slab or of the cache except the 2 x 12 in-place row writes:
+    no ``copy``, no ``transpose``, no slab sliced out or rebuilt."""
+    from pytorch_distributed_tpu.analysis.ir.hlo import aliased_param_indices
+    from pytorch_distributed_tpu.models.gpt2 import GPT2, GPT2Config
+    from pytorch_distributed_tpu.serving import InferenceEngine
+
+    slots, max_len = 64, 1024
+    model = GPT2(GPT2Config(dtype=jnp.bfloat16, param_dtype=jnp.float32))
+    cfg = model.cfg
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=v5e_device), tree)
+
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    engine = InferenceEngine(model, params, n_slots=slots, max_len=max_len)
+    cache = described(jax.eval_shape(engine.init_cache))
+    compiled = engine._decode.lower(
+        described(params), cache,
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_device),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e_device),
+        described(jax.eval_shape(lambda: jax.random.key(0))),
+    ).compile()
+
+    slab = slots * max_len * cfg.n_embd
+    leaves = jax.tree_util.tree_leaves(cache)
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < cache_bytes / 10, memory
+    # k, v and lengths (the arguments after the weights) each alias an
+    # output: the donation took
+    text = compiled.as_text()
+    first = len(jax.tree_util.tree_leaves(params))
+    assert aliased_param_indices(text) == list(
+        range(first, first + len(leaves)))
+
+    computations, fused = _computations(text)
+    relayouts = [line for body in computations.values()
+                 for _, opcode, elements, line in body
+                 if opcode in ("copy", "transpose") and elements >= slab]
+    assert not relayouts, relayouts[:3]
+    # what the step materialises at a slab's size or more, outside fusions
+    big = [(name, opcode, line)
+           for c, body in computations.items() if c not in fused
+           for name, opcode, elements, line in body if elements >= slab
+           and opcode not in ("parameter", "get-tuple-element", "tuple",
+                              "bitcast")]
+    assert len(big) == 2 * cfg.n_layer, [name for name, *_ in big]
+    for name, opcode, line in big:
+        called = re.search(r"calls=%?([\w.\-]+)", line)
+        assert opcode == "fusion" and called, line
+        # a row write into the whole (aliased) cache and nothing else big
+        ops = [op for _, op, n, _ in computations[called.group(1)]
+               if n >= slab and op not in ("parameter", "bitcast")]
+        assert ops == ["scatter"], (name, ops)

@@ -90,7 +90,8 @@ def engine_greedy(engine, cache, slot, prompt, n_tokens):
 def test_kv_cache_shapes_and_evict(tiny):
     model, _ = tiny
     cache = KVCache.create(model.cfg, n_slots=3, max_len=16)
-    assert cache.k.shape == (2, 3, 16, 4, 12)
+    # heads folded into the minor dim: one 48-wide row a token and layer
+    assert cache.k.shape == (2, 3, 16, 4 * 12)
     assert cache.v.shape == cache.k.shape
     assert cache.lengths.shape == (3,)
     assert cache.n_layers == 2 and cache.n_slots == 3 and cache.max_len == 16
