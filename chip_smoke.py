@@ -175,10 +175,14 @@ def _compiled_step_facts(name, state, placed, runner, strategy) -> dict:
     donation of every state leaf realized in ``input_output_alias``, and —
     across devices — the tensor-grade collectives the strategy's
     ``collective_signature()`` promises: a gradient reduction, and
-    parameter all-gathers under FSDP or none under DP. Families the
-    signature forbids are reported, not refused: it was written from the
-    CPU partitioner's output, and the TPU's spells resharding with
-    all-to-all and collective-permute as well."""
+    parameter all-gathers under FSDP or none under DP, and under FSDP no
+    collective on an activation (``"activations": "local"``: everything on
+    the wire is a parameter, a gradient or a shard of one, bar the
+    embedding's row exchange: an all-to-all of the looked-up rows and the
+    gather of the token ids). Families the signature forbids are reported,
+    not refused: it was written from the CPU partitioner's output, and the
+    TPU's spells a reduce-scatter as a ring of collective-permutes and the
+    row exchange as an all-to-all."""
     import jax.tree_util as jtu
 
     from pytorch_distributed_tpu.analysis.ir import hlo
@@ -195,8 +199,19 @@ def _compiled_step_facts(name, state, placed, runner, strategy) -> dict:
     if strategy.mesh.size() == 1:
         return facts
     sig = strategy.collective_signature()
-    tensor = hlo.summarize_collectives(hlo.collective_inventory(text))["tensor"]
+    ops = hlo.collective_inventory(text)
+    tensor = hlo.summarize_collectives(ops)["tensor"]
     families = set(tensor)
+    if sig.get("activations") == "local":
+        counts = hlo.parameter_element_counts(
+            (leaf.shape for leaf in jtu.tree_leaves(state.params)),
+            [strategy.mesh.size()])
+        moved = [op.describe()
+                 for op in hlo.activation_collectives(ops, counts)
+                 if op.family != "all-to-all" and op.dtype != "s32"]
+        _require(not moved,
+                 f"{name}: the strategy pins activations to the batch "
+                 f"layout, but the compiled step moves {moved}")
     _require(not sig["grad_reduce"] or families & hlo.REDUCE_FAMILIES,
              f"{name}: no tensor-grade all-reduce/reduce-scatter in the "
              f"compiled step — gradients are not synchronized ({tensor})")

@@ -28,6 +28,7 @@ JSON schema, and fingerprint identity are shared across both tiers.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from pytorch_distributed_tpu.analysis.core import Finding
@@ -144,6 +145,21 @@ def collective_findings(
             findings.append(_finding(
                 "ir-collective-budget", name,
                 f"forbidden collective in train step: {op.describe()}",
+            ))
+
+    if sig.get("activations") == "local":
+        mesh_shape = program.strategy.mesh.jax_mesh.shape
+        param_counts = hlo_mod.parameter_element_counts(
+            (leaf.shape for leaf in jtu.tree_leaves(program.state.params)),
+            list(mesh_shape.values()) + [math.prod(mesh_shape.values())],
+        )
+        for op in hlo_mod.activation_collectives(tensor, param_counts):
+            findings.append(_finding(
+                "ir-collective-budget", name,
+                f"collective on an activation: {op.describe()} is no "
+                f"parameter, gradient or shard of one — the strategy "
+                f"pins activations to the batch layout, the partitioner "
+                f"must gather the parameter instead",
             ))
 
     reduces = [op for op in tensor if op.family in hlo_mod.REDUCE_FAMILIES]

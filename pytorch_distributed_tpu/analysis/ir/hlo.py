@@ -21,8 +21,9 @@ uses for the dryrun gate. Two artifact layers matter:
 from __future__ import annotations
 
 import dataclasses
+import math
 import re
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, Iterable, List, Sequence, Set, Tuple
 
 __all__ = [
     "CollectiveOp",
@@ -34,6 +35,8 @@ __all__ = [
     "aliased_param_indices",
     "intended_alias_count",
     "summarize_collectives",
+    "parameter_element_counts",
+    "activation_collectives",
 ]
 
 #: instruction families the auditor inventories (``-start``/``-done``
@@ -74,7 +77,11 @@ class CollectiveOp:
     """One collective instruction in an HLO module."""
 
     family: str          # base family ("all-reduce", never "-start")
-    dtype: str           # result element type (first tuple element's)
+    # element type and shape of the LARGEST element of the result: the
+    # array that crosses the wire when the result is a -start tuple
+    # ((operand, result) of an all-gather, (operand, result, u32[], u32[])
+    # of a collective-permute) or a combined collective's
+    dtype: str
     shape: Tuple[int, ...]
     bytes: int           # total result bytes (summed over tuple elements)
     scalar: bool         # every result element is rank-0 (loss/metric/
@@ -122,14 +129,12 @@ def collective_inventory(hlo_text: str) -> List[CollectiveOp]:
             continue
         total = 0
         for dtype, dims in shapes:
-            n = 1
-            for d in dims:
-                n *= d
-            total += n * dtype_bytes(dtype)
+            total += math.prod(dims) * dtype_bytes(dtype)
+        moved = max(shapes, key=lambda s: math.prod(s[1]))
         ops.append(CollectiveOp(
             family=m.group(2),
-            dtype=shapes[0][0],
-            shape=shapes[-1][1],
+            dtype=moved[0],
+            shape=moved[1],
             bytes=total,
             scalar=all(not dims for _, dims in shapes),
         ))
@@ -148,6 +153,32 @@ def summarize_collectives(ops: Sequence[CollectiveOp]) -> Dict[str, Dict]:
         row["count"] += 1
         row["bytes"] += op.bytes
     return out
+
+
+def parameter_element_counts(
+    shapes: Iterable[Tuple[int, ...]], shard_counts: Iterable[int]
+) -> Set[int]:
+    """Element counts a collective on a parameter can have: each
+    parameter's own (its gather, its gradient's reduction) and that count
+    over each way the mesh can shard it (a reduce-scatter's result, one
+    step of a ring)."""
+    counts: Set[int] = set()
+    shard_counts = [k for k in shard_counts if k > 1]
+    for shape in shapes:
+        n = math.prod(shape)
+        counts.add(n)
+        counts.update(n // k for k in shard_counts if n % k == 0)
+    return counts
+
+
+def activation_collectives(
+    ops: Sequence[CollectiveOp], param_counts: Set[int]
+) -> List[CollectiveOp]:
+    """The tensor-grade collectives that move no parameter, gradient or
+    shard of one (by element count, which a reshape on the way to the
+    wire keeps): what is left is an activation."""
+    return [op for op in ops
+            if not op.scalar and math.prod(op.shape) not in param_counts]
 
 
 _ALIAS_BLOCK = re.compile(r"input_output_alias=\{(.*?)\s\}", re.S)

@@ -68,13 +68,17 @@ def provision_virtual_devices(n: int = 8) -> bool:
 def _mlp():
     import flax.linen as nn
 
+    from pytorch_distributed_tpu.mesh import pin_activation
+
     class MLP(nn.Module):
         @nn.compact
         def __call__(self, x, train: bool = True):
             x = x.reshape((x.shape[0], -1))
             x = nn.Dense(256)(x)
-            x = nn.relu(x)
-            return nn.Dense(10)(x)
+            # a model's hook site: under FSDP/HSDP the hidden activation
+            # stays batch-sharded and the kernels are gathered
+            x = pin_activation(nn.relu(x))
+            return pin_activation(nn.Dense(10)(x))
 
     return MLP()
 

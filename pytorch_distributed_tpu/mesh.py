@@ -17,6 +17,8 @@ communicator.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import math
 import warnings
 from typing import Optional, Sequence, Union
@@ -25,9 +27,56 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-__all__ = ["DeviceMesh", "init_device_mesh", "init_hybrid_mesh", "P"]
+__all__ = [
+    "DeviceMesh",
+    "init_device_mesh",
+    "init_hybrid_mesh",
+    "P",
+    "activation_layout",
+    "pin_activation",
+]
 
 P = PartitionSpec
+
+#: The layout the program being traced holds its batch-leading activations
+#: to (a ``NamedSharding``), or None. Trace-time state: ``Trainer`` sets it
+#: around the model's forward from what its strategy says
+#: (``ShardingStrategy.activation_pin``), the models read it at their
+#: hook sites through ``pin_activation``. Ambient rather than a field the
+#: trainer binds on the model: it reaches every model (a ResNet has no
+#: ``cfg``), the sites outside the blocks (the hidden state and the logits
+#: the losses consume), and leaves the user's module as it was built.
+_ACTIVATION_LAYOUT: contextvars.ContextVar = contextvars.ContextVar(
+    "pdt_activation_layout", default=None
+)
+
+
+@contextlib.contextmanager
+def activation_layout(sharding: Optional[NamedSharding]):
+    """While this is open, ``pin_activation`` holds arrays to ``sharding``
+    (None: to nothing, whatever an outer context says)."""
+    token = _ACTIVATION_LAYOUT.set(sharding)
+    try:
+        yield
+    finally:
+        _ACTIVATION_LAYOUT.reset(token)
+
+
+def pin_activation(x):
+    """``x`` held to the ambient activation layout: its leading (batch)
+    dimension on the mesh axes the batch is sharded over, the rest
+    unsharded. Returns ``x`` itself, with no operation emitted, where no
+    layout is set (no trainer is tracing, or the strategy has nothing to
+    pin) or where the leading dimension does not divide over those axes (a
+    microbatch smaller than the mesh: the partitioner is left to it)."""
+    sharding = _ACTIVATION_LAYOUT.get()
+    if sharding is None or not getattr(x, "ndim", 0):
+        return x
+    axes = sharding.spec[0]
+    axes = (axes,) if isinstance(axes, str) else axes
+    if x.shape[0] % math.prod(sharding.mesh.shape[a] for a in axes):
+        return x
+    return jax.lax.with_sharding_constraint(x, sharding)
 
 
 class DeviceMesh:

@@ -27,6 +27,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from pytorch_distributed_tpu.mesh import pin_activation
+
 __all__ = ["GPT2Config", "GPT2", "gpt2_125m"]
 
 
@@ -67,7 +69,10 @@ class GPT2Config:
     # embedding and after every block. The TP/SP layer passes
     # ``TensorParallel.activation_constraint()`` here so sequence-parallel
     # activation sharding is pinned in the executed program (Megatron SP —
-    # torch tensor/parallel/style.py:339 SequenceParallel).
+    # torch tensor/parallel/style.py:339 SequenceParallel). Left None, the
+    # same sites take the layout the trainer's strategy states while it
+    # traces (``mesh.pin_activation``: the batch layout under FSDP/HSDP,
+    # nothing anywhere else).
     act_constraint: Optional[Callable] = None
     # LM-head contraction inputs: fp32 casts (the conservative default) or
     # the compute dtype with fp32 ACCUMULATION (preferred_element_type) —
@@ -280,7 +285,7 @@ class GPT2(nn.Module):
         if cfg.dropout > 0:
             x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
 
-        constrain = cfg.act_constraint or (lambda a: a)
+        constrain = cfg.act_constraint or pin_activation
         x = constrain(x)
         block = Block
         if cfg.remat_policy is not None and not cfg.remat:
@@ -307,6 +312,10 @@ class GPT2(nn.Module):
 
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                          param_dtype=cfg.param_dtype, name="ln_f")(x)
+        # what the losses consume lies as the batch does too: the hidden
+        # state here, the logits below (gathered whole on every chip
+        # otherwise, when FSDP shards ``wte``)
+        x = pin_activation(x)
         if return_hidden:
             if cfg.moe_experts > 0:
                 return x, cfg.moe_aux_weight * aux_total
@@ -325,6 +334,7 @@ class GPT2(nn.Module):
                     "btc,vc->btv", x, wte.astype(cfg.dtype),
                     preferred_element_type=jnp.float32,
                 )
+            logits = pin_activation(logits)
         if cfg.moe_experts > 0:
             # weighted router load-balance loss, consumed by lm_loss
             return logits, cfg.moe_aux_weight * aux_total

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
+from pytorch_distributed_tpu.parallel.strategies import ShardingStrategy
 from pytorch_distributed_tpu.parallel.tensor_parallel import ParallelStyle
 
 P = PartitionSpec
@@ -165,7 +166,7 @@ class MoEMLP(nn.Module):
         }
 
 
-class ExpertDataParallel:
+class ExpertDataParallel(ShardingStrategy):
     """Trainer strategy: DDP over ``dp`` + expert params sharded over
     ``ep`` (the first-class EP mesh axis of SURVEY §2.2's build note).
     Non-expert params replicate (DDP); any param whose path contains
@@ -176,12 +177,9 @@ class ExpertDataParallel:
 
     def __init__(self, mesh, dp_axis: str = "dp", ep_axis: str = "ep",
                  expert_key: str = "experts"):
-        from pytorch_distributed_tpu.parallel.strategies import (
-            DataParallel,
-        )
-
-        self._dp = DataParallel(mesh, dp_axis)
-        self.mesh = mesh
+        super().__init__(mesh)
+        if dp_axis not in mesh.axis_names:
+            raise ValueError(f"axis {dp_axis!r} not in mesh {mesh.axis_names}")
         self.dp_axis = dp_axis
         self.ep_axis = ep_axis
         self.expert_key = expert_key
@@ -190,20 +188,7 @@ class ExpertDataParallel:
     def param_pspec(self, path: str, shape):
         if self.expert_key in path:
             return P(self.ep_axis)
-        return self._dp.param_pspec(path, shape)
-
-    def opt_pspec(self, path: str, shape):
-        return self.param_pspec(path, shape)
-
-    def model_state_pspec(self, path: str, shape):
-        return self._dp.model_state_pspec(path, shape)
-
-    def batch_pspec(self):
-        return self._dp.batch_pspec()
-
-    @property
-    def data_shard_count(self):
-        return self._dp.data_shard_count
+        return P()
 
     def describe(self) -> str:
         return (f"ExpertDataParallel(dp={self.dp_axis!r}, "

@@ -81,6 +81,7 @@ from jax import lax
 from jax.sharding import PartitionSpec
 
 from pytorch_distributed_tpu.mesh import DeviceMesh
+from pytorch_distributed_tpu.parallel.strategies import ShardingStrategy
 
 P = PartitionSpec
 
@@ -247,7 +248,7 @@ def gpipe_spmd(
 
 
 # -- Trainer integration ----------------------------------------------------
-class PipelineParallel:
+class PipelineParallel(ShardingStrategy):
     """Sharding strategy for pipelined models: stacked-[L] block params get
     P(pp) on their leading dim (device s holds stage s's contiguous layers);
     everything else replicates; batch shards over ``dp_axis`` when given.
@@ -259,7 +260,7 @@ class PipelineParallel:
     def __init__(self, mesh: DeviceMesh, *, pp_axis: str = "pp",
                  dp_axis: Optional[str] = None,
                  stage_param_keys: Sequence[str] = ("blocks",)):
-        self.mesh = mesh
+        super().__init__(mesh)
         self.pp_axis = pp_axis
         self.dp_axis = dp_axis
         self.batch_axes = dp_axis
@@ -277,19 +278,6 @@ class PipelineParallel:
             spec[0] = self.pp_axis
             return P(*spec)
         return P()
-
-    def opt_pspec(self, path: str, shape) -> PartitionSpec:
-        return self.param_pspec(path, shape)
-
-    def model_state_pspec(self, path: str, shape) -> PartitionSpec:
-        return P()
-
-    def batch_pspec(self) -> PartitionSpec:
-        return P(self.batch_axes) if self.batch_axes else P()
-
-    @property
-    def data_shard_count(self) -> int:
-        return self.mesh.size(self.dp_axis) if self.dp_axis else 1
 
     def describe(self) -> str:
         return (

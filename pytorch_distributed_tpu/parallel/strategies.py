@@ -1,12 +1,14 @@
 """Sharding strategies: param/optimizer/batch placement rules.
 
-Each strategy answers four questions for a given mesh:
+Each strategy answers five questions for a given mesh:
   * ``param_pspec(path, shape)``  — how a parameter is laid out
   * ``opt_pspec(path, shape)``    — how its optimizer-state companions are laid out
   * ``update_pspec(path, shape)`` — how the weight *update* is laid out when
     ``sharded_update`` is set (the ZeRO reduce-scatter → shard-local optimizer
     step → all-gather path, arXiv 2004.13336)
   * ``batch_axes``                — which mesh axes shard the batch dim
+  * ``activation_pin(specs)``     — the layout activations are held to, where
+    parameters and batch share a mesh axis (else None)
 
 The FSDP rule ("shard the largest dim divisible by the axis size") is the
 standard JAX/GSPMD fsdp recipe — the semantic twin of torch FlatParameter's
@@ -16,8 +18,9 @@ expressed per-param so XLA can fuse the all-gather into consumers.
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple, Union
+from typing import Any, Optional, Sequence, Tuple, Union
 
+import jax.tree_util as jtu
 from jax.sharding import PartitionSpec
 
 from pytorch_distributed_tpu.mesh import DeviceMesh
@@ -130,18 +133,48 @@ class ShardingStrategy:
             return P()
         return P(self.batch_axes)
 
+    def _batch_axes(self) -> Tuple[str, ...]:
+        if self.batch_axes is None:
+            return ()
+        if isinstance(self.batch_axes, str):
+            return (self.batch_axes,)
+        return tuple(self.batch_axes)
+
+    def activation_pin(self, param_pspecs: Any) -> Optional[PartitionSpec]:
+        """The layout that goes with ``batch_pspec()`` for an activation
+        whose leading dimension is the batch's (that dimension on
+        ``batch_axes``, the rest unsharded), or None where there is
+        nothing to pin. ``param_pspecs`` is the tree of ``param_pspec``
+        results for the model at hand.
+
+        It is a layout exactly when some parameter is sharded over a mesh
+        axis (larger than 1) that the batch is sharded over too. Only then
+        does the partitioner have a choice to make at each ``x @ W``:
+        un-shard the parameter (FSDP) or un-shard the activation and
+        compute a shard of the output features, which is tensor
+        parallelism nobody asked for. Holding the activations to the batch
+        layout leaves it the first. Everywhere else (replicated
+        parameters; parameters on an axis of their own, as under TP, PP
+        or EP; any axis of size 1) the batch's own constraint decides
+        alone, and nothing is emitted."""
+        shared = {a for a in self._batch_axes() if self.mesh.size(a) > 1}
+        if not shared:
+            return None
+        specs = jtu.tree_leaves(
+            param_pspecs, is_leaf=lambda s: isinstance(s, PartitionSpec)
+        )
+        used = {
+            axis
+            for spec in specs for entry in spec if entry is not None
+            for axis in ((entry,) if isinstance(entry, str) else entry)
+        }
+        return self.batch_pspec() if shared & used else None
+
     @property
     def data_shard_count(self) -> int:
         """Number of data shards (the 'world size' for the sampler)."""
-        if self.batch_axes is None:
-            return 1
-        axes = (
-            (self.batch_axes,)
-            if isinstance(self.batch_axes, str)
-            else self.batch_axes
-        )
         n = 1
-        for a in axes:
+        for a in self._batch_axes():
             n *= self.mesh.size(a)
         return n
 
@@ -167,6 +200,15 @@ class ShardingStrategy:
           whole-model gather a FlatParameter design would emit).
         * ``forbid`` — families that have no business in a data-parallel
           train step at all.
+        * ``activations`` — ``"local"`` where the strategy pins the
+          activations to the batch layout (``activation_pin``): every
+          tensor-grade collective then moves a parameter, a gradient or
+          a shard of one, and NONE an activation. On the TPU this is
+          held for every family, the ring steps (collective-permute) of
+          a reduce-scatter included, bar the row exchange of a sharded
+          embedding table (one all-to-all of the looked-up rows each
+          way and the gather of the token ids: 10 MB where the table's
+          gather is 129, ``tests/test_chip_compile.py``).
         """
         return {
             "grad_reduce": False,
@@ -212,8 +254,19 @@ class FullyShardedDataParallel(ShardingStrategy):
     all-gathers and the gradient reduce-scatter, and the latency-hiding
     scheduler overlaps them with compute. ``sharded_update`` pins the
     optimizer step to the same 1/fsdp layout (``update_pspec`` defaults to
-    ``param_pspec``), so grads/opt-state/update all stay sharded and only
-    the compiler decides where the gathers land.
+    ``param_pspec``), so grads/opt-state/update all stay sharded.
+
+    What makes the partitioner gather the PARAMETER at each use is
+    ``activation_pin``: batch and parameters share the ``fsdp`` axis, so
+    at every ``x[B/n, T, C] @ W[C, N/n]`` one operand must be un-sharded,
+    and the largest-divisible-dim rule lays ``W`` out exactly as
+    Megatron's column- and row-parallel layers would. Left to its cost
+    model the partitioner gathers the activation and runs tensor
+    parallelism (PERF.md, PR 29: 139 ms of exposed collectives in a 470 ms
+    step on four v5e chips). The trainer therefore holds the model's
+    activations to the batch layout while it traces (``mesh.pin_activation``
+    at the model's hook sites); where the compiler then places each
+    gather, and how far ahead, stays its own decision.
 
     ``min_shard_size`` keeps tiny params replicated (wrap-policy analog).
     Optionally composes an extra pure-DP axis: ``batch_axes=('dp','fsdp')``
@@ -254,6 +307,7 @@ class FullyShardedDataParallel(ShardingStrategy):
         sig = super().collective_signature()
         sig["grad_reduce"] = True
         sig["param_gather"] = "per_param"
+        sig["activations"] = "local"
         return sig
 
 

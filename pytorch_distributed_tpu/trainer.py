@@ -46,6 +46,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from pytorch_distributed_tpu.amp import GradScaler, Policy, get_policy
 from pytorch_distributed_tpu.data.sharding import shard_batch_for_mesh
+from pytorch_distributed_tpu.mesh import activation_layout
 from pytorch_distributed_tpu.parallel import (
     ShardingStrategy,
     TrainState,
@@ -362,9 +363,10 @@ class Trainer:
 
         def forward(params, model_state, batch, scale, rngs):
             variables = {"params": params, **model_state}
-            loss, (new_ms, metrics) = loss_fn(
-                model, variables, batch, True, rngs
-            )
+            with self._activations_pinned(params):
+                loss, (new_ms, metrics) = loss_fn(
+                    model, variables, batch, True, rngs
+                )
             with jax.named_scope("loss"):
                 scaled = loss * scale.astype(loss.dtype)
             return scaled, (loss, new_ms, metrics)
@@ -658,7 +660,10 @@ class Trainer:
         def eval_fn(state: TrainState, batch):
             batch = policy.cast_to_compute(batch)
             variables = {"params": state.params, **state.model_state}
-            loss, (_, metrics) = loss_fn(model, variables, batch, False, None)
+            with self._activations_pinned(state.params):
+                loss, (_, metrics) = loss_fn(
+                    model, variables, batch, False, None
+                )
             return {"loss": loss, **metrics}
 
         return jax.jit(eval_fn, compiler_options=self.compiler_options)
@@ -669,6 +674,22 @@ class Trainer:
         return self._eval_fn(state, self._place_batch(batch))
 
     # -- helpers -----------------------------------------------------------
+    def _activations_pinned(self, params):
+        """Context for tracing the model's forward: where the strategy
+        shards parameters over an axis the batch is sharded over too (FSDP,
+        HSDP), the models' ``pin_activation`` sites hold the activations to
+        the batch layout, so the partitioner gathers the parameter at each
+        use and never an activation. The layout is read off the mesh and
+        the specs (``params``: arrays or tracers, only paths and shapes
+        are read); where the strategy has none, nothing is emitted and the
+        step is the program it was."""
+        strategy = self.strategy
+        spec = strategy.activation_pin(_zero.param_pspecs(strategy, params))
+        return activation_layout(
+            None if spec is None
+            else NamedSharding(strategy.mesh.jax_mesh, spec)
+        )
+
     def _place_batch(self, batch):
         leaves = jtu.tree_leaves(batch)
         if leaves and all(isinstance(x, jax.Array) for x in leaves):

@@ -19,6 +19,12 @@ One whole program is held the same way: the serving engine's decode step at
 the shapes of the ``gpt2-125m.serve-chat`` cell must write the slotted KV
 cache where it lies (PERF.md, PR 25) — the compiled module is the counter
 of that mechanism, so it engages always or the test fails.
+
+And one train step: ``Trainer`` under ``FullyShardedDataParallel`` on the
+``(1, 4)`` mesh of all four described chips, at GPT-2 large's widths cut
+to two layers, must gather parameters and never an activation (PERF.md,
+PR 29); on a ``(1, 1)`` mesh the same code must lower to the text it
+lowers to with the pin taken out by hand.
 """
 
 import functools
@@ -198,3 +204,157 @@ def test_decode_program_writes_the_cache_in_place_for_v5e(v5e_device):
         ops = [op for _, op, n, _ in computations[called.group(1)]
                if n >= slab and op not in ("parameter", "bitcast")]
         assert ops == ["scatter"], (name, ops)
+
+
+# -- the FSDP train step: parameters are gathered, activations are not -------
+
+@pytest.fixture(scope="module")
+def v5e_topology(v5e_device):
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+
+
+def _fsdp_step(topology, mesh_shape, loss_fn, n_layer=2, batch=16, seq=1024):
+    """The runner's step program of GPT-2 at 1280 wide / 20 heads under
+    FSDP (``min_shard_size=8``, AdamW, policy bf16: the four-chip cell's),
+    lowered for described devices: ``(lowered, model config, state)``."""
+    import numpy as np
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import pytorch_distributed_tpu as ptd
+    from pytorch_distributed_tpu.models import GPT2, GPT2Config
+    from pytorch_distributed_tpu.parallel import FullyShardedDataParallel
+    from pytorch_distributed_tpu.pipeline_exec import AsyncRunner
+    from pytorch_distributed_tpu.pipeline_exec.metric_ring import MetricRing
+    from pytorch_distributed_tpu.trainer import Trainer
+
+    n = int(np.prod(mesh_shape))
+    mesh = ptd.init_device_mesh(mesh_shape, ("dp", "fsdp"),
+                                devices=topology.devices[:n])
+    strategy = FullyShardedDataParallel(mesh, min_shard_size=8)
+    cfg = GPT2Config(n_embd=1280, n_layer=n_layer, n_head=20,
+                     dtype=jnp.bfloat16, param_dtype=jnp.float32)
+    trainer = Trainer(GPT2(cfg), optax.adamw(3e-4), strategy,
+                      loss_fn=loss_fn, policy="bf16")
+    blank = np.zeros((1, seq), np.int32)
+    shapes = jax.eval_shape(
+        lambda k: trainer.init(k, (blank, blank)), jax.random.key(0))
+
+    def described(tree, shardings):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, shardings)
+
+    replicated = NamedSharding(mesh.jax_mesh, P())
+    state = described(shapes, trainer.state_shardings)
+    tokens = jax.ShapeDtypeStruct(
+        (batch, seq), jnp.int32,
+        sharding=NamedSharding(mesh.jax_mesh, strategy.batch_pspec()))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    rng = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=replicated)
+    runner = AsyncRunner(trainer)
+    step = runner._build(state, (tokens, tokens), rng)
+    ring = jax.eval_shape(
+        lambda: MetricRing.create(runner._names, runner.drain_every))
+    ring = described(ring, jax.tree.map(lambda a: replicated, ring))
+    return step.lower(state, ring, (tokens, tokens), rng), cfg, state
+
+
+#: temporaries of the same two-layer step before the pin, when the program
+#: ran as tensor parallelism and gathered the whole batch's logits on every
+#: chip (compile result, PR 29, the parent commit)
+PARENT_TEMP_BYTES = 4.82e9
+
+
+@pytest.mark.parametrize("loss", ["lm_loss", "chunked"])
+def test_fsdp_step_gathers_parameters_not_activations_for_v5e(
+        v5e_topology, loss):
+    """The census of ``FullyShardedDataParallel.collective_signature()``
+    on the program the TPU compiler makes for four chips: gathers of the
+    parameters' own shapes are there; no collective of any family (a ring
+    step's collective-permute included) moves anything but a parameter, a
+    gradient or a shard of one, bar the embedding's row exchange; the
+    gathered logits are gone from the temporaries."""
+    from pytorch_distributed_tpu.analysis.ir.hlo import (
+        activation_collectives, collective_inventory,
+        parameter_element_counts,
+    )
+    from pytorch_distributed_tpu.trainer import lm_loss, make_chunked_lm_loss
+
+    n_chunks = 8
+    loss_fn = (lm_loss if loss == "lm_loss"
+               else make_chunked_lm_loss(n_chunks))
+    lowered, cfg, state = _fsdp_step(v5e_topology, (1, 4), loss_fn)
+    compiled = lowered.compile()
+    ops = [op for op in collective_inventory(compiled.as_text())
+           if not op.scalar]
+
+    d = cfg.n_embd
+    # the head's operand: ``wte`` whole, or the chunked loss's slice of it
+    head = ((cfg.vocab_size, d) if loss == "lm_loss"
+            else (-(-cfg.vocab_size // n_chunks), d))
+    gathered = {op.shape for op in ops if op.family == "all-gather"}
+    for shape in [(d, 3 * d), (d, d), (d, 4 * d), (4 * d, d), head]:
+        assert shape in gathered, (shape, sorted(gathered))
+
+    shapes = [leaf.shape for leaf in jax.tree.leaves(state.params)]
+    counts = parameter_element_counts(shapes + [head], [4])
+    moved = activation_collectives(ops, counts)
+    # the embedding's row exchange: ``wte`` is sharded on its 1280 columns
+    # (50257 rows do not divide by four), so each chip looks up ALL the
+    # batch's tokens (their ids gathered: 64 KB) in its 320 columns and
+    # one all-to-all each way hands the rows to the chips that own the
+    # sequences: 10 MB forward (bf16), 21 MB backward (f32), where a
+    # gather of the table is 129 MB. Kept. The chunked loss looks the
+    # targets' rows up in the same table (x . W_y) and exchanges those too.
+    exchange = [op for op in moved
+                if op.family == "all-to-all" or op.dtype == "s32"]
+    assert (len([op for op in exchange if op.family == "all-to-all"])
+            <= (2 if loss == "lm_loss" else 4))
+    rest = [op.describe() for op in moved if op not in exchange]
+    assert not rest, rest
+    # and nothing at all with the sequence length next to the model width
+    # or the vocabulary in its shape, whatever its size
+    seq = 1024
+    for op in ops:
+        if op in exchange or len(op.shape) < 3:
+            continue
+        assert seq not in op.shape, op.describe()
+
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < PARENT_TEMP_BYTES / 2, memory
+
+
+def test_fsdp_step_on_one_device_lowers_as_without_the_pin(
+        v5e_topology, monkeypatch):
+    """On a mesh whose batch axes have size 1 the strategy states no
+    layout and nothing is emitted: the lowered step is, to the byte, the
+    one built with the pin taken out by hand (so the one-chip cells load
+    the parent's compile-cache entries)."""
+    from pytorch_distributed_tpu.parallel import ShardingStrategy
+    from pytorch_distributed_tpu.trainer import lm_loss
+
+    with_pin, _, _ = _fsdp_step(v5e_topology, (1, 1), lm_loss, batch=4)
+    monkeypatch.setattr(ShardingStrategy, "activation_pin",
+                        lambda self, specs: None)
+    without, _, _ = _fsdp_step(v5e_topology, (1, 1), lm_loss, batch=4)
+    assert with_pin.as_text() == without.as_text()
+
+
+def test_fsdp_pin_changes_the_four_chip_step(v5e_topology, monkeypatch):
+    """The other side of the test above: on ``(1, 4)`` the pin IS in the
+    lowered text (one constraint after the embedding, one after each
+    block, the hidden state, the logits, and their cotangents)."""
+    from pytorch_distributed_tpu.parallel import ShardingStrategy
+    from pytorch_distributed_tpu.trainer import lm_loss
+
+    with_pin, cfg, _ = _fsdp_step(v5e_topology, (1, 4), lm_loss)
+    monkeypatch.setattr(ShardingStrategy, "activation_pin",
+                        lambda self, specs: None)
+    without, _, _ = _fsdp_step(v5e_topology, (1, 4), lm_loss)
+    extra = (with_pin.as_text().count("sharding_constraint")
+             - without.as_text().count("sharding_constraint"))
+    assert extra >= cfg.n_layer + 3, extra

@@ -90,6 +90,70 @@ def test_collective_inventory_reads_tpu_tiled_tuple_layouts():
     assert not any(op.scalar for op in ops)
 
 
+def test_collective_inventory_names_the_array_a_start_tuple_moves():
+    """A ``-start`` result is a tuple: (operand, result) for an all-gather,
+    (operand, result, u32[], u32[]) for a collective-permute. The op's
+    ``shape`` is the array that crosses the wire, not the tuple's last
+    element (lines from the FSDP step compiled for a described v5e:2x2)."""
+    text = textwrap.dedent("""\
+      %cp = (bf16[1280,960]{0,1:T(8,128)(2,1)}, bf16[1280,960]{0,1:T(8,128)(2,1)}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%b), channel_id=45, source_target_pairs={{0,1},{1,2},{2,3},{3,0}}
+      %ag = (bf16[1280,960]{0,1:T(8,128)(2,1)}, bf16[1280,3840]{0,1:T(8,128)(2,1)}) all-gather-start(%p), dimensions={1}
+      %ar = (f32[1280]{0:T(1024)}, f32[3840]{0:T(1024)}) all-reduce(%a, %b), channel_id=3
+    """)
+    ops = collective_inventory(text)
+    assert [(op.family, op.dtype, op.shape) for op in ops] == [
+        ("collective-permute", "bf16", (1280, 960)),
+        ("all-gather", "bf16", (1280, 3840)),
+        ("all-reduce", "f32", (3840,)),
+    ]
+
+
+@pytest.mark.parametrize("shape,is_activation", [
+    ((1280, 3840), False),        # a parameter, gathered
+    ((1280, 960), False),         # its shard: one step of a ring
+    ((4, 1, 1280), False),        # a bias of 5120, reshaped on the way
+    ((16, 1024, 1280), True),     # the residual stream
+    ((16, 1024, 320), True),      # ... a shard of it
+    ((4, 4, 1024, 320), True),    # the embedding's row exchange
+])
+def test_activation_collectives_are_told_by_element_count(
+        shape, is_activation):
+    counts = hlo_mod.parameter_element_counts(
+        [(1280, 3840), (5120,), (50257, 1280)], [1, 4])
+    op = hlo_mod.CollectiveOp("all-gather", "bf16", shape, 2, False)
+    assert (hlo_mod.activation_collectives([op], counts) == [op]) \
+        == is_activation
+
+
+def test_fsdp_signature_refuses_a_collective_on_an_activation():
+    """``FullyShardedDataParallel.collective_signature()["activations"]``
+    is ``"local"``: the auditor reports a tensor-grade collective that is
+    no parameter, gradient or shard of one, and passes the FSDP set."""
+    import types
+
+    from pytorch_distributed_tpu.analysis.ir.audit import collective_findings
+    from pytorch_distributed_tpu.mesh import init_device_mesh
+    from pytorch_distributed_tpu.parallel import FullyShardedDataParallel
+
+    strategy = FullyShardedDataParallel(
+        init_device_mesh((8,), ("fsdp",)), min_shard_size=8)
+    assert strategy.collective_signature()["activations"] == "local"
+    params = {"kernel": jax.ShapeDtypeStruct((64, 256), jnp.float32),
+              "head": jax.ShapeDtypeStruct((256, 16), jnp.float32)}
+    program = types.SimpleNamespace(
+        name="fsdp:stub", strategy=strategy,
+        state=types.SimpleNamespace(params=params))
+    gather = hlo_mod.CollectiveOp("all-gather", "f32", (64, 256),
+                                  64 * 256 * 4, False)
+    reduce = hlo_mod.CollectiveOp("all-reduce", "f32", (64, 32),
+                                  64 * 32 * 4, False)
+    assert collective_findings(program, [gather, reduce]) == []
+    moved = hlo_mod.CollectiveOp("all-gather", "f32", (32, 256),
+                                 32 * 256 * 4, False)
+    found = collective_findings(program, [gather, reduce, moved])
+    assert len(found) == 1 and "activation" in found[0].message
+
+
 def test_summarize_separates_scalar_grade():
     summary = summarize_collectives(collective_inventory(SAMPLE_HLO))
     assert summary["tensor"]["all-reduce"] == {

@@ -239,3 +239,107 @@ class TestClipAndSharding:
         kernel = state.params["Dense_1"]["kernel"]
         shard_shapes = {s.data.shape for s in kernel.addressable_shards}
         assert shard_shapes == {(8, 64)} or shard_shapes == {(64, 8)}
+
+
+# -- the activation pin (PERF.md, PR 29) --------------------------------------
+# A strategy that shards parameters over an axis the batch is sharded over
+# too holds the activations to the batch layout, so the partitioner gathers
+# the parameter at each use. The sharded step must stay the one-device step.
+
+def _gpt2_steps(strategy, loss_fn, n_steps=4):
+    from pytorch_distributed_tpu.models import GPT2, GPT2Config
+
+    cfg = GPT2Config(vocab_size=257, n_positions=32, n_embd=64, n_layer=2,
+                     n_head=4)
+    rng = np.random.default_rng(0)
+    tokens = rng.integers(0, cfg.vocab_size, (8, 32)).astype(np.int32)
+    batch = (tokens, np.roll(tokens, -1, axis=1))
+    # SGD, not Adam: an update linear in the gradient keeps the parameters
+    # comparable to float32 tolerance (Adam turns the rounding of a
+    # near-zero gradient into a whole step of the learning rate)
+    trainer = Trainer(GPT2(cfg), optax.sgd(0.1, momentum=0.9), strategy,
+                      loss_fn=loss_fn)
+    state = trainer.init(jax.random.key(0), batch)
+    losses = []
+    for _ in range(n_steps):
+        state, m = trainer.step(state, batch)
+        losses.append(float(m["loss"]))
+    return np.asarray(losses), jax.device_get(state.params), trainer
+
+
+def _pinned_strategy(name):
+    from pytorch_distributed_tpu.parallel import HybridShard
+
+    if name == "fsdp_1x4":
+        mesh = init_device_mesh((1, 4), ("dp", "fsdp"),
+                                devices=jax.devices()[:4])
+        return FullyShardedDataParallel(mesh, min_shard_size=8)
+    mesh = init_device_mesh((2, 4), ("dcn", "fsdp"))
+    return HybridShard(mesh, min_shard_size=8)
+
+
+class TestActivationPin:
+    @pytest.mark.parametrize("loss", ["lm_loss", "chunked"])
+    @pytest.mark.parametrize("name", ["fsdp_1x4", "hybrid_2x4"])
+    def test_pinned_step_matches_one_device(self, name, loss):
+        from pytorch_distributed_tpu.trainer import (
+            lm_loss, make_chunked_lm_loss,
+        )
+
+        loss_fn = lm_loss if loss == "lm_loss" else make_chunked_lm_loss(4)
+        one = NoShard(init_device_mesh((1,), ("x",),
+                                       devices=jax.devices()[:1]))
+        ref_losses, ref_params, _ = _gpt2_steps(one, loss_fn)
+        strategy = _pinned_strategy(name)
+        losses, params, trainer = _gpt2_steps(strategy, loss_fn)
+        # the pin is in place: the strategy states the batch layout ...
+        specs = jax.tree.map(lambda s: s.spec, trainer.state_shardings.params)
+        assert strategy.activation_pin(specs) == strategy.batch_pspec()
+        # ... and the step is still the one-device step
+        np.testing.assert_allclose(losses, ref_losses, rtol=2e-5)
+        assert losses[-1] < losses[0]
+        jax.tree.map(
+            lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-4,
+                                                    atol=1e-6),
+            params, ref_params,
+        )
+
+    @pytest.mark.parametrize("name,axes,shape,pinned", [
+        ("fsdp", ("dp", "fsdp"), (1, 4), True),
+        ("fsdp", ("dp", "fsdp"), (4, 1), False),   # the shared axis is 1 wide
+        ("fsdp", ("dp", "fsdp"), (1, 1), False),
+        ("dp", ("dp",), (4,), False),              # replicated parameters
+        ("zero1", ("dp",), (4,), False),
+    ])
+    def test_pin_is_read_off_mesh_and_specs(self, name, axes, shape, pinned):
+        n = int(np.prod(shape))
+        mesh = init_device_mesh(shape, axes, devices=jax.devices()[:n])
+        strategy = {
+            "fsdp": lambda: FullyShardedDataParallel(mesh, min_shard_size=8),
+            "dp": lambda: DataParallel(mesh),
+            "zero1": lambda: ZeRO1(mesh, min_shard_size=8),
+        }[name]()
+        specs = {"w": strategy.param_pspec("w", (64, 256)),
+                 "b": strategy.param_pspec("b", (3,))}
+        want = strategy.batch_pspec() if pinned else None
+        assert strategy.activation_pin(specs) == want
+
+    def test_pin_activation_outside_a_trainer_is_the_identity(self):
+        from jax.sharding import NamedSharding
+
+        from pytorch_distributed_tpu.mesh import (
+            activation_layout, pin_activation,
+        )
+
+        x = jnp.ones((8, 4))
+        assert pin_activation(x) is x
+        mesh = init_device_mesh((4,), ("fsdp",), devices=jax.devices()[:4])
+        layout = NamedSharding(mesh.jax_mesh, P("fsdp"))
+        with activation_layout(layout):
+            assert pin_activation(x).sharding.spec == P("fsdp")
+            # a leading dimension the axis does not divide is left alone
+            odd = jnp.ones((6, 4))
+            assert pin_activation(odd) is odd
+            with activation_layout(None):
+                assert pin_activation(x) is x
+        assert pin_activation(x) is x
