@@ -96,20 +96,18 @@ def default_attention(q, k, v, *, causal: bool = True):
 
 
 class SelfAttention(nn.Module):
-    # ``layer_cache``/``position_offset`` switch on the serving decode path
-    # (pytorch_distributed_tpu.serving): K/V for the T new tokens are
-    # scattered into the preallocated cache and attention runs densely over
-    # each slot (ops.decode_attention — the Pallas flash kernel's T x T
-    # blocking doesn't apply at T=1). The slotted cache arrives WHOLE,
-    # ``(k, v)`` of ``[L, S, Tmax, H*D]`` with ``cache_layer`` naming this
-    # block's layer, and goes back whole: rows are written where they lie.
-    # The paged cache arrives as this layer's ``(k_pages, v_pages,
-    # block_tables)``. With layer_cache=None the training path is untouched.
+    # ``cache`` switches on the serving path (pytorch_distributed_tpu.
+    # serving): the cache itself writes the T new tokens' K/V into its
+    # ``layer`` and attends over each sequence (``cache.attend``, the one
+    # method a model knows of a cache: serving.kv_cache states the
+    # protocol). It arrives whole and goes back whole; how K and V are
+    # stored is the cache's business. With cache=None the training path is
+    # untouched.
     cfg: GPT2Config
 
     @nn.compact
-    def __call__(self, x, *, deterministic: bool = True, layer_cache=None,
-                 cache_layer=None, position_offset=None):
+    def __call__(self, x, *, deterministic: bool = True, cache=None,
+                 layer=None, position_offset=None):
         cfg = self.cfg
         B, T, C = x.shape
         H, D = cfg.n_head, cfg.n_embd // cfg.n_head
@@ -119,42 +117,18 @@ class SelfAttention(nn.Module):
         q = q.reshape(B, T, H, D)
         k = k.reshape(B, T, H, D)
         v = v.reshape(B, T, H, D)
-        new_cache = None
-        if layer_cache is None:
+        if cache is None:
             attn = cfg.attn_impl or default_attention
             y = attn(q, k, v, causal=True)
-        elif len(layer_cache) == 3:
-            # paged serving path: (k_pages, v_pages, block_tables) — the
-            # new K/V scatter through the block table into the shared page
-            # pool (ops.paged_attention)
-            from pytorch_distributed_tpu.ops.paged_attention import (
-                paged_cached_attention,
-            )
-
-            y, ck, cv = paged_cached_attention(
-                q, k, v, layer_cache[0], layer_cache[1], layer_cache[2],
-                position_offset,
-            )
-            new_cache = (ck, cv)
         else:
-            from pytorch_distributed_tpu.ops.decode_attention import (
-                cached_attention,
-            )
-
-            y, ck, cv = cached_attention(
-                q, k, v, layer_cache[0], layer_cache[1], cache_layer,
-                position_offset,
-            )
-            new_cache = (ck, cv)
+            y, cache = cache.attend(layer, q, k, v, position_offset)
         y = y.reshape(B, T, C)
         y = nn.Dense(cfg.n_embd, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                      kernel_init=nn.initializers.normal(0.02 / jnp.sqrt(2 * cfg.n_layer)),
                      name="c_proj")(y)
         if cfg.dropout > 0:
             y = nn.Dropout(cfg.dropout)(y, deterministic=deterministic)
-        if layer_cache is None:
-            return y
-        return y, new_cache
+        return y, cache
 
 
 class MLP(nn.Module):
@@ -181,25 +155,17 @@ class Block(nn.Module):
     # NOTE: ``deterministic`` is positional (not kw-only) so nn.remat can mark
     # it static (static_argnums) — a traced boolean would crash nn.Dropout.
     @nn.compact
-    def __call__(self, x, deterministic: bool = True, *, layer_cache=None,
-                 cache_layer=None, position_offset=None):
+    def __call__(self, x, deterministic: bool = True, *, cache=None,
+                 layer=None, position_offset=None):
+        """``(x, router aux loss, cache)``; ``cache`` as in SelfAttention."""
         cfg = self.cfg
         ln = lambda name: nn.LayerNorm(
             epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name=name)
-        if layer_cache is not None:
-            # serving decode path: dense block only (the engine rejects MoE
-            # configs), returns the updated cache beside the residual
-            y, new_cache = SelfAttention(cfg, name="attn")(
-                ln("ln_1")(x), deterministic=deterministic,
-                layer_cache=layer_cache, cache_layer=cache_layer,
-                position_offset=position_offset)
-            x = x + y
-            x = x + MLP(cfg, name="mlp")(
-                ln("ln_2")(x), deterministic=deterministic)
-            return x, new_cache
-        x = x + SelfAttention(cfg, name="attn")(
-            ln("ln_1")(x), deterministic=deterministic)
+        y, cache = SelfAttention(cfg, name="attn")(
+            ln("ln_1")(x), deterministic=deterministic,
+            cache=cache, layer=layer, position_offset=position_offset)
+        x = x + y
         if self.use_moe:
             from pytorch_distributed_tpu.parallel.expert import MoEMLP
 
@@ -213,9 +179,9 @@ class Block(nn.Module):
                 param_dtype=cfg.param_dtype,
                 name="moe",
             )(ln("ln_2")(x))
-            return x + y, aux["aux_loss"]
+            return x + y, aux["aux_loss"], cache
         x = x + MLP(cfg, name="mlp")(ln("ln_2")(x), deterministic=deterministic)
-        return x, jnp.float32(0.0)
+        return x, jnp.float32(0.0), cache
 
 
 class GPT2(nn.Module):
@@ -226,19 +192,21 @@ class GPT2(nn.Module):
     (``trainer.lm_loss_chunked``) consumes these with the tied ``wte`` head
     so the fp32 ``[B, T, V]`` logits tensor never materializes.
 
-    ``kv_cache`` (a ``serving.kv_cache.KVCache``) switches on the serving
-    forward: positions come from ``position_offset`` (``[B]`` int32, the
-    current length of each cache slot), each block attends over its cache
-    slot instead of the T x T causal window, and the call returns
-    ``(logits, new_kv_cache)``. Prefill is this path at T = padded prompt
-    length with NO offset (every sequence fresh, from position 0: the
-    slotted attention then never reads the cache); decode is T = 1 at
-    offset = slot length, and the speculative verify step is T = k+1 at
-    the same offset (the cached attention masks per-position, so a
-    multi-token window is causal over global positions for free). The
-    training path (``kv_cache=None``) is untouched.
+    ``kv_cache`` (a ``serving.kv_cache.KVCache`` or a
+    ``serving.paging.PagedKVCache``) makes the same forward the serving
+    one: positions come from ``position_offset`` (``[B]`` int32, the
+    current length of each cache slot), each block attends through
+    ``kv_cache.attend`` instead of over the T x T causal window, and the
+    call returns ``(logits, new_kv_cache)``. Every param binds to the path
+    training creates: a training checkpoint IS the serving checkpoint.
+    Prefill is this call at T = padded prompt length with NO offset (every
+    sequence fresh, from position 0); decode is T = 1 at offset = slot
+    length, and the speculative verify step is T = k+1 at the same offset
+    (the cache masks per position, so a multi-token window is causal over
+    global positions for free). The training path (``kv_cache=None``) is
+    untouched.
 
-    ``n_layers`` (cached path only) truncates the stack: run the first N
+    ``n_layers`` (with a cache only) truncates the stack: run the first N
     blocks, then ``ln_f`` + the tied head — the self-drafting draft of
     speculative decoding. Layers ``0..N-1`` compute exactly what the full
     forward computes there, so the draft shares the target's cache (only
@@ -255,18 +223,43 @@ class GPT2(nn.Module):
     ):
         cfg = self.cfg
         B, T = tokens.shape
-        if kv_cache is not None:
-            return self._cached_forward(
-                tokens, kv_cache, position_offset,
-                deterministic=deterministic, n_layers=n_layers,
-            )
-        if n_layers is not None:
+        nl = cfg.n_layer if n_layers is None else int(n_layers)
+        if kv_cache is None:
+            if n_layers is not None:
+                raise ValueError(
+                    "n_layers (truncated draft forward) requires kv_cache"
+                )
+            if T > cfg.n_positions:
+                raise ValueError(
+                    f"sequence length {T} exceeds n_positions "
+                    f"{cfg.n_positions}"
+                )
+            pin = pin_activation
+        else:
+            # what the serving forward does not do, stated once: no routed
+            # MLP (it has no cache story yet), no dropout, no remat (no
+            # gradient flows here), no pin of its own (``act_constraint``
+            # or nothing)
+            if cfg.moe_experts > 0:
+                raise ValueError(
+                    "kv_cache forward supports dense GPT-2 only "
+                    "(moe_experts must be 0)"
+                )
+            if kv_cache.n_layers != cfg.n_layer:
+                raise ValueError(
+                    f"kv_cache has {kv_cache.n_layers} layers, model has "
+                    f"{cfg.n_layer}"
+                )
+            if not (1 <= nl <= cfg.n_layer):
+                raise ValueError(
+                    f"n_layers {nl} must be in [1, n_layer={cfg.n_layer}]"
+                )
+            deterministic = True
+            pin = lambda a: a
+        if cfg.remat_policy is not None and not cfg.remat:
             raise ValueError(
-                "n_layers (truncated draft forward) requires kv_cache"
-            )
-        if T > cfg.n_positions:
-            raise ValueError(
-                f"sequence length {T} exceeds n_positions {cfg.n_positions}"
+                "remat_policy set but remat=False — the policy only "
+                "selects WHAT nn.remat saves; enable remat=True"
             )
         wte = self.param(
             "wte",
@@ -280,20 +273,25 @@ class GPT2(nn.Module):
             (cfg.n_positions, cfg.n_embd),
             cfg.param_dtype,
         )
+        if kv_cache is None:
+            pos = slice(T)
+        else:
+            # each token's GLOBAL position; the clamp guards the padded
+            # tail of an over-long prefill (the engine discards those query
+            # rows). No offset = every sequence starts at 0.
+            pos = jnp.arange(T, dtype=jnp.int32)[None]
+            if position_offset is not None:
+                pos = position_offset[:, None] + pos
+            pos = jnp.minimum(pos, cfg.n_positions - 1)
         with jax.named_scope("embed"):
-            x = wte[tokens].astype(cfg.dtype) + wpe[:T].astype(cfg.dtype)
+            x = wte[tokens].astype(cfg.dtype) + wpe[pos].astype(cfg.dtype)
         if cfg.dropout > 0:
             x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
 
-        constrain = cfg.act_constraint or pin_activation
+        constrain = cfg.act_constraint or pin
         x = constrain(x)
         block = Block
-        if cfg.remat_policy is not None and not cfg.remat:
-            raise ValueError(
-                "remat_policy set but remat=False — the policy only "
-                "selects WHAT nn.remat saves; enable remat=True"
-            )
-        if cfg.remat:
+        if cfg.remat and kv_cache is None:
             policy = (
                 getattr(jax.checkpoint_policies, cfg.remat_policy)
                 if cfg.remat_policy is not None else None
@@ -301,12 +299,16 @@ class GPT2(nn.Module):
             # arg 0 is the module, 1 is x, 2 is deterministic (static)
             block = nn.remat(Block, static_argnums=(2,), policy=policy)
         aux_total = jnp.float32(0.0)
-        for i in range(cfg.n_layer):
+        for i in range(nl):
             use_moe = (
                 cfg.moe_experts > 0
                 and (i + 1) % cfg.moe_every == 0
             )
-            x, aux = block(cfg, use_moe, name=f"h_{i}")(x, deterministic)
+            # the cache threads through the blocks whole: each writes its
+            # own layer (a truncated draft leaves the later layers as is)
+            x, aux, kv_cache = block(cfg, use_moe, name=f"h_{i}")(
+                x, deterministic, cache=kv_cache, layer=i,
+                position_offset=position_offset)
             aux_total = aux_total + aux
             x = constrain(x)
 
@@ -315,138 +317,31 @@ class GPT2(nn.Module):
         # what the losses consume lies as the batch does too: the hidden
         # state here, the logits below (gathered whole on every chip
         # otherwise, when FSDP shards ``wte``)
-        x = pin_activation(x)
+        x = pin(x)
         if return_hidden:
-            if cfg.moe_experts > 0:
-                return x, cfg.moe_aux_weight * aux_total
-            return x
-        # weight-tied LM head; logits in fp32 for a stable softmax/loss
-        # (a param is no submodule, so Flax scopes neither this nor the
-        # embedding lookup: "head" names it for the device trace)
-        with jax.named_scope("head"):
-            if cfg.head_in_fp32:
-                logits = jnp.einsum(
-                    "btc,vc->btv", x.astype(jnp.float32),
-                    wte.astype(jnp.float32),
-                )
-            else:
-                logits = jnp.einsum(
-                    "btc,vc->btv", x, wte.astype(cfg.dtype),
-                    preferred_element_type=jnp.float32,
-                )
-            logits = pin_activation(logits)
+            out = x
+        else:
+            # weight-tied LM head; logits in fp32 for a stable softmax/loss
+            # (a param is no submodule, so Flax scopes neither this nor the
+            # embedding lookup: "head" names it for the device trace)
+            with jax.named_scope("head"):
+                if cfg.head_in_fp32:
+                    logits = jnp.einsum(
+                        "btc,vc->btv", x.astype(jnp.float32),
+                        wte.astype(jnp.float32),
+                    )
+                else:
+                    logits = jnp.einsum(
+                        "btc,vc->btv", x, wte.astype(cfg.dtype),
+                        preferred_element_type=jnp.float32,
+                    )
+                out = pin(logits)
+        if kv_cache is not None:
+            return out, kv_cache
         if cfg.moe_experts > 0:
             # weighted router load-balance loss, consumed by lm_loss
-            return logits, cfg.moe_aux_weight * aux_total
-        return logits
-
-    def _cached_forward(self, tokens, kv_cache, position_offset,
-                        *, deterministic: bool = True, n_layers=None):
-        """Serving forward over a KV cache: ``(logits, new_kv_cache)``.
-
-        Called from the compact ``__call__`` so every param binds to the
-        same path the training forward creates — a training checkpoint IS
-        the serving checkpoint. Remat is ignored (no gradients flow here)
-        and MoE blocks are rejected (the routed MLP has no cache story yet).
-
-        ``n_layers`` truncates to the first N blocks (self-drafting); the
-        returned cache updates ONLY those layers' K/V, in place.
-        """
-        cfg = self.cfg
-        B, T = tokens.shape
-        if cfg.moe_experts > 0:
-            raise ValueError(
-                "kv_cache forward supports dense GPT-2 only "
-                "(moe_experts must be 0)"
-            )
-        if kv_cache.k.shape[0] != cfg.n_layer:
-            raise ValueError(
-                f"kv_cache has {kv_cache.k.shape[0]} layers, model has "
-                f"{cfg.n_layer}"
-            )
-        nl = cfg.n_layer if n_layers is None else int(n_layers)
-        if not (1 <= nl <= cfg.n_layer):
-            raise ValueError(
-                f"n_layers {nl} must be in [1, n_layer={cfg.n_layer}]"
-            )
-        wte = self.param(
-            "wte",
-            nn.initializers.normal(0.02),
-            (cfg.vocab_size, cfg.n_embd),
-            cfg.param_dtype,
-        )
-        wpe = self.param(
-            "wpe",
-            nn.initializers.normal(0.01),
-            (cfg.n_positions, cfg.n_embd),
-            cfg.param_dtype,
-        )
-        # duck-typed cache dispatch: a paged cache carries block tables and
-        # each layer's K/V is a page pool the sequences index through them
-        paged = hasattr(kv_cache, "block_tables")
-        # learned positional embedding at each token's GLOBAL position;
-        # clamp guards the padded tail of an over-long prefill (those
-        # query rows are discarded by the engine). No offset = every
-        # sequence starts at 0, which the slotted attention is told as
-        # such (a fresh prefill never reads the cache).
-        pos = jnp.arange(T, dtype=jnp.int32)[None]
-        if position_offset is not None:
-            pos = position_offset[:, None] + pos
-        elif paged:
-            position_offset = jnp.zeros((B,), jnp.int32)
-        pos = jnp.minimum(pos, cfg.n_positions - 1)
-        x = wte[tokens].astype(cfg.dtype) + wpe[pos].astype(cfg.dtype)
-
-        constrain = cfg.act_constraint or (lambda a: a)
-        x = constrain(x)
-        # the slotted cache threads through the blocks whole: each writes
-        # its own layer's rows into the one (donated) array
-        k, v = kv_cache.k, kv_cache.v
-        new_k, new_v = [], []
-        for i in range(nl):
-            layer_cache = (
-                (kv_cache.k[i], kv_cache.v[i], kv_cache.block_tables)
-                if paged else (k, v)
-            )
-            x, (ck, cv) = Block(cfg, False, name=f"h_{i}")(
-                x, deterministic,
-                layer_cache=layer_cache, cache_layer=i,
-                position_offset=position_offset,
-            )
-            if paged:
-                new_k.append(ck)
-                new_v.append(cv)
-            else:
-                k, v = ck, cv
-            x = constrain(x)
-
-        x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
-                         param_dtype=cfg.param_dtype, name="ln_f")(x)
-        if cfg.head_in_fp32:
-            logits = jnp.einsum(
-                "btc,vc->btv", x.astype(jnp.float32),
-                wte.astype(jnp.float32),
-            )
-        else:
-            logits = jnp.einsum(
-                "btc,vc->btv", x, wte.astype(cfg.dtype),
-                preferred_element_type=jnp.float32,
-            )
-        if not paged:
-            # a truncated draft wrote only the first nl layers' rows
-            new_cache = kv_cache.replace(k=k, v=v)
-        elif nl == cfg.n_layer:
-            new_cache = kv_cache.replace(
-                k=jnp.stack(new_k), v=jnp.stack(new_v)
-            )
-        else:
-            # truncated draft: only the first nl layers' K/V move (static
-            # slice — in place under jit when the cache is donated)
-            new_cache = kv_cache.replace(
-                k=kv_cache.k.at[:nl].set(jnp.stack(new_k)),
-                v=kv_cache.v.at[:nl].set(jnp.stack(new_v)),
-            )
-        return logits, new_cache
+            return out, cfg.moe_aux_weight * aux_total
+        return out
 
 
 def gpt2_125m(**overrides) -> GPT2:

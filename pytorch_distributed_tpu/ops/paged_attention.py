@@ -17,7 +17,7 @@ Two implementations share one contract:
   paged path is bit-identical to ``cached_attention`` whenever the page
   chain covers the same positions. Import-light (no Pallas). This is what
   the serving path runs today on EVERY platform, prefill and decode alike:
-  ``models/gpt2.py`` calls it for any paged cache.
+  ``serving.paging.PagedKVCache.attend`` is its one caller.
 * ``paged_decode_attention`` — Pallas TPU kernel for the T=1 decode step
   that gathers pages *in-kernel* via scalar-prefetched block tables (one
   grid step per table entry, online softmax across pages), so decode never

@@ -329,7 +329,7 @@ class GPT2Pipe:
 
         def dense_stage_fn(local_blocks, x):
             def body(h, layer_params):
-                h2, _aux = block.apply({"params": layer_params}, h, True)
+                h2, _aux, _ = block.apply({"params": layer_params}, h, True)
                 return h2, None
 
             h, _ = lax.scan(body, x, local_blocks)
@@ -341,7 +341,7 @@ class GPT2Pipe:
             def stage_fn(local_blocks, x, key):
                 def body(h, xs):
                     layer_params, li = xs
-                    h2, _aux = block.apply(
+                    h2, _aux, _ = block.apply(
                         {"params": layer_params}, h, False,
                         rngs={"dropout": jax.random.fold_in(key, li)},
                     )

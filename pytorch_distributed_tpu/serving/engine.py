@@ -32,9 +32,11 @@ every production LLM server — Orca, vLLM, TGI):
     1.0 to ``1 / (1 + E[accepts])``. Both the draft and verify programs
     compile once — no realloc, no shape churn.
 
-All step programs donate the cache pytree, and the cached forward threads
-the whole K/V arrays through its layers (``models.gpt2``), each layer
-writing its own rows: K/V updates are in-place HBM writes.
+All step programs donate the cache pytree, and the model's forward threads
+the whole cache through its layers (``models.gpt2``), each layer writing
+its own rows through ``cache.attend``: K/V updates are in-place HBM writes.
+How K and V are stored is the cache classes' business (``serving.kv_cache``
+states the protocol).
 
 Sampling (greedy / temperature / top-k / nucleus top-p) happens inside the
 jitted step — only sampled token ids cross the host boundary each step,
@@ -51,6 +53,7 @@ identical to the non-speculative one regardless of draft quality
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
@@ -131,20 +134,12 @@ def _slot_prefill(apply_fn, params, cache, tokens, slot, prompt_len):
     so nothing of the resident cache is read: the forward runs on a fresh
     one-slot cache of exactly ``bucket`` positions with no position offset
     (the new tokens attend each other, O(bucket^2)), and its rows land in
-    the resident cache as one ``[L, 1, bucket, H*D]`` block, in place."""
-    n_layers, _, _, width = cache.k.shape
-    rows = jnp.zeros((n_layers, 1, tokens.shape[1], width), cache.k.dtype)
-    sub = KVCache(k=rows, v=rows, lengths=jnp.zeros((1,), jnp.int32))
-    logits, sub = apply_fn(
+    the resident cache as one block, in place."""
+    logits, block = apply_fn(
         params, tokens, deterministic=True,
-        kv_cache=sub, position_offset=None,
+        kv_cache=cache.one_slot(tokens.shape[1]), position_offset=None,
     )
-    at = (0, slot, 0, 0)
-    return logits, cache.replace(
-        k=jax.lax.dynamic_update_slice(cache.k, sub.k, at),
-        v=jax.lax.dynamic_update_slice(cache.v, sub.v, at),
-        lengths=cache.lengths.at[slot].set(prompt_len),
-    )
+    return logits, cache.write_slot(slot, block, prompt_len)
 
 
 class InferenceEngine:
@@ -181,8 +176,9 @@ class InferenceEngine:
       cache_kind: ``"slotted"`` (per-slot ``max_len`` reservation) or
         ``"paged"`` (``serving.paging`` page pool + block tables; the
         scheduler drives the allocator/radix control plane). The decode
-        and speculative programs are cache-kind agnostic — the model's
-        cached forward dispatches on the pytree — only prefill differs.
+        and speculative programs are cache-kind agnostic — the model
+        reaches either cache through ``cache.attend`` — only prefill
+        differs.
         A separate draft model keeps a slotted cache either way (its
         scratch K/V has no sharing story and costs k small layers).
       page_size / n_pages: paged-cache geometry. ``n_pages`` defaults to
@@ -254,11 +250,17 @@ class InferenceEngine:
                 f"cache_kind must be 'slotted' or 'paged', got {cache_kind!r}"
             )
         self.cache_kind = cache_kind
+        paged = cache_kind == "paged"
         self.page_size = int(page_size)
         self.max_pages = -(-self.max_len // self.page_size)
-        if n_pages is None and cache_kind == "paged":
+        if n_pages is None and paged:
             n_pages = self.n_slots * self.max_pages + 1  # + trash page
         self.n_pages = int(n_pages) if n_pages is not None else 0
+        # the resident cache's constructor: the kind's class and geometry
+        self._create_cache = functools.partial(
+            PagedKVCache.create, page_size=self.page_size,
+            n_pages=self.n_pages,
+        ) if paged else KVCache.create
 
         # -- speculative configuration -------------------------------------
         self.spec_k = int(spec_k)
@@ -363,7 +365,6 @@ class InferenceEngine:
             # their (masked, overwritten-on-admit) cache rows don't move
             return new_cache.advance(1, active), next_tok
 
-        paged = self.cache_kind == "paged"
         self._prefill = jax.jit(
             paged_prefill_fn if paged else prefill_fn, donate_argnums=(1,)
         )
@@ -510,17 +511,10 @@ class InferenceEngine:
         """Fresh resident cache of the configured kind (``KVCache`` or
         ``PagedKVCache`` — the step programs take either; the scheduler
         owns the paged kind's allocator/radix control plane)."""
-        if self.cache_kind == "paged":
-            cache = PagedKVCache.create(
-                self.cfg, n_slots=self.n_slots, max_len=self.max_len,
-                page_size=self.page_size, n_pages=self.n_pages,
-                dtype=self.cache_dtype,
-            )
-        else:
-            cache = KVCache.create(
-                self.cfg, n_slots=self.n_slots, max_len=self.max_len,
-                dtype=self.cache_dtype,
-            )
+        cache = self._create_cache(
+            self.cfg, n_slots=self.n_slots, max_len=self.max_len,
+            dtype=self.cache_dtype,
+        )
         if self.cache_sharding is not None:
             cache = cache.replace(
                 k=jax.device_put(cache.k, self.cache_sharding),

@@ -28,6 +28,20 @@ Slot lifecycle (driven by serving.scheduler):
     ``<= p``, all of which were written by the current occupant
     (ops.decode_attention invariant), so stale bytes from a previous
     request are unreachable.
+
+THE CACHE PROTOCOL. How K and V are stored is this class's business (and
+``serving.paging.PagedKVCache``'s), nobody else's. A model touches a cache
+through one method, the same on both classes:
+
+    ``cache.attend(layer, q, k_new, v_new, position_offset) -> (y, cache)``
+
+``q / k_new / v_new`` are ``[B, T, H, D]`` (batch row b is slot b); the
+cache arrives whole and goes back whole with ``layer``'s new rows (or
+pages) written; ``position_offset [B]`` is each sequence's first new
+position, and ``None`` means every sequence is fresh, from position 0.
+Around it the engine and the scheduler use ``create / evict / advance /
+rollback`` and ``n_layers / n_slots / max_len``. Two classes with these
+names are the whole protocol; there is no base class and no registry.
 """
 
 from __future__ import annotations
@@ -37,6 +51,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from flax import struct
+
+from pytorch_distributed_tpu.ops.decode_attention import cached_attention
 
 __all__ = ["KVCache"]
 
@@ -102,6 +118,36 @@ class KVCache(struct.PyTreeNode):
         L, _, T, C = self.k.shape
         return 2 * L * T * C * per
 
+    def attend(self, layer: int, q, k_new, v_new, position_offset):
+        """Write the T new tokens' K/V rows into ``layer`` and attend over
+        each slot (``ops.decode_attention``): ``(y [B, T, H, D], cache)``.
+        ``position_offset=None`` is the fresh prefill: the new tokens attend
+        each other and the cache is written, never read."""
+        y, k, v = cached_attention(
+            q, k_new, v_new, self.k, self.v, layer, position_offset
+        )
+        return y, self.replace(k=k, v=v)
+
+    # -- prefill into one slot ---------------------------------------------
+    def one_slot(self, n_positions: int) -> "KVCache":
+        """A fresh one-slot cache of ``n_positions``, otherwise shaped as
+        this one: what a prompt is prefilled into before ``write_slot``
+        lands it, so that nothing of the resident cache is read."""
+        n_layers, _, _, width = self.k.shape
+        rows = jnp.zeros((n_layers, 1, n_positions, width), self.k.dtype)
+        return KVCache(k=rows, v=rows, lengths=jnp.zeros((1,), jnp.int32))
+
+    def write_slot(self, slot, block: "KVCache", length) -> "KVCache":
+        """``block`` (a ``one_slot`` cache, filled) written over positions
+        ``0..`` of ``slot`` as one in-place block, and ``lengths[slot] =
+        length`` (``slot`` and ``length`` may be traced)."""
+        at = (0, slot, 0, 0)
+        return self.replace(
+            k=jax.lax.dynamic_update_slice(self.k, block.k, at),
+            v=jax.lax.dynamic_update_slice(self.v, block.v, at),
+            lengths=self.lengths.at[slot].set(length),
+        )
+
     def evict(self, slot) -> "KVCache":
         """Free a slot (host or traced int). K/V bytes stay — masked out."""
         return self.replace(lengths=self.lengths.at[slot].set(0))
@@ -110,7 +156,7 @@ class KVCache(struct.PyTreeNode):
     def advance(self, n_tokens, active=None) -> "KVCache":
         """Multi-token append: ``lengths += n_tokens`` (``[S]`` or scalar),
         masked to ``active`` slots. The K/V bytes were already scattered by
-        the cached forward — this commits how many of them are real.
+        ``attend`` — this commits how many of them are real.
         """
         n = jnp.asarray(n_tokens, jnp.int32)
         if active is not None:

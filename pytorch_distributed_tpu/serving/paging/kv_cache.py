@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
+from pytorch_distributed_tpu.ops.paged_attention import paged_cached_attention
+
 __all__ = ["PagedKVCache", "fork_pages"]
 
 TRASH_PAGE = 0
@@ -122,6 +124,23 @@ class PagedKVCache(struct.PyTreeNode):
         per = self.k.dtype.itemsize
         L, _, T, H, D = self.k.shape
         return 2 * L * T * H * D * per
+
+    def attend(self, layer: int, q, k_new, v_new, position_offset):
+        """The cache protocol's one method (``serving.kv_cache``): scatter
+        the T new tokens' K/V through the block tables into ``layer``'s
+        pools and attend over each sequence's chain
+        (``ops.paged_attention``): ``(y [B, T, H, D], cache)`` with that
+        layer's pools written back. ``position_offset=None`` means every
+        sequence is fresh, from position 0."""
+        if position_offset is None:
+            position_offset = jnp.zeros((q.shape[0],), jnp.int32)
+        y, k, v = paged_cached_attention(
+            q, k_new, v_new, self.k[layer], self.v[layer],
+            self.block_tables, position_offset,
+        )
+        return y, self.replace(
+            k=self.k.at[layer].set(k), v=self.v.at[layer].set(v)
+        )
 
     # -- lifecycle (lengths/table bookkeeping; page ownership is host-side) -
     def evict(self, slot) -> "PagedKVCache":

@@ -189,7 +189,7 @@ class TestWorkers:
             list(DataLoader(Bad(), batch_size=2, num_workers=2))
 
     def test_throughput_scales_with_workers(self):
-        ds = _SlowDataset(n=48, delay=0.02)
+        ds = _SlowDataset(n=48, delay=0.04)
 
         def timed(workers):
             t0 = time.perf_counter()
@@ -198,11 +198,13 @@ class TestWorkers:
             assert n == 12
             return time.perf_counter() - t0
 
-        # 48 fetches x 20 ms ~= 0.96 s serial; 4 workers overlap sleeps.
+        # 48 fetches x 40 ms ~= 1.9 s serial; 4 workers overlap sleeps.
         # Generous bound: any real pipelining beats 0.6x. Timing on a
-        # loaded single-core host is noisy (worker spawn + IPC compete
-        # with whatever else runs) — best of 2 attempts keeps the claim
-        # without the load-flake.
+        # loaded host is noisy (worker spawn + IPC compete with whatever
+        # else runs: beside five other xdist workers they cost 0.35 s,
+        # which at 20 ms a fetch read 0.61x twice running) — the sleeps
+        # outweigh that, and best of 2 attempts keeps the claim without
+        # the load-flake.
         attempts = []
         for _ in range(2):
             serial = timed(0)

@@ -388,7 +388,11 @@ def test_eos_request_roundtrip(tiny):
     router = Router(store)
     prompt = np.asarray([5, 11, 17], np.int32)
     oracle = greedy_oracle(model, variables, prompt, 8)
-    eos = oracle[3]  # stop after 4 generated tokens
+    # stop mid-stream: EOS is the first token past the opening one that the
+    # continuation has not emitted before (a token seen earlier would end
+    # the request there; this oracle opens with one token five times over)
+    stop = next(i for i in range(1, len(oracle)) if oracle[i] not in oracle[:i])
+    eos = oracle[stop]
     rid = router.submit(Request(prompt=prompt, max_new_tokens=8, eos_token=eos))
     finished = []
     deadline = time.monotonic() + 60
@@ -397,7 +401,7 @@ def test_eos_request_roundtrip(tiny):
         finished.extend(router.step())
     (f,) = [x for x in finished if x.request_id == rid]
     assert f.reason == "eos"
-    assert f.tokens == oracle[:4]
+    assert f.tokens == oracle[: stop + 1]
 
 
 # -- full churn with real processes + TCPStore (satellite: failover) -------

@@ -25,6 +25,7 @@ from pytorch_distributed_tpu.ops import (
 )
 from pytorch_distributed_tpu.serving import (
     InferenceEngine,
+    KVCache,
     Request,
     Scheduler,
 )
@@ -273,6 +274,103 @@ def test_paged_decode_kernel_matches_reference():
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
     )
+
+
+# -- the cache protocol: one method, the same on both kinds ----------------
+KINDS = ("slotted", "paged")
+
+
+def _seam_cache(kind, cfg, rng=None):
+    """A two-slot cache of ``kind`` for ``cfg`` (12 positions a slot, each
+    paged slot owning three pages of four); with ``rng`` its K and V hold
+    noise, so that a write nobody asked for shows."""
+    if kind == "slotted":
+        cache = KVCache.create(cfg, n_slots=2, max_len=12)
+    else:
+        cache = PagedKVCache.create(cfg, n_slots=2, max_len=12, page_size=4)
+        cache = cache.set_table_row(0, [1, 2, 3]).set_table_row(1, [4, 5, 6])
+    if rng is not None:
+        cache = cache.replace(
+            k=jnp.asarray(rng.standard_normal(cache.k.shape), cache.k.dtype),
+            v=jnp.asarray(rng.standard_normal(cache.v.shape), cache.v.dtype),
+        )
+    return cache
+
+
+def _raw_op(kind, cache, layer, q, k_new, v_new, offset):
+    """What the op under ``cache.attend`` returns when called on the raw
+    arrays: ``(y, layer's K, layer's V)``."""
+    if kind == "slotted":
+        y, k, v = cached_attention(q, k_new, v_new, cache.k, cache.v, layer,
+                                   offset)
+        return y, k[layer], v[layer]
+    return paged_cached_attention(q, k_new, v_new, cache.k[layer],
+                                  cache.v[layer], cache.block_tables, offset)
+
+
+def _qkv(rng, cfg, T):
+    H, D = cfg.n_head, cfg.n_embd // cfg.n_head
+    return [jnp.asarray(rng.standard_normal((2, T, H, D)), jnp.float32)
+            for _ in range(3)]
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_attend_writes_its_layer_only_and_returns_the_ops_result(tiny, kind):
+    """``cache.attend(layer, ...)`` is the raw op on that layer and
+    nothing else: the other layer, the lengths and (paged) the block
+    tables come back bit-identical."""
+    cfg = tiny[0].cfg
+    rng = np.random.default_rng(11)
+    cache = _seam_cache(kind, cfg, rng)
+    q, k_new, v_new = _qkv(rng, cfg, 3)
+    offset = jnp.asarray([2, 5], jnp.int32)
+    y, new = cache.attend(1, q, k_new, v_new, offset)
+    want_y, want_k, want_v = _raw_op(kind, cache, 1, q, k_new, v_new, offset)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(want_y))
+    np.testing.assert_array_equal(np.asarray(new.k[1]), np.asarray(want_k))
+    np.testing.assert_array_equal(np.asarray(new.v[1]), np.asarray(want_v))
+    assert not np.array_equal(np.asarray(new.k[1]), np.asarray(cache.k[1]))
+    assert type(new) is type(cache)
+    old_rest = jax.tree.leaves(cache.replace(k=cache.k[0], v=cache.v[0]))
+    new_rest = jax.tree.leaves(new.replace(k=new.k[0], v=new.v[0]))
+    for a, b in zip(old_rest, new_rest):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_truncated_forward_leaves_later_layers_bit_identical(tiny, kind):
+    """``n_layers=1`` (the self-drafting draft) writes layer 0 and returns
+    layer 1 of the cache as it came."""
+    model, variables = tiny
+    rng = np.random.default_rng(12)
+    cache = _seam_cache(kind, model.cfg, rng)
+    tokens = jnp.asarray(rng.integers(0, 97, (2, 1)), jnp.int32)
+    _, new = model.apply(variables, tokens, kv_cache=cache,
+                         position_offset=jnp.asarray([3, 7], jnp.int32),
+                         n_layers=1)
+    for a, b in ((cache.k, new.k), (cache.v, new.v)):
+        np.testing.assert_array_equal(np.asarray(a[1]), np.asarray(b[1]))
+        assert not np.array_equal(np.asarray(a[0]), np.asarray(b[0]))
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_no_offset_means_every_sequence_fresh_from_position_zero(tiny, kind):
+    """``position_offset=None`` through ``attend``: rows land at positions
+    ``0..T-1`` and ``y`` is what an explicit zero offset gives on an empty
+    cache (to float32 rounding: the slotted op's fresh path contracts
+    T x T, its offset path against the folded rows)."""
+    cfg = tiny[0].cfg
+    rng = np.random.default_rng(13)
+    cache = _seam_cache(kind, cfg)
+    q, k_new, v_new = _qkv(rng, cfg, 5)
+    y_none, c_none = cache.attend(0, q, k_new, v_new, None)
+    y_zero, c_zero = cache.attend(0, q, k_new, v_new,
+                                  jnp.zeros((2,), jnp.int32))
+    np.testing.assert_allclose(np.asarray(y_none), np.asarray(y_zero),
+                               rtol=1e-5, atol=1e-6)
+    for a, b in zip(jax.tree.leaves(c_none), jax.tree.leaves(c_zero)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert np.asarray(c_none.k[0]).any() and not np.asarray(c_none.k[1]).any()
 
 
 # -- serving parity against the slotted oracle ------------------------------
