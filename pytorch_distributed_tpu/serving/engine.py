@@ -516,10 +516,7 @@ class InferenceEngine:
             dtype=self.cache_dtype,
         )
         if self.cache_sharding is not None:
-            cache = cache.replace(
-                k=jax.device_put(cache.k, self.cache_sharding),
-                v=jax.device_put(cache.v, self.cache_sharding),
-            )
+            cache = cache.placed(self.cache_sharding)
         return cache
 
     def init_draft_cache(self) -> Optional[KVCache]:
@@ -533,10 +530,7 @@ class InferenceEngine:
             max_len=self.max_len, dtype=self.cache_dtype,
         )
         if self.cache_sharding is not None:
-            cache = cache.replace(
-                k=jax.device_put(cache.k, self.cache_sharding),
-                v=jax.device_put(cache.v, self.cache_sharding),
-            )
+            cache = cache.placed(self.cache_sharding)
         return cache
 
     def _place_like(self, current, new, max_staging_bytes):

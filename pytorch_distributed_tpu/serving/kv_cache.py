@@ -39,9 +39,21 @@ through one method, the same on both classes:
 cache arrives whole and goes back whole with ``layer``'s new rows (or
 pages) written; ``position_offset [B]`` is each sequence's first new
 position, and ``None`` means every sequence is fresh, from position 0.
-Around it the engine and the scheduler use ``create / evict / advance /
-rollback`` and ``n_layers / n_slots / max_len``. Two classes with these
-names are the whole protocol; there is no base class and no registry.
+Around it the engine and the scheduler use ``create / placed / evict /
+advance / rollback`` and ``n_layers / n_slots / max_len``. Two classes with
+these names are the whole protocol; there is no base class and no registry.
+
+TWO READS, ONE RESULT. A sequence's earlier rows are read either by the
+lengths-aware kernel of ``ops.decode_attention`` (only the blocks of
+positions the slot holds: at a chat's occupancy a thirtieth of the cache)
+or by the dense contraction against every position of every slot. Which
+one is decided here, in ``attend``, from where the cache lies and from
+nothing anybody passes: the kernel where the backend is a TPU and the cache
+sits whole on each device; the dense read on any other backend (Mosaic has
+no CPU) and for a cache that ``placed`` laid out over a mesh (the
+partitioner cannot split a custom call, and would gather the cache to run
+it). A separation, not a choice: there is no configuration in which both
+could serve, so there is nothing to select.
 """
 
 from __future__ import annotations
@@ -52,7 +64,10 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
-from pytorch_distributed_tpu.ops.decode_attention import cached_attention
+from pytorch_distributed_tpu.ops.decode_attention import (
+    cached_attention,
+    kernel_reads,
+)
 
 __all__ = ["KVCache"]
 
@@ -69,6 +84,9 @@ class KVCache(struct.PyTreeNode):
     k: jax.Array
     v: jax.Array
     lengths: jax.Array
+    #: ``placed`` laid K and V out over a mesh (static: part of the tree's
+    #: structure, so a program is traced for one kind of cache)
+    sharded: bool = struct.field(pytree_node=False, default=False)
 
     @classmethod
     def create(
@@ -118,13 +136,26 @@ class KVCache(struct.PyTreeNode):
         L, _, T, C = self.k.shape
         return 2 * L * T * C * per
 
+    def placed(self, sharding) -> "KVCache":
+        """K and V laid out as ``sharding`` says (the TP plan's
+        ``serving.sharding.kv_cache_sharding``); the cache remembers that
+        it no longer lies whole on one device."""
+        return self.replace(
+            k=jax.device_put(self.k, sharding),
+            v=jax.device_put(self.v, sharding),
+            sharded=True,
+        )
+
     def attend(self, layer: int, q, k_new, v_new, position_offset):
         """Write the T new tokens' K/V rows into ``layer`` and attend over
         each slot (``ops.decode_attention``): ``(y [B, T, H, D], cache)``.
         ``position_offset=None`` is the fresh prefill: the new tokens attend
-        each other and the cache is written, never read."""
+        each other and the cache is written, never read. Otherwise the
+        earlier rows are read by the lengths-aware kernel wherever it can
+        run (module docstring), else densely."""
         y, k, v = cached_attention(
-            q, k_new, v_new, self.k, self.v, layer, position_offset
+            q, k_new, v_new, self.k, self.v, layer, position_offset,
+            kernel=not self.sharded and kernel_reads(self.k),
         )
         return y, self.replace(k=k, v=v)
 

@@ -125,6 +125,14 @@ class PagedKVCache(struct.PyTreeNode):
         L, _, T, H, D = self.k.shape
         return 2 * L * T * H * D * per
 
+    def placed(self, sharding) -> "PagedKVCache":
+        """The pools laid out as ``sharding`` says (the TP plan's
+        ``serving.sharding.paged_kv_cache_sharding``)."""
+        return self.replace(
+            k=jax.device_put(self.k, sharding),
+            v=jax.device_put(self.v, sharding),
+        )
+
     def attend(self, layer: int, q, k_new, v_new, position_offset):
         """The cache protocol's one method (``serving.kv_cache``): scatter
         the T new tokens' K/V through the block tables into ``layer``'s

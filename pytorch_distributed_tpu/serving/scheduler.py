@@ -117,6 +117,10 @@ class Scheduler:
         self.prev_tokens = np.zeros((engine.n_slots,), np.int32)
         self.active = np.zeros((engine.n_slots,), bool)
         self._n_active = 0  # == active.sum(), kept beside it by admit/evict
+        # cache positions the active sequences hold (prompt + tokens - 1
+        # each): what a decode step has to read at least; kept by admit /
+        # consume / evict, never recounted
+        self._kv_rows = 0
         self.ttft = LatencyTracker()
         self.decode_step = LatencyTracker()  # per decode step (whole batch)
         self.tokens_generated = 0
@@ -184,7 +188,7 @@ class Scheduler:
         Returns the requests that completed during this step.
         """
         with span("sched.step", step=self.steps, n_active=self._n_active,
-                  queued=len(self.queue)):
+                  queued=len(self.queue), kv_rows=self._kv_rows):
             self.steps += 1
             return self._step()
 
@@ -224,6 +228,7 @@ class Scheduler:
                     self.decode_steps += 1
                     n_act = self._n_active
                     self.tokens_generated += n_act
+                    self._kv_rows += n_act
                     self.tokens_per_forward.add(n_act)
                     put_metric("serving.tokens_generated", n_act)
                     n_before = len(finished)
@@ -272,6 +277,7 @@ class Scheduler:
                     st.tokens.append(tok)
                     self.last_tokens[slot] = tok
                     consumed += 1
+                    self._kv_rows += 1
                     done = self._maybe_finish(slot)
                     if done:
                         finished.extend(done)
@@ -449,6 +455,7 @@ class Scheduler:
             self.last_tokens[slot] = first_tok
             self.active[slot] = True
             self._n_active += 1
+            self._kv_rows += int(prompt.shape[0])
             self.tokens_generated += 1
             self.prefill_tokens_total += int(prompt.shape[0])
             self.prefill_tokens_cached += cached_len
@@ -513,6 +520,7 @@ class Scheduler:
             self.slots[slot] = None
             self.active[slot] = False
             self._n_active -= 1
+            self._kv_rows -= st.prompt.shape[0] + len(st.tokens) - 1
             fin = FinishedRequest(
                 request_id=st.request.request_id,
                 prompt=st.prompt,

@@ -13,12 +13,16 @@ Shapes are GPT-2 125M's (H=12, D=64, bf16): the flash forward and
 forward+backward in both kernel families — the grid-pruned static-causal
 one, and the positional one ring attention hops through
 (``q_pos``/``kv_pos``; different ``pallas_call``s) — at T=1024 and at one
-long T=8192, and ``paged_decode_attention`` at page sizes 16 and 128.
+long T=8192, ``paged_decode_attention`` at page sizes 16 and 128, and the
+slotted cache's lengths-aware read (``ops.decode_attention``) over the
+serve-chat cell's whole cache at T=1 (decode) and T=5 (speculative verify),
+and the same at GPT-2 large's 20 heads.
 
 One whole program is held the same way: the serving engine's decode step at
 the shapes of the ``gpt2-125m.serve-chat`` cell must write the slotted KV
-cache where it lies (PERF.md, PR 25) — the compiled module is the counter
-of that mechanism, so it engages always or the test fails.
+cache where it lies (PERF.md, PR 25) and read it with one kernel a layer
+and no fusion over a layer's slab (PR 32) — the compiled module is the
+counter of both mechanisms, so they engage always or the test fails.
 
 And one train step: ``Trainer`` under ``FullyShardedDataParallel`` on the
 ``(1, 4)`` mesh of all four described chips, at GPT-2 large's widths cut
@@ -120,6 +124,24 @@ def test_paged_decode_attention_compiles_for_v5e(v5e_device, page_size,
     )
 
 
+@pytest.mark.parametrize("T", [1, 5], ids=["decode_T1", "verify_T5"])
+@pytest.mark.parametrize("heads", [H, 20], ids=["gpt2_125m", "gpt2_large"])
+def test_decode_attention_kernel_compiles_for_v5e(v5e_device, heads, T):
+    """The read of the rows a slot holds, over the cell's whole cache
+    ``[12, 64, 1024, 768]`` left in HBM, with the row writes beside it;
+    and at GPT-2 large's 20 heads of 1,280-wide rows, which take two row
+    tiles a token where 12 heads take one."""
+    from pytorch_distributed_tpu.ops.decode_attention import cached_attention
+
+    new = ((64, T, heads, D), jnp.bfloat16)
+    cache = ((12, 64, 1024, heads * D), jnp.bfloat16)
+    _compile(
+        lambda q, k, v, kc, vc, offset: cached_attention(
+            q, k, v, kc, vc, 3, offset, kernel=True, interpret=False),
+        [new, new, new, cache, cache, ((64,), jnp.int32)], v5e_device,
+    )
+
+
 def _computations(hlo_text):
     """``{computation: [(name, opcode, elements, line), ...]}`` of a
     compiled module's text, and the names of the computations that are
@@ -143,15 +165,21 @@ def _computations(hlo_text):
     return found, fused
 
 
-def test_decode_program_writes_the_cache_in_place_for_v5e(v5e_device):
+def test_decode_program_writes_the_cache_in_place_for_v5e(v5e_device,
+                                                         monkeypatch):
     """64 slots x 1024 positions of GPT-2 125M in bf16, the cache donated:
     the step keeps under a tenth of the cache's bytes in temporaries,
     aliases every cache leaf to an output, and moves nothing the size of
     a layer's slab or of the cache except the 2 x 12 in-place row writes:
-    no ``copy``, no ``transpose``, no slab sliced out or rebuilt."""
+    no ``copy``, no ``transpose``, no slab sliced out or rebuilt. K and V
+    are read by one Mosaic kernel a layer and by no fusion."""
     from pytorch_distributed_tpu.analysis.ir.hlo import aliased_param_indices
     from pytorch_distributed_tpu.models.gpt2 import GPT2, GPT2Config
+    from pytorch_distributed_tpu.ops import decode_attention
     from pytorch_distributed_tpu.serving import InferenceEngine
+
+    # the described chip's program: ``jax.devices()`` here still says CPU
+    monkeypatch.setattr(decode_attention, "_platform", lambda: "tpu")
 
     slots, max_len = 64, 1024
     model = GPT2(GPT2Config(dtype=jnp.bfloat16, param_dtype=jnp.float32))
@@ -204,6 +232,15 @@ def test_decode_program_writes_the_cache_in_place_for_v5e(v5e_device):
         ops = [op for _, op, n, _ in computations[called.group(1)]
                if n >= slab and op not in ("parameter", "bitcast")]
         assert ops == ["scatter"], (name, ops)
+    # the read of K and V: one lengths-aware kernel a layer over the cache
+    # left in HBM, and no fusion but the row writes takes an operand the
+    # size of a layer's slab (the dense read sliced one out of the cache)
+    assert text.count('custom_call_target="tpu_custom_call"') == cfg.n_layer
+    readers = [c for c in fused
+               if any(op == "parameter" and n >= slab
+                      for _, op, n, _ in computations[c])
+               and not any(op == "scatter" for _, op, _, _ in computations[c])]
+    assert not readers, readers
 
 
 # -- the FSDP train step: parameters are gathered, activations are not -------

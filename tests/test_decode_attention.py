@@ -7,6 +7,12 @@ the plain causal T x T program for a fresh prefill). Every case here holds
 it, in bfloat16 as served, to a float32 ``jax.numpy`` attention over the
 SAME stored K/V; the cache starts full of stale bytes (a reused slot), and
 nothing of them may reach a result.
+
+A sequence's earlier rows have two reads (the dense contraction, and the
+lengths-aware Pallas kernel that copies in only the blocks a slot holds):
+every case runs both, the kernel in interpret mode, to one tolerance.
+``RAGGED`` adds what only the kernel can get wrong: block edges, idle
+slots, a full slot. Which read serves a cache is ``KVCache.attend``'s.
 """
 
 import numpy as np
@@ -42,11 +48,12 @@ def _reference(q, k_cache, v_cache, pos):
     """float32 attention of ``q [B,T,H,D]`` over layer LAYER of the caches
     as stored: query (b, t) sees positions <= pos[b, t]."""
     B, T = pos.shape
-    k = np.asarray(k_cache[LAYER], np.float32).reshape(B, TMAX, H, D)
-    v = np.asarray(v_cache[LAYER], np.float32).reshape(B, TMAX, H, D)
+    t_max = k_cache.shape[2]
+    k = np.asarray(k_cache[LAYER], np.float32).reshape(B, t_max, H, D)
+    v = np.asarray(v_cache[LAYER], np.float32).reshape(B, t_max, H, D)
     scores = jnp.einsum("bthd,bshd->bhts", jnp.asarray(q, jnp.float32), k)
     scores = scores / np.sqrt(D)
-    visible = np.arange(TMAX)[None, None, :] <= pos[:, :, None]
+    visible = np.arange(t_max)[None, None, :] <= pos[:, :, None]
     scores = jnp.where(visible[:, None], scores, -jnp.inf)
     return jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(scores, -1), v)
 
@@ -67,8 +74,14 @@ def _occupy(k_cache, v_cache, rng):
     return k_cache, v_cache
 
 
+#: the two reads of earlier rows; a fresh prefill reads nothing and takes
+#: the same arm under both
+READS = {"dense": False, "kernel": True}
+
+
+@pytest.mark.parametrize("read", READS)
 @pytest.mark.parametrize("case", CASES)
-def test_cached_attention_matches_float32_reference(case):
+def test_cached_attention_matches_float32_reference(case, read):
     T, offset = CASES[case]
     rng = np.random.default_rng(0)
     q, k_new, v_new = (
@@ -83,8 +96,11 @@ def test_cached_attention_matches_float32_reference(case):
     for stale_seed in (1, 2):
         k0, v0 = _occupy(*_stale_cache(stale_seed),
                          np.random.default_rng(3))
-        out, k1, v1 = jax.jit(cached_attention, static_argnums=5)(
-            q, k_new, v_new, k0, v0, LAYER, off)
+        out, k1, v1 = jax.jit(
+            cached_attention, static_argnums=5,
+            static_argnames=("kernel", "interpret"),
+        )(q, k_new, v_new, k0, v0, LAYER, off, kernel=READS[read],
+          interpret=True)
         assert out.shape == (S, T, H, D) and out.dtype == jnp.bfloat16
         assert k1.shape == k0.shape and k1.dtype == k0.dtype
         # the new rows lie where the positions say, nothing else moved
@@ -102,6 +118,140 @@ def test_cached_attention_matches_float32_reference(case):
     # another previous occupant, the very same result: stale bytes in a
     # reused slot are masked, not merely small
     np.testing.assert_array_equal(outs[0], outs[1])
+
+
+# -- lengths the dense read never cared about -------------------------------
+#: the kernel copies a slot's rows in 128-position blocks. Per case: T,
+#: each slot's offset, and the cache's positions
+RAGGED = {
+    "idle_slots_at_0": (1, (0, 37, 0, 0, 201, 0), 256),
+    "offsets_on_a_block_edge": (1, (127, 128, 129, 0, 1, 255), 256),
+    "every_slot_full": (1, (255,) * 6, 256),
+    "verify_T5_straddles_a_block_edge": (
+        5, (125, 126, 127, 128, 0, 251), 256),
+    "one_to_eight_blocks": (1, (511, 512, 513, 640, 1023, 0), 1024),
+}
+
+
+@pytest.mark.parametrize("case", RAGGED)
+def test_kernel_read_of_ragged_lengths_matches_float32_reference(case):
+    """The kernel against the float32 reference and against the dense
+    read, over a cache whose every position past a slot's new rows holds
+    large stale values: one of them reaching a result moves it by far more
+    than the tolerance, and two different fills must give the same bits."""
+    T, offset, t_max = RAGGED[case]
+    slots = len(offset)
+    rng = np.random.default_rng(11)
+    q, k_new, v_new = (
+        jnp.asarray(rng.normal(0, 1, (slots, T, H, D)), jnp.bfloat16)
+        for _ in range(3))
+    off = jnp.asarray(offset, jnp.int32)
+    pos = np.asarray(offset)[:, None] + np.arange(T)[None]
+    own = np.arange(t_max)[None, :, None] < pos[:, -1:, None] + 1
+    rows = rng.normal(0, 1, (2, L, slots, t_max, C))
+
+    outs = []
+    for stale_seed in (1, 2):
+        stale = np.random.default_rng(stale_seed).normal(
+            0, 1e4, (2, L, slots, t_max, C))
+        k0, v0 = (jnp.asarray(np.where(own, r, junk), jnp.bfloat16)
+                  for r, junk in zip(rows, stale))
+        read = jax.jit(cached_attention, static_argnums=5,
+                       static_argnames=("kernel", "interpret"))
+        out, k1, v1 = read(q, k_new, v_new, k0, v0, LAYER, off,
+                           kernel=True, interpret=True)
+        dense, kd, vd = read(q, k_new, v_new, k0, v0, LAYER, off)
+        np.testing.assert_array_equal(np.asarray(k1), np.asarray(kd))
+        np.testing.assert_array_equal(np.asarray(v1), np.asarray(vd))
+        ref = np.asarray(_reference(q, k1, v1, pos))
+        for got in (out, dense):
+            np.testing.assert_allclose(
+                np.asarray(got, np.float32), ref, rtol=2e-2, atol=2e-2)
+        outs.append(np.asarray(out))
+    np.testing.assert_array_equal(outs[0], outs[1])
+
+
+@pytest.mark.parametrize("T", [1, 3])
+@pytest.mark.parametrize("heads", [12, 16, 17, 20])
+def test_kernel_read_adapts_to_the_head_count(heads, T):
+    """A token's query rows are one per head, padded to whole 16-row
+    tiles: one tile to 16 heads, two from 17 (GPT-2 large has 20). The
+    padding rows own no column and must add nothing to a result."""
+    slots, t_max, width = 3, 256, heads * D
+    offset = (130, 0, 255 - T)
+    rng = np.random.default_rng(heads)
+    q, k_new, v_new = (
+        jnp.asarray(rng.normal(0, 1, (slots, T, heads, D)), jnp.bfloat16)
+        for _ in range(3))
+    k0, v0 = (jnp.asarray(rng.normal(0, 1, (L, slots, t_max, width)),
+                          jnp.bfloat16) for _ in range(2))
+    off = jnp.asarray(offset, jnp.int32)
+    read = jax.jit(cached_attention, static_argnums=5,
+                   static_argnames=("kernel", "interpret"))
+    out, k1, v1 = read(q, k_new, v_new, k0, v0, LAYER, off,
+                       kernel=True, interpret=True)
+    dense, kd, vd = read(q, k_new, v_new, k0, v0, LAYER, off)
+    np.testing.assert_array_equal(np.asarray(k1), np.asarray(kd))
+    pos = np.asarray(offset)[:, None] + np.arange(T)[None]
+    k = np.asarray(k1[LAYER], np.float32).reshape(slots, t_max, heads, D)
+    v = np.asarray(v1[LAYER], np.float32).reshape(slots, t_max, heads, D)
+    scores = jnp.einsum("bthd,bshd->bhts", jnp.asarray(q, jnp.float32), k)
+    visible = np.arange(t_max)[None, None, :] <= pos[:, :, None]
+    scores = jnp.where(visible[:, None], scores / np.sqrt(D), -jnp.inf)
+    ref = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(scores, -1), v)
+    for got in (out, dense):
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(ref),
+            rtol=2e-2, atol=2e-2)
+
+
+def test_the_cache_chooses_its_read_from_where_it_lies(monkeypatch):
+    """``KVCache.attend`` reads with the kernel exactly where the backend
+    is a TPU and the cache lies whole on a device; a cache that ``placed``
+    laid out over a mesh, and any cache on another backend, reads densely.
+    Nobody passes the choice in."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from pytorch_distributed_tpu.ops import decode_attention
+    from pytorch_distributed_tpu.serving import kv_cache
+
+    cfg = GPT2Config(n_embd=128, n_head=4, n_layer=2, n_positions=128,
+                     dtype=jnp.bfloat16)
+    chosen = []
+
+    def spy(q, *rest, kernel, **kw):
+        chosen.append(kernel)
+        return q, rest[2], rest[3]
+
+    monkeypatch.setattr(kv_cache, "cached_attention", spy)
+    q = jnp.zeros((2, 1, 4, 32), jnp.bfloat16)
+    offset = jnp.zeros((2,), jnp.int32)
+    whole = KVCache.create(cfg, n_slots=2, max_len=128)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
+    laid_out = whole.placed(NamedSharding(mesh, P(None, None, None, "tp")))
+    assert laid_out.sharded and not whole.sharded
+    assert laid_out.k.sharding.spec == P(None, None, None, "tp")
+
+    for platform, cache, want in (
+            ("cpu", whole, False), ("cpu", laid_out, False),
+            ("tpu", whole, True), ("tpu", laid_out, False)):
+        monkeypatch.setattr(decode_attention, "_platform", lambda: platform)
+        cache.attend(0, q, q, q, offset)
+        assert chosen.pop() is want, (platform, cache.sharded)
+    # any head count is the kernel's: GPT-2 large, 20 heads of 1,280 wide
+    # rows, was served before there was a kernel and still is
+    large = KVCache.create(
+        GPT2Config(n_embd=1280, n_head=20, n_layer=1, n_positions=128,
+                   dtype=jnp.bfloat16), n_slots=2, max_len=128)
+    q20 = jnp.zeros((2, 1, 20, 64), jnp.bfloat16)
+    large.attend(0, q20, q20, q20, offset)
+    assert chosen.pop() is True
+    # a cache Mosaic cannot copy whole blocks of stays with the dense read
+    odd = KVCache.create(cfg, n_slots=2, max_len=96)
+    odd.attend(0, q, q, q, offset)
+    assert chosen.pop() is False
+    # the static field travels with the tree: a traced cache still knows
+    assert jax.eval_shape(lambda c: c, laid_out).sharded
 
 
 def test_cached_attention_rejects_a_cache_that_is_not_the_batch():

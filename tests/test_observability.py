@@ -158,7 +158,9 @@ def _tiny_gpt2():
 @pytest.fixture(scope="module")
 def served(tmp_path_factory):
     """Seven requests through two slots under a profiler session: the
-    spans the scheduler and the engine wrote, and what was finished."""
+    spans the scheduler and the engine wrote, what was finished, and the
+    cache positions the slots held as each step began (recounted from the
+    slots, outside the session's spans)."""
     from pytorch_distributed_tpu.observability import profile_trace
     from pytorch_distributed_tpu.serving import (
         InferenceEngine,
@@ -177,8 +179,13 @@ def served(tmp_path_factory):
             sched.submit(Request(
                 prompt=rng.integers(0, 97, int(rng.integers(2, 8))),
                 max_new_tokens=int(rng.integers(2, 9))))
-        finished = sched.run()
-    return _pdt_spans(trace_dir), finished
+        finished, held = [], []
+        while sched.has_work:
+            held.append((sum(st.prompt.shape[0] + len(st.tokens) - 1
+                             for st in sched.slots if st is not None),
+                         type(sched._kv_rows)))
+            finished.extend(sched.step())
+    return _pdt_spans(trace_dir), finished, held
 
 
 @pytest.fixture(scope="module")
@@ -234,9 +241,10 @@ class TestSpans:
 
     def test_a_scheduler_step_encloses_admission_decode_and_consume(
             self, served):
-        spans, _ = served
+        spans, _, _ = served
         step = _named(spans, "sched.step")[0]
-        assert step.stats == {"step": 0, "n_active": 0, "queued": 7}
+        assert step.stats == {"step": 0, "n_active": 0, "queued": 7,
+                              "kv_rows": 0}
         inside = [s for s in spans if s.parent is step]
         assert [s.name for s in inside] == [
             "sched.admit", "sched.admit", "engine.decode", "sched.consume"]
@@ -259,8 +267,27 @@ class TestSpans:
             assert 1 <= consume.stats["tokens"] <= 2
             assert consume.parent.name == "sched.step"
 
+    def test_a_step_says_how_many_cache_rows_its_sequences_hold(self, served):
+        """``kv_rows`` is what a decode step has to read at least: the sum
+        over active sequences of prompt + tokens - 1, through admissions,
+        decode steps, evictions and re-admissions into the freed slots;
+        kept as a Python int, so the stat costs no array operation."""
+        spans, finished, held = served
+        steps = _named(spans, "sched.step")
+        assert [s.stats["kv_rows"] for s in steps] == [n for n, _ in held]
+        assert all(kind is int for _, kind in held)
+        rows = [n for n, _ in held]
+        # seven requests through two slots: the count rose, fell at an
+        # eviction and rose again at the re-admission, and ends empty
+        falls = [i for i in range(1, len(rows)) if rows[i] < rows[i - 1]]
+        assert falls and any(rows[j] > rows[j - 1]
+                             for j in range(falls[0] + 1, len(rows)))
+        assert rows[0] == 0 and len(finished) == 7
+        evicted = _named(spans, "sched.evict")
+        assert len(evicted) == 7
+
     def test_every_admitted_request_is_evicted_with_its_tokens(self, served):
-        spans, finished = served
+        spans, finished, _ = served
         admitted = [s.stats["request_id"]
                     for s in _named(spans, "sched.admit")]
         evicted = {s.stats["request_id"]: s.stats
@@ -280,7 +307,7 @@ class TestSpans:
     def test_queue_wait_counts_from_arrival(self, served):
         """Two slots, seven requests submitted at once: the third waits
         until a slot frees, and its wait is in its time to first token."""
-        spans, finished = served
+        spans, finished, _ = served
         by_id = {f.request_id: f for f in finished}
         for fin in finished:
             assert 0 <= fin.queue_s < fin.ttft_s <= fin.total_s
