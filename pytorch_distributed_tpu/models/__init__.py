@@ -15,6 +15,7 @@ from pytorch_distributed_tpu.models.resnet import (
     resnet101,
 )
 from pytorch_distributed_tpu.models.gpt2 import GPT2, GPT2Config, gpt2_125m
+from pytorch_distributed_tpu.models.xing4 import Xing4, Xing4Config
 
 __all__ = [
     "ResNet",
@@ -25,4 +26,6 @@ __all__ = [
     "GPT2",
     "GPT2Config",
     "gpt2_125m",
+    "Xing4",
+    "Xing4Config",
 ]

@@ -31,7 +31,7 @@ from pytorch_distributed_tpu.serving.engine import (
     SamplingParams,
     sample_tokens,
 )
-from pytorch_distributed_tpu.serving.kv_cache import KVCache
+from pytorch_distributed_tpu.serving.kv_cache import KVCache, LatentCache
 from pytorch_distributed_tpu.serving.paging import (
     CapacityError,
     PageAllocator,
@@ -64,6 +64,7 @@ from pytorch_distributed_tpu.serving.speculative import (
 
 __all__ = [
     "KVCache",
+    "LatentCache",
     "PagedKVCache",
     "PageAllocator",
     "RadixTree",

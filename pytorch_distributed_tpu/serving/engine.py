@@ -1,4 +1,5 @@
-"""Inference engine: jitted prefill + decode + speculative steps over GPT-2.
+"""Inference engine: jitted prefill + decode + speculative steps over a model
+with the cache-aware forward contract (``models.gpt2``, ``models.xing4``).
 
 Compiled programs serve the whole session (the prefill/decode split of
 every production LLM server — Orca, vLLM, TGI):
@@ -9,18 +10,14 @@ every production LLM server — Orca, vLLM, TGI):
     cache is read), its K/V rows land in ONE slot of the shared cache as
     one ``[L, 1, bucket, H*D]`` block, and the first generated token is
     sampled from the last real prompt position's logits. Prompts pad to
-    the smallest LENGTH BUCKET (powers of two up to ``prefill_len``) so
-    short prompts stop paying full-length prefill compute; jit caches one
-    program per bucket.
-  * ``decode``  — ``[n_slots, 1]``: every slot advances one token per call,
-    attention runs over each slot's cache, and only ACTIVE slots' lengths
-    advance (free slots ride along as padding — the decode batch shape
-    never changes, so the program compiles exactly once). The slotted
-    cache is stored ``[L, S, max_len, H*D]`` (``serving.kv_cache``): the
-    step scatters ``2 * L * S`` rows into the donated arrays where they
-    lie and reads K and V once, as stored — no layer's slab is copied,
-    re-laid-out, sliced out or rebuilt (``tests/test_chip_compile.py``
-    holds the compiled program to it).
+    the smallest LENGTH BUCKET (powers of two up to ``prefill_len``): short
+    prompts stop paying full-length compute; one program per bucket.
+  * ``decode``  — ``[n_slots, 1]``: every slot advances one token per call
+    and only ACTIVE slots' lengths advance (free slots ride as padding:
+    the batch shape never changes, the program compiles once). The step
+    scatters its new rows into the donated slotted cache where they lie
+    and reads the earlier ones as stored (``serving.kv_cache``;
+    ``tests/test_chip_compile.py`` holds the compiled program to it).
   * ``spec``    — speculative decoding (``spec_k > 0``): a cheap draft
     proposes k tokens per slot into scratch cache positions past each
     slot's length, then ONE target forward over the ``[S, k+1]`` window
@@ -33,10 +30,9 @@ every production LLM server — Orca, vLLM, TGI):
     compile once — no realloc, no shape churn.
 
 All step programs donate the cache pytree, and the model's forward threads
-the whole cache through its layers (``models.gpt2``), each layer writing
-its own rows through ``cache.attend``: K/V updates are in-place HBM writes.
-How K and V are stored is the cache classes' business (``serving.kv_cache``
-states the protocol).
+the whole cache through its layers, each writing its own rows through
+``cache.attend``: in-place HBM writes. How a sequence's state is stored is
+the cache classes' business (``serving.kv_cache`` states the protocol).
 
 Sampling (greedy / temperature / top-k / nucleus top-p) happens inside the
 jitted step — only sampled token ids cross the host boundary each step,
@@ -129,15 +125,15 @@ def _default_buckets(prefill_len: int) -> Tuple[int, ...]:
 
 def _slot_prefill(apply_fn, params, cache, tokens, slot, prompt_len):
     """Run ``tokens [1, bucket]`` through ``apply_fn`` into one slot of
-    ``cache``; returns ``(logits, cache)`` with ``lengths[slot] =
-    prompt_len``. The prompt is the slot's first occupant from position 0,
-    so nothing of the resident cache is read: the forward runs on a fresh
-    one-slot cache of exactly ``bucket`` positions with no position offset
-    (the new tokens attend each other, O(bucket^2)), and its rows land in
-    the resident cache as one block, in place."""
+    ``cache``: ``(logits, cache)`` with ``lengths[slot] = prompt_len``. The
+    forward runs on a fresh one-slot cache of ``bucket`` positions with no
+    position offset (nothing resident is read; O(bucket^2)); its rows land
+    as one block, in place. The one-slot cache carries ``prompt_len``: a
+    model may give the last real position's logits alone, ``[1, 1, V]``."""
     logits, block = apply_fn(
         params, tokens, deterministic=True,
-        kv_cache=cache.one_slot(tokens.shape[1]), position_offset=None,
+        kv_cache=cache.one_slot(tokens.shape[1], prompt_len),
+        position_offset=None,
     )
     return logits, cache.write_slot(slot, block, prompt_len)
 
@@ -146,8 +142,8 @@ class InferenceEngine:
     """Compiled prefill/decode over a flax GPT-2 and a slotted KVCache.
 
     Args:
-      model: a ``models.GPT2`` (dense; MoE configs are rejected by the
-        cache-aware forward).
+      model: a ``models.GPT2`` (dense) or a model that names the class of
+        its own slotted cache (``model.cache_class``: ``models.Xing4``).
       params: the model's param pytree — host numpy, device arrays, or
         TP-sharded arrays from ``serving.sharding.load_gpt2_params``.
       n_slots: decode batch width (concurrent sequences).
@@ -209,9 +205,11 @@ class InferenceEngine:
         n_pages: Optional[int] = None,
     ):
         cfg = model.cfg
-        if cfg.moe_experts > 0:
+        if getattr(cfg, "moe_experts", 0) > 0:
             raise ValueError("serving supports dense GPT-2 only (MoE "
                              "blocks have no KV-cache story yet)")
+        slotted = _slotted_cache_class(
+            model, cache_kind, cache_sharding, spec_k)
         sampling.validate()
         self.model = model
         self.cfg = cfg
@@ -260,7 +258,8 @@ class InferenceEngine:
         self._create_cache = functools.partial(
             PagedKVCache.create, page_size=self.page_size,
             n_pages=self.n_pages,
-        ) if paged else KVCache.create
+        ) if paged else slotted.create
+        self._step_stats = tuple(getattr(slotted, "STEP_STATS", ()))
 
         # -- speculative configuration -------------------------------------
         self.spec_k = int(spec_k)
@@ -323,7 +322,8 @@ class InferenceEngine:
             logits, cache = _slot_prefill(
                 model_apply, params, cache, tokens, slot, prompt_len
             )
-            last = logits[0, prompt_len - 1]
+            whole = logits.shape[1] == tokens.shape[1]
+            last = logits[0, prompt_len - 1] if whole else logits[0, 0]
             tok = sample_tokens(last[None], rng, sp)[0]
             return cache, tok
 
@@ -361,6 +361,8 @@ class InferenceEngine:
                 kv_cache=cache, position_offset=cache.lengths,
             )
             next_tok = sample_tokens(logits[:, 0, :], rng, sp)
+            if self._step_stats:
+                next_tok = jnp.concatenate([next_tok, new_cache.step_stats])
             # only active slots advance; free slots ride as padding and
             # their (masked, overwritten-on-admit) cache rows don't move
             return new_cache.advance(1, active), next_tok
@@ -705,7 +707,7 @@ class InferenceEngine:
         tail or last sample); ``active [S]`` bool. Returns the updated
         cache and the sampled tokens ``[S]`` (garbage at inactive slots —
         the scheduler ignores them)."""
-        with span("engine.decode"):
+        with span("engine.decode") as whole:
             with span("engine.decode.dispatch") as dispatch:
                 cache, toks = self._decode(
                     self.params, cache,
@@ -716,7 +718,11 @@ class InferenceEngine:
                 dispatch.set_metadata(executables=self._decode._cache_size())
             with span("engine.decode.read"):
                 toks = np.asarray(toks)  # waits for the device, copies back
-        return cache, toks
+            if self._step_stats:
+                whole.set_metadata(**{
+                    name: int(n) for name, n in zip(
+                        self._step_stats, toks[self.n_slots:])})
+        return cache, toks[:self.n_slots]
 
     def spec_decode(
         self,
@@ -759,3 +765,27 @@ class InferenceEngine:
                 out = (np.asarray(emitted), np.asarray(counts),
                        np.asarray(prev_next))
         return (cache, dcache, *out)
+
+
+def _slotted_cache_class(model, cache_kind, cache_sharding, spec_k):
+    """The class of the slotted cache a model is served from: ``KVCache``
+    unless the model names its own (``model.cache_class``; ``models.xing4``
+    names ``LatentCache``). What such a cache does not support raises here,
+    at construction, with a sentence. A class's ``STEP_STATS`` name the
+    counts its model leaves in ``cache.step_stats`` each step: the decode
+    program sends them to the host behind the step's tokens, in the one
+    read the step makes anyway, onto the ``pdt.engine.decode`` span."""
+    slotted = getattr(model, "cache_class", KVCache)
+    if slotted is not KVCache:
+        unsupported = [what for what, asked in (
+            ("cache_kind='paged'", cache_kind == "paged"),
+            ("cache_sharding", cache_sharding is not None),
+            ("spec_k > 0", spec_k > 0)) if asked]
+        if unsupported:
+            raise ValueError(
+                f"{type(model).__name__} is served from a "
+                f"{slotted.__name__} only, whole on one device and one "
+                f"token a step: {', '.join(unsupported)} not supported "
+                f"(a paged latent pool, a tensor-parallel plan and "
+                f"drafting are ROADMAP items)")
+    return slotted

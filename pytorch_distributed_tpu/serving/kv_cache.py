@@ -37,11 +37,11 @@ through one method, the same on both classes:
 
 ``q / k_new / v_new`` are ``[B, T, H, D]`` (batch row b is slot b); the
 cache arrives whole and goes back whole with ``layer``'s new rows (or
-pages) written; ``position_offset [B]`` is each sequence's first new
-position, and ``None`` means every sequence is fresh, from position 0.
-Around it the engine and the scheduler use ``create / placed / evict /
-advance / rollback`` and ``n_layers / n_slots / max_len``. Two classes with
-these names are the whole protocol; there is no base class and no registry.
+pages) written; ``position_offset [B]`` is its sequences' first new
+positions, ``None`` if all are fresh. Around it the engine and scheduler
+use ``create / placed / evict / advance / rollback``, ``n_layers / n_slots
+/ max_len``. The names are the protocol; ``LatentCache`` (below, with its
+own ``attend`` operands) is a third class with them. No base, no registry.
 
 TWO READS, ONE RESULT. A sequence's earlier rows are read either by the
 lengths-aware kernel of ``ops.decode_attention`` (only the blocks of
@@ -64,12 +64,12 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
+from pytorch_distributed_tpu.ops import latent_attention
 from pytorch_distributed_tpu.ops.decode_attention import (
-    cached_attention,
-    kernel_reads,
-)
+    cached_attention, kernel_reads)
+# (folded so KVCache.attend keeps line 156: GPT-2's decode kernel records it)
 
-__all__ = ["KVCache"]
+__all__ = ["KVCache", "LatentCache"]
 
 
 class KVCache(struct.PyTreeNode):
@@ -160,13 +160,16 @@ class KVCache(struct.PyTreeNode):
         return y, self.replace(k=k, v=v)
 
     # -- prefill into one slot ---------------------------------------------
-    def one_slot(self, n_positions: int) -> "KVCache":
+    def one_slot(self, n_positions: int, length=0) -> "KVCache":
         """A fresh one-slot cache of ``n_positions``, otherwise shaped as
         this one: what a prompt is prefilled into before ``write_slot``
-        lands it, so that nothing of the resident cache is read."""
+        lands it, so that nothing of the resident cache is read.
+        ``length`` (host or traced int) says how many of the positions the
+        prompt will really fill."""
         n_layers, _, _, width = self.k.shape
         rows = jnp.zeros((n_layers, 1, n_positions, width), self.k.dtype)
-        return KVCache(k=rows, v=rows, lengths=jnp.zeros((1,), jnp.int32))
+        return KVCache(k=rows, v=rows,
+                       lengths=jnp.full((1,), length, jnp.int32))
 
     def write_slot(self, slot, block: "KVCache", length) -> "KVCache":
         """``block`` (a ``one_slot`` cache, filled) written over positions
@@ -199,4 +202,114 @@ class KVCache(struct.PyTreeNode):
         new length keep their speculative K/V bytes — the masking invariant
         hides them and the next step's writes overwrite them, so no memset,
         no realloc, no shape churn."""
+        return self.replace(lengths=jnp.asarray(lengths, jnp.int32))
+
+
+class LatentCache(struct.PyTreeNode):
+    """The slotted cache of a latent-attention (MLA) model: ONE row a token
+    a layer, ``rows [L, S, T, W]`` = ``[c_kv | k_r | 0]`` padded to whole
+    lanes (``ops.latent_attention`` says why 576 is stored 640 wide), and
+    per-slot ``lengths [S]``. The protocol's names are ``KVCache``'s and so
+    is the slot lifecycle; what ``attend`` takes is this format's own: the
+    queries, the new latents and the map ``W_kvb`` from a latent to a
+    head's keys and values, which a fresh prefill expands with and a decode
+    step absorbs into its queries.
+
+    ``step_stats [len(STEP_STATS)]`` is what the model counted while it
+    last ran over this cache (``experts_hit``: the distinct experts that
+    got a token, summed over layers); the engine sends it to the host in
+    the read of the step's tokens."""
+
+    STEP_STATS = ("experts_hit",)
+
+    rows: jax.Array
+    lengths: jax.Array
+    step_stats: jax.Array
+
+    @classmethod
+    def create(cls, cfg: Any, *, n_slots: int, max_len: int,
+               dtype: Any = None) -> "LatentCache":
+        """Zero-filled cache for a config with ``n_layer``,
+        ``kv_lora_rank``, ``qk_rope_head_dim``, ``n_positions``, ``dtype``."""
+        if max_len > cfg.n_positions:
+            raise ValueError(
+                f"max_len {max_len} exceeds model n_positions "
+                f"{cfg.n_positions}")
+        if n_slots < 1:
+            raise ValueError("n_slots must be >= 1")
+        width = latent_attention.row_width(cfg.kv_lora_rank,
+                                           cfg.qk_rope_head_dim)
+        return cls(
+            rows=jnp.zeros((cfg.n_layer, n_slots, max_len, width),
+                           dtype or cfg.dtype),
+            lengths=jnp.zeros((n_slots,), jnp.int32),
+            step_stats=jnp.zeros((len(cls.STEP_STATS),), jnp.int32),
+        )
+
+    @property
+    def n_layers(self) -> int:
+        return self.rows.shape[0]
+
+    @property
+    def n_slots(self) -> int:
+        return self.rows.shape[1]
+
+    @property
+    def max_len(self) -> int:
+        return self.rows.shape[2]
+
+    def placed(self, sharding) -> "LatentCache":
+        raise NotImplementedError(
+            "a latent cache is one row for all heads: there is no head "
+            "axis to lay over a mesh (ROADMAP: a tensor-parallel plan for "
+            "latent attention)")
+
+    def attend(self, layer: int, q, latent, kv_b, position_offset, *,
+               scale: float):
+        """Write the T new tokens' latents into ``layer`` and attend over
+        each slot: ``(y [B, T, H, d_v], cache)``. ``q [B, T, H, d_n + d_r]``,
+        ``latent [B, T, d_c + d_r]``, ``kv_b [d_c, H, d_n + d_v]``.
+        ``position_offset=None`` is the fresh prefill (expanded, nothing
+        read); otherwise the absorbed read, by the lengths-aware kernel
+        wherever it can run, else densely."""
+        d_c = kv_b.shape[0]
+        y, rows = latent_attention.latent_attention(
+            q, latent, kv_b, self.rows, layer, position_offset,
+            d_c=d_c, d_n=q.shape[-1] - (latent.shape[-1] - d_c),
+            scale=scale, kernel=kernel_reads(self.rows),
+        )
+        return y, self.replace(rows=rows)
+
+    def counted(self, **stats) -> "LatentCache":
+        """The cache with the step's counts (``STEP_STATS``) set."""
+        return self.replace(step_stats=jnp.stack(
+            [jnp.asarray(stats[name], jnp.int32)
+             for name in self.STEP_STATS]))
+
+    # -- prefill into one slot ---------------------------------------------
+    def one_slot(self, n_positions: int, length=0) -> "LatentCache":
+        n_layers, _, _, width = self.rows.shape
+        return self.replace(
+            rows=jnp.zeros((n_layers, 1, n_positions, width),
+                           self.rows.dtype),
+            lengths=jnp.full((1,), length, jnp.int32))
+
+    def write_slot(self, slot, block: "LatentCache", length) -> "LatentCache":
+        return self.replace(
+            rows=jax.lax.dynamic_update_slice(self.rows, block.rows,
+                                              (0, slot, 0, 0)),
+            lengths=self.lengths.at[slot].set(length),
+            step_stats=block.step_stats,
+        )
+
+    def evict(self, slot) -> "LatentCache":
+        return self.replace(lengths=self.lengths.at[slot].set(0))
+
+    def advance(self, n_tokens, active=None) -> "LatentCache":
+        n = jnp.asarray(n_tokens, jnp.int32)
+        if active is not None:
+            n = jnp.where(active, n, 0)
+        return self.replace(lengths=self.lengths + n)
+
+    def rollback(self, lengths) -> "LatentCache":
         return self.replace(lengths=jnp.asarray(lengths, jnp.int32))

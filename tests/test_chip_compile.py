@@ -243,6 +243,125 @@ def test_decode_program_writes_the_cache_in_place_for_v5e(v5e_device,
     assert not readers, readers
 
 
+# -- Xing4.0 at the serve-docqa cell's shapes: the latent cache ---------------
+
+#: ``memory_stats()["bytes_limit"]`` of one v5e chip (my chip run, PR 33)
+V5E_BYTES_LIMIT = 16_909_336_064
+
+
+def _xing4_engine(v5e_device, monkeypatch, n_layer):
+    """The engine of ``xing4.0-29b-a4b.serve-docqa`` (published widths,
+    bfloat16, 48 slots x 8,192 positions) over described shapes, cut to
+    ``n_layer`` layers (one of them dense)."""
+    from pytorch_distributed_tpu.models import Xing4, Xing4Config
+    from pytorch_distributed_tpu.ops import decode_attention
+    from pytorch_distributed_tpu.serving import InferenceEngine
+
+    monkeypatch.setattr(decode_attention, "_platform", lambda: "tpu")
+    model = Xing4(Xing4Config(n_layer=n_layer, first_k_dense_replace=1,
+                              dtype=jnp.bfloat16, param_dtype=jnp.bfloat16))
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=v5e_device), tree)
+
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    engine = InferenceEngine(model, params, n_slots=48, max_len=8192)
+    cache = described(jax.eval_shape(engine.init_cache))
+    rng = described(jax.eval_shape(lambda: jax.random.key(0)))
+    return engine, described(params), cache, rng
+
+
+def _bytes(tree):
+    return sum(a.size * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(tree))
+
+
+def test_latent_decode_program_writes_the_cache_in_place_for_v5e(
+        v5e_device, monkeypatch):
+    """Six layers (4.79 G parameters), the cache donated: the step keeps
+    under a tenth of the 3.0 GB cache in temporaries, aliases every cache
+    leaf to an output, moves nothing the size of a layer's slab but the six
+    in-place row writes, and reads the rows with six calls of ONE Mosaic
+    kernel and with no fusion."""
+    from pytorch_distributed_tpu.analysis.ir.hlo import aliased_param_indices
+
+    engine, params, cache, rng = _xing4_engine(v5e_device, monkeypatch, 6)
+    assert _bytes(params) == 9_596_580_368
+    slots, max_len, width = 48, 8192, 640
+    assert cache.rows.shape == (6, slots, max_len, width)
+    compiled = engine._decode.lower(
+        params, cache,
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_device),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e_device), rng,
+    ).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < _bytes(cache) / 10, memory
+    text = compiled.as_text()
+    first = len(jax.tree_util.tree_leaves(params))
+    # the rows and the lengths (the arguments after the weights) alias an
+    # output; the step's counts are made anew
+    assert aliased_param_indices(text) == [first, first + 1]
+
+    slab = slots * max_len * width
+    computations, fused = _computations(text)
+    relayouts = [line for body in computations.values()
+                 for _, opcode, elements, line in body
+                 if opcode in ("copy", "transpose") and elements >= slab]
+    assert not relayouts, relayouts[:3]
+    big = [(name, opcode, line)
+           for c, body in computations.items() if c not in fused
+           for name, opcode, elements, line in body if elements >= slab
+           and opcode not in ("parameter", "get-tuple-element", "tuple",
+                              "bitcast")]
+    assert len(big) == 6, [name for name, *_ in big]
+    for name, opcode, line in big:
+        called = re.search(r"calls=%?([\w.\-]+)", line)
+        assert opcode == "fusion" and called, line
+        ops = [op for _, op, n, _ in computations[called.group(1)]
+               if n >= slab and op not in ("parameter", "bitcast")]
+        assert ops == ["scatter"], (name, ops)
+    kernels = re.findall(r"[^\n]*latent_attention_read/pallas_call[^\n]*",
+                         text)
+    assert len([k for k in kernels if "tpu_custom_call" in k]) == 6
+    # one jit of the kernel shared by the layers: the lowered module
+    # defines the kernel's function once and calls it six times
+    lowered = engine._decode.lower(
+        params, cache,
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_device),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e_device), rng,
+    ).as_text()
+    assert len(re.findall(r"func.func private @_kernel_read", lowered)) == 1
+    assert len(re.findall(r"call @_kernel_read", lowered)) == 6
+    # no fusion but the row writes takes the cache or a layer's slab of it
+    # (weights this large there are: the 131,072-row embedding and head)
+    readers = [c for c in fused
+               if any(op == "parameter" and "48,8192,640]" in line
+                      for _, op, _, line in computations[c])
+               and not any(op == "scatter" for _, op, _, _ in computations[c])]
+    assert not readers, readers
+
+
+def test_latent_prefill_bucket_8192_fits_beside_the_resident_state_for_v5e(
+        v5e_device, monkeypatch):
+    """The longest prefill bucket. Its temporaries do not depend on the
+    depth (a layer's buffers are the next one's), so the program is compiled
+    at two layers (one dense, one of experts) and laid beside the six-layer
+    cell's resident state: weights, cache, the one-slot block."""
+    engine, params, cache, rng = _xing4_engine(v5e_device, monkeypatch, 2)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e_device)
+    compiled = engine._prefill.lower(
+        params, cache,
+        jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=v5e_device),
+        i32, i32, rng).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    resident = 9_596_580_368 + 6 * 48 * 8192 * 640 * 2
+    assert temp < 3.4e9, temp
+    assert resident + temp < V5E_BYTES_LIMIT - 0.5e9, (resident, temp)
+
+
 # -- the FSDP train step: parameters are gathered, activations are not -------
 
 @pytest.fixture(scope="module")
