@@ -17,7 +17,17 @@ and T = 5 at random offsets, held to a float32 reference that EXPANDS K and
 V from the rows as stored (no absorption). Run it BEFORE a cell, after any
 change to a kernel (``slotted`` or ``latent`` alone runs that half):
 
-    chiprun --chips 1 -- python3 chip_kernel_parity.py [slotted|latent]
+    chiprun --chips 1 -- python3 chip_kernel_parity.py [slotted|latent|gqa [prefill]]
+
+``gqa`` (alone; the default runs the other two) is the cache of two depths
+of the ``k-exaone-236b-a23b.serve-mixed-len`` cell: ``ops.gqa_attention``'s
+two reads over a full layer (``[1, 32, 32768, 1024]`` bf16, 64 query heads on
+8 K/V heads) and over a ring (``[4, 32, 128, 1024]``), rows past a slot's
+count stale and large; the prefill's attention in both forms (blockwise in
+``jax.numpy``, and the Pallas kernel) against the T x T softmax at 4,096
+tokens and timed at 32,768, banded and full; and a share
+of the experts (16 of 128 held) through ``dropless_experts`` against every
+held expert applied to every token under its gate or zero.
 
 One JSON line a case, then ``{"ok": ...}``; exit 1 where a case fails or
 the backend is not a TPU. The times are whole-token times of the
@@ -192,6 +202,205 @@ def latent_cases():
     return ok
 
 
+# -- the cache of two depths: ops.gqa_attention -------------------------------
+G_S, G_HQ, G_HKV, G_D = 32, 64, 8, 128
+#: (name, layers, depth, rows held a slot)
+GQA_CASES = [
+    ("full_mixed", 1, 32768, lambda rng: np.where(
+        rng.random(G_S) < 0.4, np.exp(rng.uniform(
+            np.log(256), np.log(29000), G_S)).astype(np.int64), 0)),
+    ("full_full", 1, 32768, lambda rng: np.full(G_S, 32768)),
+    ("ring_mixed", 4, 128, lambda rng: np.minimum(
+        rng.integers(0, 300, G_S), 128)),
+    ("ring_full", 4, 128, lambda rng: np.full(G_S, 128)),
+]
+
+
+def _gqa_reference(q, k, v, layer, n_rows):
+    """float32 attention over the rows as stored, a slot at a time (a full
+    layer in float32 is 8.6 GB at once)."""
+    hi = jax.lax.Precision.HIGHEST
+    depth = k.shape[2]
+
+    def slot(args):
+        q, k, v, n = args
+        keys = k.astype(jnp.float32).reshape(depth, G_HKV, G_D)
+        values = v.astype(jnp.float32).reshape(depth, G_HKV, G_D)
+        qg = q.astype(jnp.float32).reshape(G_HKV, G_HQ // G_HKV, G_D)
+        scores = jnp.einsum("hgd,rhd->hgr", qg, keys,
+                            precision=hi) * G_D ** -0.5
+        held = jnp.arange(depth) < n
+        probs = jnp.where(held, jax.nn.softmax(
+            jnp.where(held, scores, -jnp.inf), -1), 0.0)
+        return jnp.einsum("hgr,rhd->hgd", probs, values,
+                          precision=hi).reshape(G_HQ, G_D)
+
+    return jax.lax.map(slot, (q, k[layer], v[layer], n_rows))
+
+
+def gqa_cases(only=None):
+    """``only``: ``"prefill"`` skips the reads and the expert share."""
+    from pytorch_distributed_tpu.ops import gqa_attention
+    from pytorch_distributed_tpu.ops.dropless_experts import (
+        dropless_experts, held_share, route_sigmoid_topk)
+
+    ok = True
+    for n, (case, layers, depth, rows_of) in enumerate(
+            GQA_CASES if only is None else []):
+        rng = np.random.default_rng(200 + n)
+        n_rows = jnp.asarray(rows_of(rng), jnp.int32)
+        kq, kk, kv = jax.random.split(jax.random.key(200 + n), 3)
+        q = jax.random.normal(kq, (G_S, G_HQ, G_D), jnp.bfloat16)
+        stale = jnp.where(jnp.arange(depth)[None, :, None]
+                          < n_rows[:, None, None], 1.0, 30.0
+                          ).astype(jnp.bfloat16)
+        k = jax.random.normal(kk, (layers, G_S, depth, G_HKV * G_D),
+                              jnp.bfloat16) * stale
+        v = jax.random.normal(kv, (layers, G_S, depth, G_HKV * G_D),
+                              jnp.bfloat16) * stale
+        layer = layers - 1
+        read = jax.jit(gqa_attention.cached_read,
+                       static_argnames=("kernel",))
+        live = np.asarray(n_rows) > 0
+        ref = np.asarray(jax.jit(_gqa_reference, static_argnums=3)(
+            q, k, v, layer, n_rows))[live]
+        dense = np.asarray(read(q, k, v, layer, n_rows), np.float32)[live]
+        kern_all = np.asarray(read(q, k, v, layer, n_rows, kernel=True),
+                              np.float32)
+        kern = kern_all[live]
+        line = {
+            "op": "gqa", "case": case, "rows_held": int(n_rows.sum()),
+            "kernel_vs_reference": float(np.abs(kern - ref).max()),
+            "dense_vs_reference": float(np.abs(dense - ref).max()),
+            "kernel_within_tolerance": bool(
+                np.allclose(kern, ref, rtol=RTOL, atol=ATOL)),
+            "dense_within_tolerance": bool(
+                np.allclose(dense, ref, rtol=RTOL, atol=ATOL)),
+            "idle_slots_zero": bool(not np.abs(kern_all[~live]).any()),
+            "finite": bool(np.isfinite(kern_all).all()),
+        }
+        for name, kernel in (("dense_read_ms", False),
+                             ("kernel_read_ms", True)):
+            def all_layers(q, k, v, n_rows, kernel=kernel):
+                return sum(gqa_attention.cached_read(
+                    q, k, v, i, n_rows, kernel=kernel).astype(jnp.float32)
+                    for i in range(layers))
+            token = jax.jit(all_layers)
+            jax.block_until_ready(token(q, k, v, n_rows))
+            t0 = time.perf_counter()
+            for _ in range(20):
+                out = token(q, k, v, n_rows)
+            jax.block_until_ready(out)
+            line[name] = (time.perf_counter() - t0) / 20 * 1e3
+        line["kernel_roofline_pct"] = 100 * (
+            layers * int(n_rows.sum()) * 2 * G_HKV * G_D * 2
+            / (line["kernel_read_ms"] * 1e-3) / 819e9)
+        ok &= (line["kernel_within_tolerance"] and line["finite"]
+               and line["idle_slots_zero"])
+        print(json.dumps(line), flush=True)
+        del k, v
+
+    # the prefill's blockwise attention: numbers at 4,096, times at 32,768
+    def plain(q, k, v, window):
+        T, G = q.shape[1], q.shape[2] // k.shape[2]
+        hi = jax.lax.Precision.HIGHEST
+        q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+        k, v = jnp.repeat(k, G, axis=2), jnp.repeat(v, G, axis=2)
+        scores = jnp.einsum("bthd,bshd->bhts", q, k, precision=hi) \
+            * G_D ** -0.5
+        s, p = jnp.arange(T)[None, :], jnp.arange(T)[:, None]
+        seen = (s <= p) & ((s > p - window) if window else True)
+        probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1)
+        return jnp.einsum("bhts,bshd->bthd", probs, v, precision=hi)
+
+    blocks = (gqa_attention._KERNEL_QUERY_BLOCK,
+              gqa_attention._KERNEL_KEY_BLOCK)
+    variants = [(128, False, blocks), (None, False, blocks),
+                (None, True, blocks)]
+    if only == "prefill":       # other blocks, for the choice of the two
+        variants += [(None, True, b) for b in (
+            (256, 512), (128, 1024), (256, 256)) if b != blocks]
+    for window, kernel, (bq, bk) in variants:
+        gqa_attention._KERNEL_QUERY_BLOCK = bq
+        gqa_attention._KERNEL_KEY_BLOCK = bk
+        attend = jax.jit(functools.partial(
+            gqa_attention.prefill_attention, window=window, kernel=kernel))
+        line = {"op": "gqa_prefill", "window": window, "kernel": kernel}
+        if kernel:
+            line["blocks"] = [bq, bk]
+        for T in (4096, 8192, 32768):
+            kq, kk, kv = jax.random.split(jax.random.key(T), 3)
+            q = jax.random.normal(kq, (1, T, G_HQ, G_D), jnp.bfloat16)
+            k = jax.random.normal(kk, (1, T, G_HKV, G_D), jnp.bfloat16)
+            v = jax.random.normal(kv, (1, T, G_HKV, G_D), jnp.bfloat16)
+            out = jax.block_until_ready(attend(q, k, v))
+            if T == 4096:
+                ref = np.asarray(jax.jit(plain, static_argnums=3)(
+                    q, k, v, window))
+                got = np.asarray(out, np.float32)
+                line["vs_reference"] = float(np.abs(got - ref).max())
+                line["within_tolerance"] = bool(
+                    np.allclose(got, ref, rtol=RTOL, atol=ATOL))
+                ok &= line["within_tolerance"]
+            t0 = time.perf_counter()
+            for _ in range(5):
+                out = attend(q, k, v)
+            jax.block_until_ready(out)
+            line[f"T{T}_ms"] = (time.perf_counter() - t0) / 5 * 1e3
+        pairs = (32768 * 128 - 128 * 127 // 2 if window
+                 else 32768 * 32769 // 2)
+        line["T32768_pct_of_bf16_peak"] = 100 * (
+            4.0 * pairs * G_HQ * G_D / (line["T32768_ms"] * 1e-3) / 197e12)
+        print(json.dumps(line), flush=True)
+    gqa_attention._KERNEL_QUERY_BLOCK, gqa_attention._KERNEL_KEY_BLOCK = blocks
+    if only is not None:
+        return ok
+
+    # a share of the experts: rows past every group must add nothing
+    n, d, F, E, held = 512, 6144, 2048, 128, 16
+    ks = jax.random.split(jax.random.key(7), 5)
+    x = jax.random.normal(ks[0], (n, d), jnp.bfloat16)
+    router = jax.random.normal(ks[1], (d, E), jnp.float32) * 0.02
+    w_gate, w_up = (jax.random.normal(k, (held, d, F), jnp.bfloat16) * 0.02
+                    for k in ks[2:4])
+    w_down = jax.random.normal(ks[4], (held, F, d), jnp.bfloat16) * 0.02
+
+    @jax.jit
+    def share(x):
+        experts, gates = route_sigmoid_topk(x, router, jnp.zeros((E,)), 8,
+                                            2.5)
+        y, hit = dropless_experts(x, *held_share(experts, gates, 0, held),
+                                  w_gate, w_up, w_down)
+        dense_gates = jnp.zeros((n, E)).at[
+            jnp.arange(n)[:, None], experts].set(gates)[:, :held]
+        hi = jax.lax.Precision.HIGHEST
+        x32 = x.astype(jnp.float32)
+
+        def one(acc, e):
+            g, u, dn, w = e
+            h = jax.nn.silu(jnp.dot(x32, g.astype(jnp.float32),
+                                    precision=hi)) * jnp.dot(
+                x32, u.astype(jnp.float32), precision=hi)
+            return acc + w[:, None] * jnp.dot(h, dn.astype(jnp.float32),
+                                              precision=hi), None
+        want, _ = jax.lax.scan(one, jnp.zeros((n, d)), (
+            w_gate, w_up, w_down, dense_gates.T))
+        return y, hit, want, (experts < held).sum()
+
+    y, hit, want, pairs = share(x)
+    y, want = np.asarray(y, np.float32), np.asarray(want)
+    line = {"op": "expert_share", "held_pairs": int(pairs),
+            "of_pairs": n * 8, "experts_hit": int(hit),
+            "range": float(want.max() - want.min()),
+            "vs_every_expert_masked": float(np.abs(y - want).max()),
+            "finite": bool(np.isfinite(y).all())}
+    line["within_tolerance"] = bool(
+        line["vs_every_expert_masked"] < 0.02 * line["range"])
+    ok &= line["finite"] and line["within_tolerance"]
+    print(json.dumps(line), flush=True)
+    return ok
+
+
 def main():
     device = jax.devices()[0]
     if device.platform != "tpu":
@@ -199,6 +408,11 @@ def main():
                           "kernel's arithmetic exists only on a TPU"}))
         return 1
     which = sys.argv[1] if len(sys.argv) > 1 else "both"
+    if which == "gqa":
+        ok = gqa_cases(*sys.argv[2:3])
+        print(json.dumps({"ok": ok, "device": {
+            "platform": device.platform, "kind": device.device_kind}}))
+        return 0 if ok else 1
     ok = latent_cases() if which in ("latent", "both") else True
     for n, (case, T, H, L) in enumerate(CASES if which != "latent" else []):
         C = H * D

@@ -16,6 +16,7 @@ from pytorch_distributed_tpu.models.resnet import (
 )
 from pytorch_distributed_tpu.models.gpt2 import GPT2, GPT2Config, gpt2_125m
 from pytorch_distributed_tpu.models.xing4 import Xing4, Xing4Config
+from pytorch_distributed_tpu.models.exaone_moe import ExaoneMoE, ExaoneMoEConfig
 
 __all__ = [
     "ResNet",
@@ -28,4 +29,6 @@ __all__ = [
     "gpt2_125m",
     "Xing4",
     "Xing4Config",
+    "ExaoneMoE",
+    "ExaoneMoEConfig",
 ]

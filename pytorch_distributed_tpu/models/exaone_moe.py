@@ -1,0 +1,300 @@
+"""The ``exaone_moe`` decoder in flax.linen: window and full attention
+layers mixed, fewer K/V heads than query heads, norms on the sublayers'
+outputs, and a SHARE of the routed experts.
+
+The architecture of ``LGAI-EXAONE/K-EXAONE-236B-A23B`` (``config.json``,
+``model_type`` ``exaone_moe``; where it is silent, the family's released
+``exaone4`` code). The equations are written out in
+``chipbench/references/exaone_moe.py``, the plain float32 reference this
+forward is held to. For layer ``l`` with input ``h``::
+
+    q, k, v = h W_q, h W_k, h W_v      H_q heads, H_kv heads, H_kv heads of D
+    q, k <- RMSNorm_D(q), RMSNorm_D(k)                 a learned gain each
+    window layers only: q, k rotated (all D columns, pairs (i, i + D/2))
+    a = causal softmax(q k^T / sqrt(D)) v              head j on K/V head j // G;
+                                                       a window layer sees its
+                                                       last ``sliding_window``
+    h <- h + RMSNorm(a W_o)
+    h <- h + RMSNorm(mlp(h))                           no norm BEFORE a sublayer
+
+``mlp`` is a gated MLP in the ``dense`` layers and, in the others, ``sum_i
+g_i FFN_i(x) + FFN_shared(x)`` over the ``num_experts_per_tok`` experts that
+``ops.dropless_experts.route_sigmoid_topk`` chooses among ALL
+``num_experts``. A model holds ``held_experts = (first, count)`` of them
+(all, by default): the pairs whose expert it holds go through
+``ops.dropless_experts.dropless_experts`` with no token dropped, the others
+add nothing here (``held_share``): they are another chip's part of an
+expert-parallel deployment, whose exchange is not in this file.
+
+The forward contract is ``models.xing4``'s: ``model.apply(variables, tokens,
+deterministic=True, kv_cache=, position_offset=) -> (logits, cache)``, and
+``logits`` alone without a cache; ``model.cfg``; ``model.cache_class`` names
+``serving.window_cache.WindowedKVCache`` (touched through ``cache.attend``
+and ``cache.counted``); a FRESH prefill through a cache returns the logits
+of each sequence's last real position only, ``[B, 1, V]``. Whatever of a
+layer is tokenwise (projections, norms, rotation, the MLPs; all but the
+attention itself) runs ``_TOKEN_CHUNK`` tokens at a time: at 32,768 tokens
+the dense layer's 18,432-wide intermediates would be 3.6 GB, an expert
+layer's eight sorted pairs a token 3.2 GB a copy, and a norm's float32
+copy of the queries 1 GB.
+
+Not in the served model: the multi-token-prediction layer
+(``num_nextn_predict_layers``), which the main model's logits do not depend
+on. Dtypes: weights and compute ``param_dtype`` / ``dtype`` (bfloat16 when
+served); router, norms' statistics, rotary angles, scores and softmax, and
+the sum over a token's experts in float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from pytorch_distributed_tpu.models.xing4 import _rms, _Weights
+from pytorch_distributed_tpu.ops import gqa_attention
+from pytorch_distributed_tpu.ops.dropless_experts import (
+    dropless_experts,
+    held_share,
+    route_sigmoid_topk,
+)
+from pytorch_distributed_tpu.ops.latent_attention import rotate
+
+__all__ = ["ExaoneMoEConfig", "ExaoneMoE"]
+
+#: tokens of a prompt that go through an MLP sublayer at a time
+_TOKEN_CHUNK = 2048
+
+
+@dataclasses.dataclass(frozen=True)
+class ExaoneMoEConfig:
+    """The source's keys under their own names, but for ``n_layer``
+    (``num_hidden_layers``) and ``n_positions`` (``max_position_embeddings``),
+    which the serving engine reads, and ``held_experts`` (module docstring),
+    which is the deployment's and not the source's."""
+
+    vocab_size: int = 153600
+    n_positions: int = 262144
+    n_layer: int = 48
+    hidden_size: int = 6144
+    num_attention_heads: int = 64
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    intermediate_size: int = 18432
+    moe_intermediate_size: int = 2048
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    num_shared_experts: int = 1
+    routed_scaling_factor: float = 2.5
+    sliding_window: int = 128
+    #: ``"sliding_attention"`` or ``"full_attention"`` a layer
+    layer_types: Tuple[str, ...] = ()
+    #: ``"dense"`` or ``"sparse"`` a layer
+    mlp_layer_types: Tuple[str, ...] = ()
+    rope_theta: float = 1000000.0
+    rms_norm_eps: float = 1e-5
+    initializer_range: float = 0.02
+    held_experts: Tuple[int, int] = (0, 128)
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    def __post_init__(self):
+        if not (len(self.layer_types) == len(self.mlp_layer_types)
+                == self.n_layer):
+            raise ValueError(
+                f"layer_types and mlp_layer_types name {self.n_layer} "
+                f"layers each")
+        first, count = self.held_experts
+        if not (0 <= first and count >= 1
+                and first + count <= self.num_experts):
+            raise ValueError(
+                f"held_experts {self.held_experts} are not among "
+                f"{self.num_experts}")
+
+    @property
+    def layer_windowed(self) -> Tuple[bool, ...]:
+        return tuple(t == "sliding_attention" for t in self.layer_types)
+
+
+def _by_chunks(fn, *xs):
+    """``fn(*xs)`` over the tokens (leading axis) of the arrays ``xs``,
+    ``_TOKEN_CHUNK`` at a time where there are more (one program, run in a
+    loop): what ``fn`` gives per token comes back whole, what it gives per
+    call (a scalar) as a vector over the calls."""
+    n = xs[0].shape[0]
+    if n <= _TOKEN_CHUNK or n % _TOKEN_CHUNK:
+        return jax.tree_util.tree_map(
+            lambda a: a if a.ndim else a[None], fn(*xs))
+    out = jax.lax.map(lambda chunk: fn(*chunk), tuple(
+        x.reshape((n // _TOKEN_CHUNK, _TOKEN_CHUNK) + x.shape[1:])
+        for x in xs))
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((n,) + a.shape[2:]) if a.ndim > 1 else a, out)
+
+
+class GatedMLPWeights(_Weights):
+    """The three matrices ``(gate, up, down)`` of a gated MLP ``width``
+    wide, for ``_gated_mlp``: weights apart from the arithmetic, which runs
+    inside ``_by_chunks``'s loop where no module may be born."""
+    width: int = 0
+
+    @nn.compact
+    def __call__(self, d):
+        return (self.w("gate", (d, self.width)), self.w("up", (d, self.width)),
+                self.w("down", (self.width, d)))
+
+
+def _gated_mlp(x, gate, up, down):
+    return (jax.nn.silu(x @ gate) * (x @ up)) @ down
+
+
+class Attention(_Weights):
+    """``h + RMSNorm(a W_o)`` of one layer, ``h [N, d]`` the ``B x T``
+    tokens in a row. Everything but the attention itself is tokenwise and
+    runs in chunks: at 32,768 tokens the queries' float32 copies under the
+    norm and the rotation alone would be 2 GB."""
+    windowed: bool = False
+
+    @nn.compact
+    def __call__(self, h, positions, cache, layer, position_offset, out_gain):
+        cfg = self.cfg
+        B, T = positions.shape
+        d = h.shape[-1]
+        Hq, Hkv, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                      cfg.head_dim)
+        eps = cfg.rms_norm_eps
+        w_q, w_k, w_v = (self.w("q", (d, Hq * D)), self.w("k", (d, Hkv * D)),
+                         self.w("v", (d, Hkv * D)))
+        q_gain, k_gain = self.gain("q_norm", D), self.gain("k_norm", D)
+        w_o = self.w("o", (Hq * D, d))
+        inv_freq = gqa_attention.rope_inv_freq(D, cfg.rope_theta)
+
+        def project(x, at):
+            n = x.shape[0]
+            q = _rms((x @ w_q).reshape(n, Hq, D), q_gain, eps)
+            k = _rms((x @ w_k).reshape(n, Hkv, D), k_gain, eps)
+            if self.windowed:           # a full layer has no positions
+                q = rotate(q[None], at[None], inv_freq)[0]
+                k = rotate(k[None], at[None], inv_freq)[0]
+            return q, k, (x @ w_v).reshape(n, Hkv, D)
+
+        q, k, v = (a.reshape((B, T) + a.shape[1:]) for a in _by_chunks(
+            project, h, positions.reshape(B * T)))
+        with jax.named_scope("attn/window" if self.windowed else "attn/full"):
+            if cache is None:
+                y = gqa_attention.blockwise_attention(
+                    q, k, v,
+                    window=cfg.sliding_window if self.windowed else None)
+            else:
+                y, cache = cache.attend(layer, q, k, v, position_offset)
+        return _by_chunks(lambda y, x: x + _rms(y @ w_o, out_gain, eps),
+                          y.reshape(B * T, Hq * D), h), cache
+
+
+class ExpertShare(_Weights):
+    """``h + RMSNorm(sum g_i FFN_i(h) + FFN_shared(h))`` over the experts
+    this model holds, ``h [N, d]``. Returns ``(h, hit)``: ``hit`` counts the
+    held experts that got a token (of a prompt in chunks, the most in any
+    chunk)."""
+
+    @nn.compact
+    def __call__(self, h, out_gain):
+        cfg = self.cfg
+        d = h.shape[-1]
+        E, F = cfg.num_experts, cfg.moe_intermediate_size
+        first, held = cfg.held_experts
+        router = self.w("router", (d, E), jnp.float32)
+        bias = self.param("router_bias", nn.initializers.zeros, (E,),
+                          jnp.float32)
+        w_gate = self.w("experts_gate", (held, d, F))
+        w_up = self.w("experts_up", (held, d, F))
+        w_down = self.w("experts_down", (held, F, d))
+        shared = GatedMLPWeights(cfg, width=F * cfg.num_shared_experts,
+                                 name="shared")(d)
+
+        def tokens(x):
+            with jax.named_scope("moe/route"):
+                experts, gates = held_share(*route_sigmoid_topk(
+                    x, router, bias, cfg.num_experts_per_tok,
+                    cfg.routed_scaling_factor), first, held)
+            with jax.named_scope("moe/experts"):
+                y, hit = dropless_experts(x, experts, gates, w_gate, w_up,
+                                          w_down)
+            with jax.named_scope("moe/shared"):
+                y = y + _gated_mlp(x, *shared)
+            return x + _rms(y, out_gain, cfg.rms_norm_eps), hit
+
+        h, hit = _by_chunks(tokens, h)
+        return h, hit.max()
+
+
+class ExaoneMoE(nn.Module):
+    """Decoder-only ``exaone_moe``. Input ``tokens [B, T]`` int32 -> logits
+    (see the module docstring for the cache-aware forward)."""
+
+    cfg: ExaoneMoEConfig
+
+    @property
+    def cache_class(self):
+        from pytorch_distributed_tpu.serving.window_cache import (
+            WindowedKVCache,
+        )
+
+        return WindowedKVCache
+
+    @nn.compact
+    def __call__(self, tokens, deterministic: bool = True, *, kv_cache=None,
+                 position_offset=None):
+        cfg = self.cfg
+        B, T = tokens.shape
+        if kv_cache is not None and kv_cache.n_layers != cfg.n_layer:
+            raise ValueError(
+                f"kv_cache has {kv_cache.n_layers} layers, model has "
+                f"{cfg.n_layer}")
+        positions = jnp.arange(T, dtype=jnp.int32)[None]
+        if position_offset is not None:
+            positions = position_offset[:, None] + positions
+        positions = jnp.broadcast_to(positions, (B, T))
+        init = nn.initializers.normal(cfg.initializer_range)
+        d, eps = cfg.hidden_size, cfg.rms_norm_eps
+
+        def gain(name):
+            return self.param(name, nn.initializers.ones, (d,),
+                              cfg.param_dtype)
+
+        with jax.named_scope("embed"):
+            embed = self.param("embed", init, (cfg.vocab_size, d),
+                               cfg.param_dtype)
+            h = embed[tokens.reshape(B * T)].astype(cfg.dtype)
+        hit = jnp.zeros((), jnp.int32)
+        for i in range(cfg.n_layer):
+            h, kv_cache = Attention(
+                cfg, windowed=cfg.layer_windowed[i], name=f"layer_{i}_attn")(
+                    h, positions, kv_cache, i, position_offset,
+                    gain(f"layer_{i}_attn_norm"))
+            out_gain = gain(f"layer_{i}_mlp_norm")
+            if cfg.mlp_layer_types[i] == "dense":
+                mlp = GatedMLPWeights(cfg, width=cfg.intermediate_size,
+                                      name=f"layer_{i}_mlp")(d)
+                with jax.named_scope("mlp"):
+                    h = _by_chunks(lambda x: x + _rms(
+                        _gated_mlp(x, *mlp), out_gain, eps), h)
+            else:
+                h, layer_hit = ExpertShare(cfg, name=f"layer_{i}_moe")(
+                    h, out_gain)
+                hit = hit + layer_hit
+        with jax.named_scope("head"):
+            h = h.reshape(B, T, d)
+            if kv_cache is not None and position_offset is None:
+                # fresh prefill: only the last real position is sampled from
+                last = (kv_cache.lengths - 1) % T
+                h = jnp.take_along_axis(h, last[:, None, None], axis=1)
+            h = _rms(h, gain("norm"), eps)
+            logits = h @ self.param("head", init, (d, cfg.vocab_size),
+                                    cfg.param_dtype).astype(cfg.dtype)
+        if kv_cache is not None:
+            return logits, kv_cache.counted(experts_hit=hit)
+        return logits

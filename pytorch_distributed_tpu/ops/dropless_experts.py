@@ -21,7 +21,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["route_sigmoid_topk", "dropless_experts"]
+__all__ = ["route_sigmoid_topk", "dropless_experts", "held_share"]
 
 
 def route_sigmoid_topk(x, w_router, bias, k: int, scaling: float
@@ -63,3 +63,19 @@ def dropless_experts(x, experts, gates, w_gate, w_up, w_down
     back = out[jnp.argsort(order)].reshape(n, k, -1)
     y = jnp.einsum("nkd,nk->nd", back.astype(jnp.float32), gates)
     return y.astype(x.dtype), (sizes > 0).sum().astype(jnp.int32)
+
+
+def held_share(experts, gates, first: int, count: int
+               ) -> Tuple[jax.Array, jax.Array]:
+    """The part of a routing that a holder of experts ``first .. first +
+    count - 1`` computes: ``(experts, gates)`` with a held expert under its
+    own number among the held (``0 .. count - 1``) and every other pair
+    under ``count`` with gate 0. ``dropless_experts`` over ``count`` experts'
+    matrices sorts such pairs behind every group, counts them in no group
+    (their rows are multiplied by nothing) and adds them under a zero gate:
+    they are another holder's part. A holder of all experts gets back what
+    it gave."""
+    own = experts - first
+    held = (own >= 0) & (own < count)
+    return (jnp.where(held, own, count).astype(jnp.int32),
+            jnp.where(held, gates, 0.0))

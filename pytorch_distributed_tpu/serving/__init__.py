@@ -32,6 +32,7 @@ from pytorch_distributed_tpu.serving.engine import (
     sample_tokens,
 )
 from pytorch_distributed_tpu.serving.kv_cache import KVCache, LatentCache
+from pytorch_distributed_tpu.serving.window_cache import WindowedKVCache
 from pytorch_distributed_tpu.serving.paging import (
     CapacityError,
     PageAllocator,
@@ -65,6 +66,7 @@ from pytorch_distributed_tpu.serving.speculative import (
 __all__ = [
     "KVCache",
     "LatentCache",
+    "WindowedKVCache",
     "PagedKVCache",
     "PageAllocator",
     "RadixTree",

@@ -514,3 +514,96 @@ def test_fsdp_pin_changes_the_four_chip_step(v5e_topology, monkeypatch):
     extra = (with_pin.as_text().count("sharding_constraint")
              - without.as_text().count("sharding_constraint"))
     assert extra >= cfg.n_layer + 3, extra
+
+
+# -- the cache of two depths: K-EXAONE's cell at its real sizes ---------------
+
+def _exaone_engine(v5e_device, monkeypatch):
+    """The engine of ``k-exaone-236b-a23b.serve-mixed-len`` (the
+    configuration file as it is: published widths, bfloat16, 5 layers, 16 of
+    128 experts; the traffic file's slots and positions) over described
+    shapes."""
+    from chipbench import cells
+    from chipbench.families import exaone_moe as family
+    from pytorch_distributed_tpu.ops import decode_attention
+    from pytorch_distributed_tpu.serving import InferenceEngine
+
+    monkeypatch.setattr(decode_attention, "_platform", lambda: "tpu")
+    cell = cells.resolve(cells.load_benchmark(),
+                         "k-exaone-236b-a23b.serve-mixed-len")
+    model = family.build_model(cell.config)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=v5e_device), tree)
+
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    engine = InferenceEngine(model, params, n_slots=cell.traffic["n_slots"],
+                             max_len=cell.traffic["max_len"])
+    cache = described(jax.eval_shape(engine.init_cache))
+    rng = described(jax.eval_shape(lambda: jax.random.key(0)))
+    return engine, described(params), cache, rng
+
+
+def test_windowed_decode_program_reads_what_the_slots_hold_for_v5e(
+        v5e_device, monkeypatch):
+    """3.71 G parameters, the cache of one full layer (4.29 GB) and four
+    rings (67 MB) donated: the step keeps under a hundredth of the cache in
+    temporaries, aliases every K/V leaf and the lengths to an output, and
+    reads with five calls of ONE Mosaic kernel traced once a depth."""
+    from pytorch_distributed_tpu.analysis.ir.hlo import aliased_param_indices
+
+    engine, params, cache, rng = _exaone_engine(v5e_device, monkeypatch)
+    assert _bytes(params) == 7_430_349_312
+    slots = cache.k_full.shape[1]
+    assert cache.k_full.shape == (1, slots, 32768, 1024)
+    assert cache.k_ring.shape == (4, slots, 128, 1024)
+    # a KVCache of five layers would be 21.5 GB at 32 slots
+    assert _bytes(cache) < 1.02 * 2 * slots * 1024 * 2 * (32768 + 4 * 128)
+    compiled = engine._decode.lower(
+        params, cache,
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_device),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e_device), rng,
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < _bytes(cache) / 100
+    text = compiled.as_text()
+    first = len(jax.tree_util.tree_leaves(params))
+    # k_full, v_full, k_ring, v_ring, lengths; the step's counts are new
+    assert aliased_param_indices(text) == list(range(first, first + 5))
+    kernels = re.findall(r"[^\n]*gqa_attention_read/pallas_call[^\n]*", text)
+    assert len([k for k in kernels if "tpu_custom_call" in k]) == 5
+    slab = slots * 32768 * 1024
+    computations, _ = _computations(text)
+    relayouts = [line for body in computations.values()
+                 for _, opcode, elements, line in body
+                 if opcode in ("copy", "transpose") and elements >= slab]
+    assert not relayouts, relayouts[:3]
+
+
+def test_windowed_prefill_bucket_32768_fits_beside_the_resident_state_for_v5e(
+        v5e_device, monkeypatch):
+    """The longest bucket: its temporaries beside the weights and the
+    cache leave a gigabyte of the chip free, the full layer attends by the
+    Mosaic kernel (once) and no array of scores has 32,768 x 32,768
+    elements."""
+    engine, params, cache, rng = _exaone_engine(v5e_device, monkeypatch)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e_device)
+    compiled = engine._prefill.lower(
+        params, cache,
+        jax.ShapeDtypeStruct((1, 32768), jnp.int32, sharding=v5e_device),
+        i32, i32, rng).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    resident = _bytes(params) + _bytes(cache)
+    assert resident > 10e9
+    assert resident + temp < V5E_BYTES_LIMIT - 1.0e9, (resident, temp)
+    text = compiled.as_text()
+    kernels = re.findall(r"[^\n]*gqa_attention_prefill/pallas_call[^\n]*",
+                         text)
+    assert len([k for k in kernels if "tpu_custom_call" in k]) == 1
+    computations, _ = _computations(text)
+    # (the cache's full layer has that many elements itself, in bfloat16)
+    assert not [line for body in computations.values()
+                for _, _, elements, line in body
+                if elements >= 32768 * 32768 and "= f32[" in line]
