@@ -17,7 +17,7 @@ and T = 5 at random offsets, held to a float32 reference that EXPANDS K and
 V from the rows as stored (no absorption). Run it BEFORE a cell, after any
 change to a kernel (``slotted`` or ``latent`` alone runs that half):
 
-    chiprun --chips 1 -- python3 chip_kernel_parity.py [slotted|latent|gqa [prefill]]
+    chiprun --chips 1 -- python3 chip_kernel_parity.py [slotted|latent|gqa [prefill|share]]
 
 ``gqa`` (alone; the default runs the other two) is the cache of two depths
 of the ``k-exaone-236b-a23b.serve-mixed-len`` cell: ``ops.gqa_attention``'s
@@ -26,8 +26,9 @@ two reads over a full layer (``[1, 32, 32768, 1024]`` bf16, 64 query heads on
 count stale and large; the prefill's attention in both forms (blockwise in
 ``jax.numpy``, and the Pallas kernel) against the T x T softmax at 4,096
 tokens and timed at 32,768, banded and full; and a share
-of the experts (16 of 128 held) through ``dropless_experts`` against every
-held expert applied to every token under its gate or zero.
+of the experts (16 of 128 held) through ``dropless_experts``, over the
+buffer of held pairs and over every pair sorted at once, against every held
+expert applied to every token under its gate or zero (``share_cases``).
 
 One JSON line a case, then ``{"ok": ...}``; exit 1 where a case fails or
 the backend is not a TPU. The times are whole-token times of the
@@ -239,11 +240,12 @@ def _gqa_reference(q, k, v, layer, n_rows):
 
 
 def gqa_cases(only=None):
-    """``only``: ``"prefill"`` skips the reads and the expert share."""
+    """``only``: ``"prefill"`` skips the reads and the expert share,
+    ``"share"`` everything else."""
     from pytorch_distributed_tpu.ops import gqa_attention
-    from pytorch_distributed_tpu.ops.dropless_experts import (
-        dropless_experts, held_share, route_sigmoid_topk)
 
+    if only == "share":
+        return share_cases()
     ok = True
     for n, (case, layers, depth, rows_of) in enumerate(
             GQA_CASES if only is None else []):
@@ -355,22 +357,49 @@ def gqa_cases(only=None):
     gqa_attention._KERNEL_QUERY_BLOCK, gqa_attention._KERNEL_KEY_BLOCK = blocks
     if only is not None:
         return ok
+    return ok & share_cases()
 
-    # a share of the experts: rows past every group must add nothing
-    n, d, F, E, held = 512, 6144, 2048, 128, 16
+
+def _timed(fn, *args, calls=10):
+    out = jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / calls * 1e3
+
+
+def share_cases():
+    """A holder's share of the experts at the cell's shapes (6,144 wide, 16
+    of 128 held, 8 a token): ``dropless_experts`` over the buffer of held
+    pairs (``num_experts=128``) and over all ``n * 8`` pairs sorted at once
+    against every held expert applied to every token under its gate or
+    zero in float32, for a prefill's chunks of 2,048, 4,096 and 8,192 tokens, a
+    decode step's 32, and a routing that sends EVERY pair to this holder
+    (four buffers full); then three ways a pass's rows can go back to
+    their tokens, alone: each token's ``k`` rows gathered and summed (the
+    op's), a segment sum over the rows sorted back by token, and a gather a
+    token slot in a loop."""
+    from pytorch_distributed_tpu.ops.dropless_experts import (
+        dropless_experts, held_share, route_sigmoid_topk, share_passes,
+        share_rows)
+
+    ok = True
+    d, F, E, held, k = 6144, 2048, 128, 16, 8
     ks = jax.random.split(jax.random.key(7), 5)
-    x = jax.random.normal(ks[0], (n, d), jnp.bfloat16)
     router = jax.random.normal(ks[1], (d, E), jnp.float32) * 0.02
-    w_gate, w_up = (jax.random.normal(k, (held, d, F), jnp.bfloat16) * 0.02
-                    for k in ks[2:4])
+    w_gate, w_up = (jax.random.normal(q, (held, d, F), jnp.bfloat16) * 0.02
+                    for q in ks[2:4])
     w_down = jax.random.normal(ks[4], (held, F, d), jnp.bfloat16) * 0.02
+    weights = (w_gate, w_up, w_down)
 
     @jax.jit
-    def share(x):
-        experts, gates = route_sigmoid_topk(x, router, jnp.zeros((E,)), 8,
-                                            2.5)
-        y, hit = dropless_experts(x, *held_share(experts, gates, 0, held),
-                                  w_gate, w_up, w_down)
+    def routed(x, bias):
+        return route_sigmoid_topk(x, router, bias, k, 2.5)
+
+    @jax.jit
+    def every_expert(x, experts, gates, w_gate, w_up, w_down):
+        n = x.shape[0]
         dense_gates = jnp.zeros((n, E)).at[
             jnp.arange(n)[:, None], experts].set(gates)[:, :held]
         hi = jax.lax.Precision.HIGHEST
@@ -383,21 +412,101 @@ def gqa_cases(only=None):
                 x32, u.astype(jnp.float32), precision=hi)
             return acc + w[:, None] * jnp.dot(h, dn.astype(jnp.float32),
                                               precision=hi), None
-        want, _ = jax.lax.scan(one, jnp.zeros((n, d)), (
-            w_gate, w_up, w_down, dense_gates.T))
-        return y, hit, want, (experts < held).sum()
+        return jax.lax.scan(one, jnp.zeros((n, d)), (
+            w_gate, w_up, w_down, dense_gates.T))[0]
 
-    y, hit, want, pairs = share(x)
-    y, want = np.asarray(y, np.float32), np.asarray(want)
-    line = {"op": "expert_share", "held_pairs": int(pairs),
-            "of_pairs": n * 8, "experts_hit": int(hit),
-            "range": float(want.max() - want.min()),
-            "vs_every_expert_masked": float(np.abs(y - want).max()),
-            "finite": bool(np.isfinite(y).all())}
-    line["within_tolerance"] = bool(
-        line["vs_every_expert_masked"] < 0.02 * line["range"])
-    ok &= line["finite"] and line["within_tolerance"]
-    print(json.dumps(line), flush=True)
+    compacted = jax.jit(functools.partial(dropless_experts, num_experts=E))
+    whole_sort = jax.jit(dropless_experts)
+    for case, n, favoured in (("decode_step", 32, 0), ("chunk", 2048, 0),
+                              ("chunk", 4096, 0), ("chunk", 8192, 0),
+                              ("every_pair_held", 2048, held)):
+        x = jax.random.normal(jax.random.key(n), (n, d), jnp.bfloat16)
+        experts, gates = routed(x, jnp.zeros((E,)).at[:favoured].set(10.0))
+        own = held_share(experts, gates, 0, held)
+        pairs, passes = share_passes(own[0], held, E)
+        want = np.asarray(every_expert(x, experts, gates, *weights))
+        line = {"op": "expert_share", "case": case, "tokens": n,
+                "held_pairs": int(pairs), "of_pairs": n * k,
+                "buffer_rows": share_rows(n * k, held, E),
+                "passes": int(passes),
+                "range": float(want.max() - want.min())}
+        forms = [("compacted", compacted)]
+        if n <= 2048:       # 8,192 tokens' pairs are 3.2 GB a copy unsorted
+            forms.append(("whole_sort", whole_sort))
+        for name, form in forms:
+            y, hit = form(x, *own, *weights)
+            y = np.asarray(y, np.float32)
+            line[f"{name}_vs_every_expert_masked"] = float(
+                np.abs(y - want).max())
+            line[f"{name}_finite"] = bool(np.isfinite(y).all())
+            line[f"{name}_experts_hit"] = int(hit)
+            line[f"{name}_ms"] = _timed(form, x, *own, *weights)
+        # the program's path decides; the whole sort (PR 40's path, which
+        # counts on zeros in the rows past ragged_dot's groups) is recorded
+        line["within_tolerance"] = line["compacted_finite"] and (
+            line["compacted_vs_every_expert_masked"] < 0.02 * line["range"])
+        ok &= line["within_tolerance"]
+        print(json.dumps(line), flush=True)
+
+    # a pass's rows back to their tokens: out [rows, d] sorted by expert,
+    # under the gates, into [n, d] float32
+    for n in (2048, 4096, 8192):
+        x = jax.random.normal(jax.random.key(n), (n, d), jnp.bfloat16)
+        experts, gates = held_share(*routed(x, jnp.zeros((E,))), 0, held)
+        rows = share_rows(n * k, held, E)
+        order = jnp.argsort(experts.reshape(-1), stable=True)[:rows]
+        live = jnp.arange(rows) < share_passes(experts, held, E)[0]
+        out = jax.random.normal(ks[0], (rows, d), jnp.bfloat16)
+
+        def row_of_pair(order, live):       # ``rows``: the pair has none
+            return jnp.full((n * k,), rows, jnp.int32).at[
+                jnp.where(live, order, n * k)].set(
+                    jnp.arange(rows, dtype=jnp.int32), mode="drop")
+
+        @jax.jit
+        def k_rows_a_token(out, order, live, gates):    # the op's own
+            at = row_of_pair(order, live)
+            back = jnp.take(out, at, axis=0, mode="fill", fill_value=0)
+            return jnp.einsum("nkd,nk->nd", back.reshape(n, k, d).astype(
+                jnp.float32), gates)
+
+        @jax.jit
+        def sorted_segment_sum(out, order, live, gates):
+            pair = jnp.where(live, order, n * k)
+            back = jnp.argsort(pair)            # pair order is token order
+            pair = pair[back]
+            weighted = jnp.where(
+                (pair < n * k)[:, None], out[back].astype(jnp.float32)
+                * gates.reshape(-1)[jnp.minimum(pair, n * k - 1)][:, None],
+                0.0)
+            return jax.ops.segment_sum(weighted, pair // k, num_segments=n,
+                                       indices_are_sorted=True)
+
+        @jax.jit
+        def gather_per_slot(out, order, live, gates):
+            at = row_of_pair(order, live)
+            at, by_slot = jax.lax.sort((at.reshape(n, k), gates),
+                                       dimension=-1, num_keys=1)
+            padded = jnp.concatenate([out, jnp.zeros((1, d), out.dtype)])
+
+            def slot(s, y):
+                row = jax.lax.dynamic_index_in_dim(at, s, 1, keepdims=False)
+                g = jax.lax.dynamic_index_in_dim(by_slot, s, 1,
+                                                 keepdims=False)
+                return y + padded[row].astype(jnp.float32) * jnp.where(
+                    row < rows, g, 0.0)[:, None]
+            return jax.lax.fori_loop(0, (at < rows).sum(-1).max(), slot,
+                                     jnp.zeros((n, d), jnp.float32))
+
+        want = np.asarray(k_rows_a_token(out, order, live, gates))
+        line = {"op": "expert_share_combine", "tokens": n, "rows": rows}
+        for form in (k_rows_a_token, sorted_segment_sum, gather_per_slot):
+            name = form.__name__
+            line[f"{name}_vs_k_rows_a_token"] = float(np.abs(
+                np.asarray(form(out, order, live, gates)) - want).max())
+            line[f"{name}_ms"] = _timed(form, out, order, live, gates)
+            ok &= line[f"{name}_vs_k_rows_a_token"] < 1e-3
+        print(json.dumps(line), flush=True)
     return ok
 
 

@@ -23,8 +23,9 @@ g_i FFN_i(x) + FFN_shared(x)`` over the ``num_experts_per_tok`` experts that
 ``num_experts``. A model holds ``held_experts = (first, count)`` of them
 (all, by default): the pairs whose expert it holds go through
 ``ops.dropless_experts.dropless_experts`` with no token dropped, the others
-add nothing here (``held_share``): they are another chip's part of an
-expert-parallel deployment, whose exchange is not in this file.
+add nothing here and are neither gathered nor multiplied (``held_share``):
+they are another chip's part of an expert-parallel deployment, whose
+exchange is not in this file.
 
 The forward contract is ``models.xing4``'s: ``model.apply(variables, tokens,
 deterministic=True, kv_cache=, position_offset=) -> (logits, cache)``, and
@@ -34,9 +35,11 @@ and ``cache.counted``); a FRESH prefill through a cache returns the logits
 of each sequence's last real position only, ``[B, 1, V]``. Whatever of a
 layer is tokenwise (projections, norms, rotation, the MLPs; all but the
 attention itself) runs ``_TOKEN_CHUNK`` tokens at a time: at 32,768 tokens
-the dense layer's 18,432-wide intermediates would be 3.6 GB, an expert
-layer's eight sorted pairs a token 3.2 GB a copy, and a norm's float32
-copy of the queries 1 GB.
+the dense layer's 18,432-wide intermediates would be 3.6 GB and a norm's
+float32 copy of the queries 1 GB. An expert sublayer's arrays are the rows
+of the pairs it HOLDS (``ops.dropless_experts.share_rows``: twice the
+expected share, a quarter of the tokens' eight pairs for 16 of 128), so it
+takes chunks of its own, up to ``_EXPERT_CHUNK`` tokens (``ExpertShare``).
 
 Not in the served model: the multi-token-prediction layer
 (``num_nextn_predict_layers``), which the main model's logits do not depend
@@ -60,13 +63,17 @@ from pytorch_distributed_tpu.ops.dropless_experts import (
     dropless_experts,
     held_share,
     route_sigmoid_topk,
+    share_passes,
+    share_rows,
 )
 from pytorch_distributed_tpu.ops.latent_attention import rotate
 
 __all__ = ["ExaoneMoEConfig", "ExaoneMoE"]
 
-#: tokens of a prompt that go through an MLP sublayer at a time
+#: tokens of a prompt that go through a tokenwise sublayer at a time
 _TOKEN_CHUNK = 2048
+#: the most that go through an expert sublayer at a time (``ExpertShare``)
+_EXPERT_CHUNK = 4096
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,18 +126,17 @@ class ExaoneMoEConfig:
         return tuple(t == "sliding_attention" for t in self.layer_types)
 
 
-def _by_chunks(fn, *xs):
+def _by_chunks(fn, *xs, chunk=_TOKEN_CHUNK):
     """``fn(*xs)`` over the tokens (leading axis) of the arrays ``xs``,
-    ``_TOKEN_CHUNK`` at a time where there are more (one program, run in a
-    loop): what ``fn`` gives per token comes back whole, what it gives per
-    call (a scalar) as a vector over the calls."""
+    ``chunk`` at a time where there are more (one program, run in a loop):
+    what ``fn`` gives per token comes back whole, what it gives per call (a
+    scalar) as a vector over the calls."""
     n = xs[0].shape[0]
-    if n <= _TOKEN_CHUNK or n % _TOKEN_CHUNK:
+    if n <= chunk or n % chunk:
         return jax.tree_util.tree_map(
             lambda a: a if a.ndim else a[None], fn(*xs))
-    out = jax.lax.map(lambda chunk: fn(*chunk), tuple(
-        x.reshape((n // _TOKEN_CHUNK, _TOKEN_CHUNK) + x.shape[1:])
-        for x in xs))
+    out = jax.lax.map(lambda part: fn(*part), tuple(
+        x.reshape((n // chunk, chunk) + x.shape[1:]) for x in xs))
     return jax.tree_util.tree_map(
         lambda a: a.reshape((n,) + a.shape[2:]) if a.ndim > 1 else a, out)
 
@@ -196,9 +202,22 @@ class Attention(_Weights):
 
 class ExpertShare(_Weights):
     """``h + RMSNorm(sum g_i FFN_i(h) + FFN_shared(h))`` over the experts
-    this model holds, ``h [N, d]``. Returns ``(h, hit)``: ``hit`` counts the
-    held experts that got a token (of a prompt in chunks, the most in any
-    chunk)."""
+    this model holds, ``h [N, d]``. Returns ``(h, (hit, fill, spill))``,
+    which ``serving.window_cache.WindowedKVCache.STEP_STATS`` names
+    ``experts_hit``, ``experts_fill_pct``, ``experts_spill``: the held
+    experts that got a token and the pairs routed to them against the rows
+    of a pass, in percent (of a prompt in chunks, the most in any chunk),
+    and the passes ``dropless_experts`` made beyond a chunk's first (a
+    buffer over 100 percent full).
+
+    A chunk is ``_EXPERT_CHUNK`` tokens where the rows of a pass
+    (``share_rows``) then stay within what ``_TOKEN_CHUNK`` tokens' pairs
+    are, and ``_TOKEN_CHUNK`` otherwise: 4,096 for 16 of 128 (8,192 rows a
+    pass), 2,048 for a holder of every expert, so a held expert's three
+    matrices are read once for about 256 rows and not twice for 128. (At 8,192 tokens the gather
+    of every token's 8 rows is 805 MB: the share is slower a token on the
+    v5e and the 32,768 bucket's temporaries are 4.6 GB where they may be
+    3.9: PERF.md, PR 41.)"""
 
     @nn.compact
     def __call__(self, h, out_gain):
@@ -215,20 +234,28 @@ class ExpertShare(_Weights):
         shared = GatedMLPWeights(cfg, width=F * cfg.num_shared_experts,
                                  name="shared")(d)
 
+        k = cfg.num_experts_per_tok
+        chunk = (_EXPERT_CHUNK
+                 if share_rows(_EXPERT_CHUNK * k, held, E) <= _TOKEN_CHUNK * k
+                 else _TOKEN_CHUNK)
+
         def tokens(x):
             with jax.named_scope("moe/route"):
                 experts, gates = held_share(*route_sigmoid_topk(
-                    x, router, bias, cfg.num_experts_per_tok,
-                    cfg.routed_scaling_factor), first, held)
+                    x, router, bias, k, cfg.routed_scaling_factor),
+                    first, held)
+                pairs, passes = share_passes(experts, held, E)
             with jax.named_scope("moe/experts"):
                 y, hit = dropless_experts(x, experts, gates, w_gate, w_up,
-                                          w_down)
+                                          w_down, num_experts=E)
             with jax.named_scope("moe/shared"):
                 y = y + _gated_mlp(x, *shared)
-            return x + _rms(y, out_gain, cfg.rms_norm_eps), hit
+            fill = 100 * pairs // share_rows(experts.size, held, E)
+            return (x + _rms(y, out_gain, cfg.rms_norm_eps), hit, fill,
+                    jnp.maximum(passes - 1, 0))
 
-        h, hit = _by_chunks(tokens, h)
-        return h, hit.max()
+        h, hit, fill, spill = _by_chunks(tokens, h, chunk=chunk)
+        return h, (hit.max(), fill.max(), spill.sum())
 
 
 class ExaoneMoE(nn.Module):
@@ -269,7 +296,7 @@ class ExaoneMoE(nn.Module):
             embed = self.param("embed", init, (cfg.vocab_size, d),
                                cfg.param_dtype)
             h = embed[tokens.reshape(B * T)].astype(cfg.dtype)
-        hit = jnp.zeros((), jnp.int32)
+        hit = fill = spill = jnp.zeros((), jnp.int32)
         for i in range(cfg.n_layer):
             h, kv_cache = Attention(
                 cfg, windowed=cfg.layer_windowed[i], name=f"layer_{i}_attn")(
@@ -283,9 +310,11 @@ class ExaoneMoE(nn.Module):
                     h = _by_chunks(lambda x: x + _rms(
                         _gated_mlp(x, *mlp), out_gain, eps), h)
             else:
-                h, layer_hit = ExpertShare(cfg, name=f"layer_{i}_moe")(
+                h, layer = ExpertShare(cfg, name=f"layer_{i}_moe")(
                     h, out_gain)
-                hit = hit + layer_hit
+                hit, fill, spill = (hit + layer[0],
+                                    jnp.maximum(fill, layer[1]),
+                                    spill + layer[2])
         with jax.named_scope("head"):
             h = h.reshape(B, T, d)
             if kv_cache is not None and position_offset is None:
@@ -296,5 +325,6 @@ class ExaoneMoE(nn.Module):
             logits = h @ self.param("head", init, (d, cfg.vocab_size),
                                     cfg.param_dtype).astype(cfg.dtype)
         if kv_cache is not None:
-            return logits, kv_cache.counted(experts_hit=hit)
+            return logits, kv_cache.counted(
+                experts_hit=hit, experts_fill_pct=fill, experts_spill=spill)
         return logits

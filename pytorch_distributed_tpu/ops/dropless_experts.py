@@ -12,16 +12,37 @@ rows of ``[3584, 1024]``, not E times that; compile result, PERF.md PR 33),
 and the results go back to their tokens by the inverse permutation and are
 summed under their gates in float32. One expert taking every token is one
 group of ``n * k`` rows and 63 empty ones.
+
+A HOLDER of ``count`` of ``num_experts`` experts (``held_share``; an
+expert-parallel deployment's exchange hands a chip its own pairs and no
+others) does all of that over its own pairs only. The one sort puts them
+first; the row gather, the three grouped products and their casts then take
+``share_rows`` of them at a time: a buffer of a STATIC size, twice the share
+the holder expects (8,192 rows of the 32,768 pairs that 4,096 tokens make
+for 16 of 128), from which each token's ``k`` rows are gathered (a pair that
+is another holder's gathers zeros) and summed under the gates in float32,
+as above. A routing that crowds more pairs onto the holder than one buffer
+takes goes round again over the same buffer (``share_passes``: the first
+pass in line, the others in a loop whose trip count is the held pairs over
+the rows less one, none in practice), so no pair is dropped and nothing is
+approximated: in one pass the result is the whole sort's to the bit, in
+several its float32 sum over a token's experts in another order. The buffer follows from the operands' shapes alone; a holder
+of every expert takes all ``n * k`` pairs in one pass, which is the first
+paragraph to the letter.
 """
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["route_sigmoid_topk", "dropless_experts", "held_share"]
+__all__ = ["route_sigmoid_topk", "dropless_experts", "held_share",
+           "share_rows", "share_passes"]
+
+#: rows of a tile of the grouped product: a buffer of pairs is whole tiles
+_ROW_TILE = 128
 
 
 def route_sigmoid_topk(x, w_router, bias, k: int, scaling: float
@@ -40,28 +61,89 @@ def route_sigmoid_topk(x, w_router, bias, k: int, scaling: float
     return experts.astype(jnp.int32), gates
 
 
-def dropless_experts(x, experts, gates, w_gate, w_up, w_down
+def share_rows(pairs: int, count: int, num_experts: int) -> int:
+    """Rows of the buffer that a holder of ``count`` of ``num_experts``
+    experts sorts, gathers and multiplies at a time, of ``pairs`` (token,
+    expert) pairs routed among all ``num_experts``: twice the share it
+    expects, in whole row tiles of the grouped product, never more than
+    the pairs there are. A holder of every expert takes them all."""
+    if count >= num_experts:
+        return pairs
+    rows = -(-2 * pairs * count // num_experts)
+    return min(-(-rows // _ROW_TILE) * _ROW_TILE, pairs)
+
+
+def share_passes(experts, count: int, num_experts: int
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """``(held, passes)`` of a routing as ``held_share`` gives it: the
+    pairs whose expert is one of the holder's ``count``, and how many
+    buffers of ``share_rows`` they fill (one, but for a chunk whose tokens
+    crowd onto this holder: ``dropless_experts`` then goes round again)."""
+    held = (experts < count).sum().astype(jnp.int32)
+    return held, -(-held // share_rows(experts.size, count, num_experts))
+
+
+def dropless_experts(x, experts, gates, w_gate, w_up, w_down,
+                     num_experts: Optional[int] = None
                      ) -> Tuple[jax.Array, jax.Array]:
     """``sum_i gates[:, i] * FFN_{experts[:, i]}(x)`` with ``FFN(x) =
     (silu(x W_gate) * x W_up) W_down``. ``x [n, d]``; ``experts, gates
     [n, k]``; ``w_gate, w_up [E, d, F]``, ``w_down [E, F, d]``. Returns
     ``(y [n, d] in x's dtype, hit)``, ``hit`` the number of experts that
-    got at least one token."""
+    got at least one token.
+
+    ``num_experts`` is how many the router chose among, where the ``E``
+    matrices are a holder's share of them and ``experts`` is what
+    ``held_share`` made of the routing (module docstring: the held pairs
+    alone are gathered and multiplied, ``share_rows`` of them a pass).
+    Without it such a routing's pairs are all sorted and multiplied at
+    once, and what the other holders' pairs add is zero only where
+    ``ragged_dot`` leaves the rows past its groups zero (it does on the
+    CPU; on the TPU they once read NaN: PERF.md, PR 41)."""
     n, k = experts.shape
     n_experts = w_gate.shape[0]
+    cap = share_rows(n * k, n_experts, num_experts or n_experts)
     flat = experts.reshape(-1)
     order = jnp.argsort(flat, stable=True)
     sizes = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
-    rows = x[order // k]                                   # [n * k, d]
 
-    def grouped(a, w):
-        return jax.lax.ragged_dot(
-            a, w, sizes, preferred_element_type=jnp.float32).astype(x.dtype)
+    def ffn(rows, sizes):
+        def grouped(a, w):
+            return jax.lax.ragged_dot(
+                a, w, sizes, preferred_element_type=jnp.float32
+            ).astype(x.dtype)
 
-    hidden = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
-    out = grouped(hidden, w_down)
-    back = out[jnp.argsort(order)].reshape(n, k, -1)
-    y = jnp.einsum("nkd,nk->nd", back.astype(jnp.float32), gates)
+        return grouped(
+            jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up), w_down)
+
+    def under_gates(back):                  # a token's k rows, summed
+        return jnp.einsum("nkd,nk->nd",
+                          back.reshape(n, k, -1).astype(jnp.float32), gates)
+
+    if cap == n * k:
+        # every pair has a row: un-sort them and sum each token's k
+        out = ffn(x[order // k], sizes)                    # [n * k, d]
+        y = under_gates(out[jnp.argsort(order)])
+    else:
+        held, ends = sizes.sum(), jnp.cumsum(sizes)
+        at = jnp.argsort(order)         # where a pair lies among the sorted
+        at = jnp.where(at < held, at, -1)
+        order = jnp.pad(order, (0, -(n * k) % cap))
+
+        def one_pass(i, y):
+            first = i * cap
+            upto = jnp.clip(ends - first, 0, cap)
+            pairs = jax.lax.dynamic_slice(order, (first,), (cap,))
+            out = ffn(x[pairs // k], jnp.diff(upto, prepend=0))  # [cap, d]
+            # a pair of another pass or another holder takes no row: zeros
+            row = jnp.where((at >= first) & (at < first + cap), at - first,
+                            cap)
+            return y + under_gates(
+                jnp.take(out, row, axis=0, mode="fill", fill_value=0))
+
+        y = jax.lax.fori_loop(
+            1, -(-held // cap), one_pass,
+            one_pass(0, jnp.zeros((n, x.shape[-1]), jnp.float32)))
     return y.astype(x.dtype), (sizes > 0).sum().astype(jnp.int32)
 
 

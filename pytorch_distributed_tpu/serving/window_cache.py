@@ -60,7 +60,8 @@ class WindowedKVCache(struct.PyTreeNode):
     the host in the read of the step's tokens. ``windowed[l]`` says which
     kind layer ``l`` is (static: part of the tree's structure)."""
 
-    STEP_STATS = ("experts_hit", "kv_full_rows", "kv_ring_rows")
+    STEP_STATS = ("experts_hit", "experts_fill_pct", "experts_spill",
+                  "kv_full_rows", "kv_ring_rows")
     UNSUPPORTED_BECAUSE = (
         "a ring cannot give back rows a rollback would need, a paged pool "
         "whose window layers free pages behind the window and a "
@@ -168,9 +169,10 @@ class WindowedKVCache(struct.PyTreeNode):
 
     def counted(self, **stats) -> "WindowedKVCache":
         """The cache with the step's counts set: the model's own
-        (``experts_hit``) and the rows a decode step's reads held, summed
-        over the layers of each kind: ``lengths + 1`` a live slot a full
-        layer, at most ``window`` of them a ring."""
+        (``experts_hit``, ``experts_fill_pct``, ``experts_spill``:
+        ``models.exaone_moe.ExpertShare``) and the rows a decode step's
+        reads held, summed over the layers of each kind: ``lengths + 1`` a
+        live slot a full layer, at most ``window`` of them a ring."""
         live = self.lengths > 0
         rows = jnp.where(live, self.lengths + 1, 0)
         n_win = sum(self.windowed)

@@ -31,6 +31,8 @@ from pytorch_distributed_tpu.ops.dropless_experts import (
     dropless_experts,
     held_share,
     route_sigmoid_topk,
+    share_passes,
+    share_rows,
 )
 from pytorch_distributed_tpu.serving import (
     InferenceEngine,
@@ -133,7 +135,7 @@ def test_prefill_then_decode_through_the_cache_is_the_reference(
             variables, jnp.asarray(tokens[None, t:t + 1]), kv_cache=cache,
             position_offset=cache.lengths)
         # one full layer holds t + 1 rows, four rings at most a window each
-        assert cache.step_stats.tolist()[1:] == [
+        assert cache.step_stats.tolist()[-2:] == [
             t + 1, 4 * min(t + 1, WINDOW)]
         cache = cache.advance(1)
         assert float(jnp.abs(logits[0, 0] - ref[t]).max()) < TOL, t
@@ -394,6 +396,66 @@ def test_a_holder_of_every_expert_gets_back_what_it_gave():
     assert not float(jnp.abs(y).max()) and int(hit) == 0
 
 
+@pytest.mark.parametrize("n,E,favoured,cap,passes", [
+    (96, 128, None, 256, 1),            # an eighth of 768 pairs, more or less
+    (96, 128, (0, 16), 256, 3),         # every pair is this holder's
+    (96, 128, (16, 32), 256, 0),        # none is
+    (96, 16, None, 768, 1),             # a holder of every expert
+    (32, 128, None, 128, 1),            # a decode step of 32 slots
+], ids=["typical", "every_pair_held", "none_held", "all_experts_held",
+        "decode_step"])
+def test_the_compacted_share_is_the_whole_sort_share(n, E, favoured, cap,
+                                                     passes):
+    """A holder of 16 of ``E`` experts sorts, gathers and multiplies a
+    buffer of ``share_rows`` pairs at a time, and goes round again for a
+    routing that overfills it: the sum is that of all ``n * k`` pairs sorted
+    at once (no ``num_experts``), and ``hit`` is the same."""
+    p, x = _expert_layer(seed=3, n=n, E=E)
+    bias = jnp.zeros((E,))
+    if favoured:
+        bias = bias.at[slice(*favoured)].set(10.0)
+    experts, gates = held_share(*route_sigmoid_topk(
+        x, p["router"], bias, 8, 2.5), 0, 16)
+    weights = [p[name][:16] for name in ("experts_gate", "experts_up",
+                                         "experts_down")]
+    assert share_rows(n * 8, 16, E) == cap
+    held, made = share_passes(experts, 16, E)
+    assert int(made) == passes and int(held) == int((experts < 16).sum())
+    if favoured:
+        assert int(held) == (n * 8 if favoured[0] == 0 else 0)
+    want, want_hit = dropless_experts(x, experts, gates, *weights)
+    got, hit = jax.jit(dropless_experts, static_argnames="num_experts")(
+        x, experts, gates, *weights, num_experts=E)
+    assert float(jnp.abs(got - want).max()) < TOL
+    assert int(hit) == int(want_hit) == len(np.unique(experts[experts < 16]))
+    assert float(jnp.abs(want).max()) > 0.1 or passes == 0
+
+
+def test_a_prompt_in_chunks_is_the_reference(served, monkeypatch):
+    """At sizes where the loops over chunks and the buffer of held pairs
+    are real (8 tokens a tokenwise chunk, 16 an expert sublayer's, whose
+    buffer is 32 rows, half of their 64 pairs): the logits
+    are the reference's, and a prefill leaves its counts in the cache."""
+    from pytorch_distributed_tpu.models import exaone_moe as module
+    from pytorch_distributed_tpu.ops import dropless_experts as op
+
+    monkeypatch.setattr(module, "_TOKEN_CHUNK", 8)
+    monkeypatch.setattr(module, "_EXPERT_CHUNK", 16)
+    monkeypatch.setattr(op, "_ROW_TILE", 8)
+    assert share_rows(16 * 4, 4, 16) == 32
+    model, variables = served
+    tokens = _tokens(5, 48)
+    logits = model.apply(variables, tokens[None])[0]
+    assert float(jnp.abs(logits - _reference(variables, tokens)).max()) < TOL
+    cache = WindowedKVCache.create(model.cfg, n_slots=1, max_len=64)
+    _, cache = _prefilled(model, variables, cache, 0, tokens, 48)
+    stats = dict(zip(cache.STEP_STATS, cache.step_stats.tolist()))
+    # four expert layers of three chunks; a chunk's 64 pairs fill two buffers
+    assert 0 < stats["experts_fill_pct"] <= 200
+    assert 0 <= stats["experts_spill"] <= 4 * 3
+    assert (stats["experts_spill"] > 0) == (stats["experts_fill_pct"] > 100)
+
+
 # -- the engine and the scheduler ---------------------------------------------
 
 def test_a_mixed_length_trace_through_the_scheduler_is_the_references(served):
@@ -436,8 +498,9 @@ def test_engine_refuses_what_a_ring_cannot_do(served, kwargs, named):
 
 
 def test_decode_span_carries_the_steps_counts(served, monkeypatch):
-    """``experts_hit`` and the rows the step's reads held ride the read of
-    the step's tokens onto the ``pdt.engine.decode`` span."""
+    """``experts_hit``, the held pairs and the passes they took beyond the
+    first, and the rows the step's reads held ride the read of the step's
+    tokens onto the ``pdt.engine.decode`` span."""
     from pytorch_distributed_tpu.serving import engine as engine_module
 
     model, variables = served
@@ -466,6 +529,10 @@ def test_decode_span_carries_the_steps_counts(served, monkeypatch):
     stats = seen["engine.decode"]
     # four expert layers, four held of sixteen, one token of four choices
     assert 0 <= stats["experts_hit"] <= 16
+    # at most its four choices are held; one buffer holds them in one pass
+    assert 0 <= stats["experts_fill_pct"] <= 100
+    assert stats["experts_spill"] == 0
+    assert set(stats) == set(WindowedKVCache.STEP_STATS)
     assert stats["kv_full_rows"] == 12 and stats["kv_ring_rows"] == 4 * WINDOW
 
 
