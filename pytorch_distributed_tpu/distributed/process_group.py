@@ -31,7 +31,6 @@ import numpy as np
 from pytorch_distributed_tpu.distributed.store import PrefixStore, Store
 
 from pytorch_distributed_tpu.observability import (
-    put_metric,
     record_event,
     span,
 )
@@ -423,7 +422,6 @@ class ProcessGroup:
                 nbytes=nbytes, world_size=self.world_size,
                 duration_ms=round((time.perf_counter() - t0) * 1e3, 3),
             )
-            put_metric(f"pg.{op_name}")
             return out
 
         if async_op:

@@ -12,8 +12,11 @@
   * ``FlightRecorder``  — C++ ring buffer of eager collectives + stall
     watchdog with dump-on-hang (c10d FlightRecorder + NCCL watchdog roles)
   * ``fr_trace``        — dump analyzer (torch ``flight_recorder/fr_trace.py``)
-  * ``Event`` / ``record_event`` / ``put_metric`` — structured events +
-    counters (torch ``elastic/events``, ``elastic/metrics``)
+  * ``Event`` / ``record_event`` / ``recent_events`` — structured events
+    (torch ``elastic/events``): what the multihost control plane and an
+    operator's log read. There is no counter registry beside them: a count
+    is an attribute of the object that makes it (``Scheduler.tokens_generated``,
+    ``Router.stats()``) or a stat on its span
   * ``debug_level``     — OFF/INFO/DETAIL from $TPU_DISTRIBUTED_DEBUG
     (``debug.h:18`` role; DETAIL also switches on the shadow-verification
     wrapper in pytorch_distributed_tpu.distributed)
@@ -35,9 +38,7 @@ from pytorch_distributed_tpu.observability.logging_utils import (
     LatencyTracker,
     RatioTracker,
     debug_level,
-    get_metrics,
     nan_check,
-    put_metric,
     recent_events,
     record_event,
 )
@@ -58,8 +59,6 @@ __all__ = [
     "Event",
     "record_event",
     "recent_events",
-    "put_metric",
-    "get_metrics",
     "nan_check",
     "IterationLogger",
     "LatencyTracker",

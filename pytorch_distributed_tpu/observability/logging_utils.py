@@ -8,9 +8,7 @@ import dataclasses
 import json
 import logging
 import os
-import threading
 import time
-from collections import defaultdict
 from enum import Enum
 from typing import Any, Callable, Dict, List, Optional
 
@@ -22,8 +20,6 @@ __all__ = [
     "Event",
     "record_event",
     "recent_events",
-    "put_metric",
-    "get_metrics",
     "nan_check",
     "IterationLogger",
     "LatencyTracker",
@@ -79,30 +75,13 @@ def record_event(
             h(ev)
         except Exception:
             logger.exception("event handler failed for %s", name)
-    logger.debug("event: %s", ev.serialize())
+    if logger.isEnabledFor(logging.DEBUG):   # else nothing is serialised
+        logger.debug("event: %s", ev.serialize())
     return ev
 
 
 def recent_events(n: int = 100) -> List[Event]:
     return _recorded_events[-n:]
-
-
-# -- metrics (elastic/metrics put_metric role) -----------------------------
-_metrics: Dict[str, float] = defaultdict(float)
-
-
-_metrics_lock = threading.Lock()
-
-
-def put_metric(name: str, value: float = 1.0) -> None:
-    # called from ProcessGroup pool threads: the += must be atomic or
-    # concurrent async collectives lose counter increments
-    with _metrics_lock:
-        _metrics[name] += value
-
-
-def get_metrics() -> Dict[str, float]:
-    return dict(_metrics)
 
 
 # -- NaN check (NanCheck.hpp role) -----------------------------------------

@@ -34,8 +34,14 @@ def profile_trace(log_dir: str) -> Iterator[None]:
     """Capture a jax.profiler trace to ``log_dir`` (torch.profiler.profile
     role). While it runs every :func:`span` is in the trace, on the clock
     of the device's own events. View with TensorBoard or xprof, or read
-    the ``.xplane.pb`` with ``jax.profiler.ProfileData``."""
-    jax.profiler.start_trace(log_dir, create_perfetto_link=False)
+    the ``.xplane.pb`` with ``jax.profiler.ProfileData``. The Python tracer
+    stays off: it slows the host that is being measured and fills the file
+    with frames nobody reads; the host's own events (the runtime's calls
+    under a span) are kept."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, create_perfetto_link=False,
+                             profiler_options=options)
     try:
         yield
     finally:

@@ -667,29 +667,28 @@ class InferenceEngine:
         stats = {} if request_id is None else {"request_id": request_id}
         with span("engine.prefill", **stats) as whole:
             with span("engine.prefill.dispatch") as dispatch:
-                padded, n_real = self._pad_prompt(prompt[cached_len:])
-                whole.set_metadata(bucket=padded.shape[1], n_real=n_real)
-                if self.cache_kind == "paged":
+                with span("engine.prefill.dispatch.inputs"):
+                    padded, n_real = self._pad_prompt(prompt[cached_len:])
+                    whole.set_metadata(bucket=padded.shape[1], n_real=n_real)
+                    scalars = (slot, n_real) if self.cache_kind != "paged" \
+                        else (slot, cached_len, n_real)
+                    tokens = jnp.asarray(padded)
+                    scalars = [jnp.int32(i) for i in scalars]
+                    rng = self._next_rng()
+                with span("engine.prefill.dispatch.call"):
                     cache, tok = self._prefill(
-                        self.params, cache, jnp.asarray(padded),
-                        jnp.int32(slot), jnp.int32(cached_len),
-                        jnp.int32(n_real), self._next_rng(),
+                        self.params, cache, tokens,
+                        *scalars, rng,
                     )
-                else:
-                    cache, tok = self._prefill(
-                        self.params, cache, jnp.asarray(padded),
-                        jnp.int32(slot), jnp.int32(n_real), self._next_rng(),
-                    )
+                del tokens, scalars, rng  # as in decode: not after the read
                 dispatch.set_metadata(executables=self._prefill._cache_size())
             with span("engine.prefill.read"):
                 tok = int(tok)  # waits for the device
         return cache, tok
 
-    def prefill_draft(
-        self, draft_cache: KVCache, slot: int, prompt: np.ndarray
-    ) -> KVCache:
-        """Admit ``prompt`` into the separate draft model's cache (same
-        bucket as the target prefill; no sampling)."""
+    def prefill_draft(self, draft_cache: KVCache, slot: int,
+                      prompt: np.ndarray) -> KVCache:
+        """``prompt`` into the draft model's cache (same bucket; no token)."""
         if self._draft_prefill is None:
             raise RuntimeError("no separate draft model configured")
         padded, n = self._pad_prompt(prompt)
@@ -698,23 +697,28 @@ class InferenceEngine:
             jnp.int32(slot), jnp.int32(n),
         )
 
-    def decode(
-        self, cache: KVCache, last_tokens: np.ndarray, active: np.ndarray
-    ) -> Tuple[KVCache, np.ndarray]:
-        """One decode step for the whole slot batch.
-
-        ``last_tokens [S]``: each active slot's most recent token (prompt
-        tail or last sample); ``active [S]`` bool. Returns the updated
-        cache and the sampled tokens ``[S]`` (garbage at inactive slots —
-        the scheduler ignores them)."""
+    def decode(self, cache: KVCache, last_tokens: np.ndarray,
+               active: np.ndarray) -> Tuple[KVCache, np.ndarray]:
+        """One decode step for the whole slot batch: ``last_tokens [S]`` is
+        each active slot's newest token, ``active [S]`` bool. Returns the
+        cache and the sampled tokens ``[S]`` (garbage at inactive slots)."""
         with span("engine.decode") as whole:
             with span("engine.decode.dispatch") as dispatch:
+                with span("engine.decode.dispatch.inputs"):
+                    last = jnp.asarray(np.asarray(last_tokens, np.int32))
+                    act = jnp.asarray(np.asarray(active, bool))
+                    rng = self._next_rng()
+                call = span("engine.decode.dispatch.call").__enter__()
                 cache, toks = self._decode(
                     self.params, cache,
-                    jnp.asarray(np.asarray(last_tokens, np.int32)),
-                    jnp.asarray(np.asarray(active, bool)),
-                    self._next_rng(),
+                    last,
+                    act,
+                    rng,
                 )
+                # left BY HAND: a ``with`` moves the call's column, and the
+                # cache's key with it (C19); the inputs go NOW, not after .read
+                call.__exit__(None, None, None)
+                del last, act, rng
                 dispatch.set_metadata(executables=self._decode._cache_size())
             with span("engine.decode.read"):
                 toks = np.asarray(toks)  # waits for the device, copies back
@@ -746,20 +750,23 @@ class InferenceEngine:
             raise RuntimeError("spec_k=0 — speculative decoding disabled")
         with span("engine.decode"):
             with span("engine.decode.dispatch") as dispatch:
-                last = jnp.asarray(np.asarray(last_tokens, np.int32))
-                prev = jnp.asarray(np.asarray(prev_tokens, np.int32))
-                act = jnp.asarray(np.asarray(active, bool))
-                rng = self._next_rng()
-                if self.draft_model is None:
-                    cache, emitted, counts, prev_next = self._spec(
-                        self.params, cache, last, act, rng
-                    )
-                    dcache = draft_cache
-                else:
-                    cache, dcache, emitted, counts, prev_next = self._spec(
-                        self.params, self.draft_params, cache, draft_cache,
-                        last, prev, act, rng,
-                    )
+                with span("engine.decode.dispatch.inputs"):
+                    last = jnp.asarray(np.asarray(last_tokens, np.int32))
+                    prev = jnp.asarray(np.asarray(prev_tokens, np.int32))
+                    act = jnp.asarray(np.asarray(active, bool))
+                    rng = self._next_rng()
+                with span("engine.decode.dispatch.call"):
+                    if self.draft_model is None:
+                        cache, emitted, counts, prev_next = self._spec(
+                            self.params, cache, last, act, rng
+                        )
+                        dcache = draft_cache
+                    else:
+                        step = self._spec(
+                            self.params, self.draft_params, cache,
+                            draft_cache, last, prev, act, rng,
+                        )
+                        cache, dcache, emitted, counts, prev_next = step
                 dispatch.set_metadata(executables=self._spec._cache_size())
             with span("engine.decode.read"):
                 out = (np.asarray(emitted), np.asarray(counts),

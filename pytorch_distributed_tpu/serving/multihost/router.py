@@ -41,7 +41,6 @@ import numpy as np
 from pytorch_distributed_tpu.distributed.store import Store, StoreTimeoutError
 from pytorch_distributed_tpu.observability import (
     LatencyTracker,
-    put_metric,
     record_event,
 )
 from pytorch_distributed_tpu.serving.multihost import protocol
@@ -230,7 +229,6 @@ class Router:
                 step=step,
                 hosts=sum(h.alive for h in self.hosts.values()),
             )
-        put_metric("serving.weight_pushes")
         return self.weight_pushes
 
     # -- membership + health -----------------------------------------------
@@ -279,7 +277,6 @@ class Router:
                 "serving.host_evict", source="router", host=hv.host,
                 chan=hv.chan, reason="heartbeat_ttl", in_flight=len(victims),
             )
-        put_metric("serving.host_evictions")
         readmit: List[_InFlight] = []
         for rid in victims:
             inf = self._inflight[rid]
@@ -376,7 +373,6 @@ class Router:
         del self._inflight[inf.request_id]
         self._completed.add(inf.request_id)
         self.request_latency.add(total)
-        put_metric("serving.router_finished")
         finished.append(fin)
 
     # -- dispatch ----------------------------------------------------------

@@ -4,27 +4,25 @@ The Orca-style iteration-level scheduler: requests queue FIFO, every free
 slot is filled by a prefill at the top of each step, one decode step then
 advances ALL active slots together, and sequences that hit EOS / their
 token budget / slot capacity are evicted at iteration granularity so their
-slot is reusable on the very next step. The decode batch never reshapes —
-finished slots become padding lanes until a queued request takes them over
-(no recompile, no batch drain: a long sequence never holds short ones
-hostage, which is the whole point over static batching).
+slot is reusable on the very next step (finished slots are padding lanes
+until then: the batch never reshapes, nothing recompiles). With a
+speculative engine (``engine.spec_k > 0``) a step consumes 1..k+1 tokens a
+slot from one draft+verify round, scanned token by token, so streams and
+finish reasons are the one-token path's.
 
-With a speculative engine (``engine.spec_k > 0``) each step consumes
-1..k+1 tokens per active slot from one draft+verify round: the accepted
-span is scanned for EOS / budget / capacity exactly as the one-token path
-would have, token by token, so finish reasons and token streams are
-identical to non-speculative serving — only the number of target forwards
-per token changes. Accept-rate and tokens-per-target-forward accumulate in
-``RatioTracker`` counters and flow out through :meth:`Scheduler.stats`.
-
-Per-request and per-step timings flow into ``observability``: structured
-``serving.request_finished`` events carry TTFT and decode latency, and the
-scheduler's LatencyTrackers feed the decode benchmark's p50/p99 numbers.
-Under a profiler session the host spans ``pdt.sched.step`` > ``.admit`` /
-``.consume`` > ``.evict`` say where a step's host time went, with the
-request's id, its queue wait and the tokens consumed as their stats.
-Every request latency starts at ARRIVAL (``Request.arrival_s``), not at
-admission: an open loop's queue wait is part of its time to first token.
+Every request latency starts at ARRIVAL: the instant the front end names
+(``Request.arrival_s``: when the request was DUE), else the submission.
+Under a profiler session a request's spans, each with its ``request_id``:
+``pdt.sched.submit`` (``late_us``: submitted so long after it arrived),
+``pdt.sched.admit`` > ``pdt.engine.prefill`` > ``.dispatch`` > ``.inputs``,
+``.call``, then ``pdt.sched.evict``; ``pdt.sched.step`` > ``.consume`` say
+where a step's host time went. From the host-clock intervals of its recent
+engine calls the scheduler accounts every wait by cause, where it happens:
+on ``sched.admit`` ``queue_us == wait_prefill_us + wait_decode_us +
+wait_other_us`` (inside OTHER requests' prefills, inside decode steps, the
+rest) and ``ttft_us == queue_us + admit_us``, exactly. ``FinishedRequest``
+carries the waits with no session; ``Scheduler.stats()`` and the
+``serving.*`` events (``emit_events``) are the operator's running view.
 """
 
 from __future__ import annotations
@@ -32,7 +30,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from typing import Any, Deque, Dict, List, Optional
+from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -40,7 +38,6 @@ import numpy as np
 from pytorch_distributed_tpu.observability import (
     LatencyTracker,
     RatioTracker,
-    put_metric,
     record_event,
     span,
 )
@@ -82,6 +79,8 @@ class FinishedRequest:
     ttft_s: float  # arrival -> first token (queue wait included)
     total_s: float  # arrival -> eviction
     queue_s: float = 0.0  # arrival -> admission
+    wait_prefill_s: float = 0.0  # of queue_s: inside OTHERS' prefills
+    wait_decode_s: float = 0.0  # of queue_s: inside decode steps
 
 
 @dataclasses.dataclass
@@ -91,6 +90,8 @@ class _SlotState:
     tokens: List[int]
     queue_s: float
     ttft_s: float
+    wait_prefill_s: float
+    wait_decode_s: float
 
 
 class Scheduler:
@@ -146,36 +147,35 @@ class Scheduler:
             self.radix = None
         self.prefill_tokens_total = 0   # prompt tokens across admissions
         self.prefill_tokens_cached = 0  # of those, served from the radix
+        # host-clock (t0, t1, was_prefill) of the newest engine calls (16 s
+        # of 3.9 ms steps; bounded, so nothing grows): what ``_waited``
+        # accounts a wait from
+        self._engine_calls: Deque[Tuple[float, float, bool]] = deque(
+            maxlen=4096)
 
     # -- queue -------------------------------------------------------------
     def submit(self, request: Request) -> int:
         """Enqueue; returns the assigned request id (admission is FIFO)."""
         if request.max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
-        if request.request_id is None:
-            request.request_id = self._next_id
-            self._next_id += 1
-        else:
-            self._next_id = max(self._next_id, request.request_id + 1)
-        if request.arrival_s is None:
-            request.arrival_s = time.perf_counter()
-        self.queue.append(request)
+        with span("sched.submit", prompt_len=len(request.prompt),
+                  queued=len(self.queue), n_active=self._n_active) as submit:
+            if request.request_id is None:
+                request.request_id = self._next_id
+                self._next_id += 1
+            else:
+                self._next_id = max(self._next_id, request.request_id + 1)
+            now = time.perf_counter()
+            if request.arrival_s is None:
+                request.arrival_s = now
+            submit.set_metadata(request_id=request.request_id,
+                                late_us=int((now - request.arrival_s) * 1e6))
+            self.queue.append(request)
         return request.request_id
 
     @property
     def n_active(self) -> int:
         return self._n_active
-
-    @property
-    def free_pages(self) -> int:
-        """Admission capacity in pages — the multihost load snapshot's
-        occupancy signal. Paged: physically free pages net of outstanding
-        reservations. Slotted: free slots in page-equivalents (each slot
-        is a ``max_len`` worth of pages), so routers compare the two cache
-        kinds on one scale."""
-        if self.allocator is not None:
-            return int(self.allocator.available_pages)
-        return (self.engine.n_slots - self.n_active) * self.engine.max_pages
 
     @property
     def has_work(self) -> bool:
@@ -223,6 +223,7 @@ class Scheduler:
                     self.cache, self.last_tokens, self.active
                 )
                 dt = time.perf_counter() - t0
+                self._engine_calls.append((t0, t0 + dt, False))
                 with span("sched.consume") as consume:
                     self.decode_step.add(dt)
                     self.decode_steps += 1
@@ -230,7 +231,6 @@ class Scheduler:
                     self.tokens_generated += n_act
                     self._kv_rows += n_act
                     self.tokens_per_forward.add(n_act)
-                    put_metric("serving.tokens_generated", n_act)
                     n_before = len(finished)
                     for slot in map(int, np.flatnonzero(self.active)):
                         st = self.slots[slot]
@@ -256,6 +256,9 @@ class Scheduler:
             self.prev_tokens, self.active,
         )
         dt = time.perf_counter() - t0
+        # the drafts and the verify are ONE engine call, one interval: a
+        # request that waits for a slot waits out the whole round
+        self._engine_calls.append((t0, t0 + dt, False))
         with span("sched.consume") as consume:
             n_before = len(finished)
             self.decode_step.add(dt)
@@ -264,8 +267,6 @@ class Scheduler:
             n_act = len(active_slots)
             accepted = int(counts[self.active].sum()) - n_act
             self.accept_rate.add(accepted, k * n_act)
-            put_metric("serving.spec_proposed", k * n_act)
-            put_metric("serving.spec_accepted", accepted)
             consumed_total = 0
             step_counts = {}
             for slot in active_slots:
@@ -298,7 +299,6 @@ class Scheduler:
                 step_counts[slot] = consumed
             self.tokens_generated += consumed_total
             self.tokens_per_forward.add(consumed_total)
-            put_metric("serving.tokens_generated", consumed_total)
             if self.emit_events:
                 record_event(
                     "serving.spec_step", source="scheduler",
@@ -318,7 +318,8 @@ class Scheduler:
         ``redistribute/`` planner, so in-flight sequences continue without
         recompiling and (for equal values) without perturbing a single
         token. ``step()`` is synchronous, so any moment outside a
-        ``step()`` call is a safe swap point.
+        ``step()`` call is a safe swap point. ``self.weight_swaps`` counts
+        the swaps made.
         """
         t0 = time.perf_counter()
         cost = self.engine.swap_params(
@@ -334,7 +335,6 @@ class Scheduler:
                 naive_gather_bytes=cost.naive_gather_bytes,
                 duration_s=dt, n_active=self.n_active,
             )
-        put_metric("serving.weight_swaps")
         return cost
 
     def run(self, *, max_steps: Optional[int] = None) -> List[FinishedRequest]:
@@ -412,10 +412,9 @@ class Scheduler:
     def _admit(self, slot: int, req: Request,
                plan=None) -> List[FinishedRequest]:
         prompt = np.asarray(req.prompt, np.int32).reshape(-1)
-        queue_s = time.perf_counter() - req.arrival_s
+        wait_us, queue_s, prefill_s, decode_s = self._waited(req.arrival_s)
         with span("sched.admit", request_id=req.request_id, slot=slot,
-                  prompt_len=int(prompt.shape[0]),
-                  queue_us=int(queue_s * 1e6)) as admit:
+                  prompt_len=int(prompt.shape[0]), **wait_us) as admit:
             cached_len = 0
             if self.allocator is not None:
                 if plan is None:
@@ -427,10 +426,12 @@ class Scheduler:
                         )
                 cached_len = self._attach_pages(slot, plan)
             admit.set_metadata(cached_len=cached_len)
+            t0 = time.perf_counter()
             self.cache, first_tok = self.engine.prefill(
                 self.cache, slot, prompt, cached_len=cached_len,
                 request_id=req.request_id,
             )
+            self._engine_calls.append((t0, time.perf_counter(), True))
             if self.draft_cache is not None:
                 # the separate draft's slotted cache has no prefix sharing —
                 # it always prefills the full prompt
@@ -448,9 +449,15 @@ class Scheduler:
             # first token (an open loop's requests wait for a free slot)
             ttft = time.perf_counter() - req.arrival_s
             self.ttft.add(ttft)
+            # page bookkeeping + the request's own prefill, as an integer so
+            # that ttft_us == queue_us + admit_us to the microsecond
+            ttft_us = int(ttft * 1e6)
+            admit.set_metadata(admit_us=ttft_us - wait_us["queue_us"],
+                               ttft_us=ttft_us)
             self.slots[slot] = _SlotState(
                 request=req, prompt=prompt, tokens=[first_tok],
                 queue_s=queue_s, ttft_s=ttft,
+                wait_prefill_s=prefill_s, wait_decode_s=decode_s,
             )
             self.last_tokens[slot] = first_tok
             self.active[slot] = True
@@ -529,6 +536,8 @@ class Scheduler:
                 ttft_s=st.ttft_s,
                 total_s=total,
                 queue_s=st.queue_s,
+                wait_prefill_s=st.wait_prefill_s,
+                wait_decode_s=st.wait_decode_s,
             )
             if self.emit_events:
                 record_event(
@@ -538,11 +547,54 @@ class Scheduler:
                     new_tokens=len(fin.tokens),
                     ttft_s=fin.ttft_s, total_s=fin.total_s,
                     queue_s=fin.queue_s,
+                    wait_prefill_s=fin.wait_prefill_s,
+                    wait_decode_s=fin.wait_decode_s,
                 )
-            put_metric("serving.requests_finished")
             return fin
 
+    def _waited(self, arrival_s: float):
+        """What filled the wait from ``arrival_s`` to now, the admission:
+        the seconds the scheduler was inside OTHER requests'
+        ``engine.prefill`` and inside decode steps, from the intervals of
+        its newest engine calls, walked back until one ends before the
+        arrival (a call in progress AT the arrival counts for its part
+        after it, so a front end may name an arrival in the past). Returns
+        the ``pdt.sched.admit`` span's wait stats, whole microseconds that
+        add up exactly (``wait_other_us`` is the rest: consume,
+        bookkeeping, the caller's loop, sleep, and whatever the history no
+        longer holds), then the wait and its two parts in seconds."""
+        queue_s = time.perf_counter() - arrival_s
+        prefill_s = decode_s = 0.0
+        prefills = 0
+        for t0, t1, was_prefill in reversed(self._engine_calls):
+            if t1 <= arrival_s:
+                break
+            inside = t1 - max(t0, arrival_s)
+            if was_prefill:
+                prefill_s += inside
+                prefills += 1
+            else:
+                decode_s += inside
+        queue_us = int(queue_s * 1e6)
+        prefill_us = min(int(prefill_s * 1e6), queue_us)
+        decode_us = min(int(decode_s * 1e6), queue_us - prefill_us)
+        return ({"queue_us": queue_us, "wait_prefill_us": prefill_us,
+                 "prefills_ahead": prefills, "wait_decode_us": decode_us,
+                 "wait_other_us": queue_us - prefill_us - decode_us},
+                queue_s, min(prefill_s, queue_s), min(decode_s, queue_s))
+
     # -- stats -------------------------------------------------------------
+    @property
+    def free_pages(self) -> int:
+        """Admission capacity in pages — the multihost load snapshot's
+        occupancy signal. Paged: physically free pages net of outstanding
+        reservations. Slotted: free slots in page-equivalents (each slot
+        is a ``max_len`` worth of pages), so routers compare the two cache
+        kinds on one scale."""
+        if self.allocator is not None:
+            return int(self.allocator.available_pages)
+        return (self.engine.n_slots - self.n_active) * self.engine.max_pages
+
     def stats(self) -> Dict[str, float]:
         """Aggregate serving stats (feeds the decode benchmark report).
 

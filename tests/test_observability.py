@@ -16,8 +16,6 @@ from pytorch_distributed_tpu.observability import (
     fr_trace,
     get_flight_recorder,
     nan_check,
-    put_metric,
-    get_metrics,
     record_event,
 )
 
@@ -95,12 +93,35 @@ class TestFlightRecorder:
 
 class TestLoggingUtils:
     def test_events_and_metrics(self):
+        from pytorch_distributed_tpu.observability import recent_events
+
         ev = record_event("rendezvous_complete", source="agent", nodes=4)
         assert ev.metadata == {"nodes": 4}
         assert json.loads(ev.serialize())["name"] == "rendezvous_complete"
-        put_metric("agent.restarts")
-        put_metric("agent.restarts", 2)
-        assert get_metrics()["agent.restarts"] >= 3
+        assert recent_events(1) == [ev]
+        # events are the one record beside the spans: no counter registry
+        import pytorch_distributed_tpu.observability as obs
+        assert not hasattr(obs, "put_metric")
+        assert not hasattr(obs, "get_metrics")
+
+    def test_an_event_is_serialised_only_for_a_debug_logger(
+            self, monkeypatch, caplog):
+        import logging
+
+        from pytorch_distributed_tpu.observability import logging_utils
+
+        calls = []
+        serialize = logging_utils.Event.serialize
+        monkeypatch.setattr(
+            logging_utils.Event, "serialize",
+            lambda self: calls.append(self.name) or serialize(self))
+        with caplog.at_level(logging.INFO, logger="pytorch_distributed_tpu"):
+            record_event("quiet", source="agent")
+        assert calls == []
+        with caplog.at_level(logging.DEBUG, logger="pytorch_distributed_tpu"):
+            record_event("loud", source="agent")
+        assert calls == ["loud"]
+        assert any("loud" in r.getMessage() for r in caplog.records)
 
     def test_nan_check(self):
         nan_check({"w": np.ones(3)}, name="grads")  # clean passes
@@ -138,6 +159,17 @@ def _pdt_spans(trace_dir):
 
     return program_trace.spans_of_profile(ProfileData.from_file(
         trace_reduce.newest_xplane(str(trace_dir))))
+
+
+def _python_frames(trace_dir):
+    """Events the Python tracer wrote (``$file.py:line function``)."""
+    from jax.profiler import ProfileData
+
+    from chipbench import trace_reduce
+
+    profile = ProfileData.from_file(trace_reduce.newest_xplane(str(trace_dir)))
+    return sum(e.name.startswith("$") for plane in profile.planes
+               for line in plane.lines for e in line.events)
 
 
 def _named(spans, name):
@@ -185,7 +217,7 @@ def served(tmp_path_factory):
                              for st in sched.slots if st is not None),
                          type(sched._kv_rows)))
             finished.extend(sched.step())
-    return _pdt_spans(trace_dir), finished, held
+    return _pdt_spans(trace_dir), finished, held, _python_frames(trace_dir)
 
 
 @pytest.fixture(scope="module")
@@ -241,7 +273,7 @@ class TestSpans:
 
     def test_a_scheduler_step_encloses_admission_decode_and_consume(
             self, served):
-        spans, _, _ = served
+        spans, _, _, _ = served
         step = _named(spans, "sched.step")[0]
         assert step.stats == {"step": 0, "n_active": 0, "queued": 7,
                               "kv_rows": 0}
@@ -259,7 +291,9 @@ class TestSpans:
         assert prefill.stats["bucket"] == 8
         assert prefill.stats["n_real"] == admit.stats["prompt_len"]
         assert set(admit.stats) == {
-            "request_id", "slot", "prompt_len", "cached_len", "queue_us"}
+            "request_id", "slot", "prompt_len", "cached_len", "queue_us",
+            "wait_prefill_us", "prefills_ahead", "wait_decode_us",
+            "wait_other_us", "admit_us", "ttft_us"}
         # every step counts itself; decode steps say how many tokens came
         steps = _named(spans, "sched.step")
         assert [s.stats["step"] for s in steps] == list(range(len(steps)))
@@ -272,7 +306,7 @@ class TestSpans:
         over active sequences of prompt + tokens - 1, through admissions,
         decode steps, evictions and re-admissions into the freed slots;
         kept as a Python int, so the stat costs no array operation."""
-        spans, finished, held = served
+        spans, finished, held, _ = served
         steps = _named(spans, "sched.step")
         assert [s.stats["kv_rows"] for s in steps] == [n for n, _ in held]
         assert all(kind is int for _, kind in held)
@@ -287,7 +321,7 @@ class TestSpans:
         assert len(evicted) == 7
 
     def test_every_admitted_request_is_evicted_with_its_tokens(self, served):
-        spans, finished, _ = served
+        spans, finished, _, _ = served
         admitted = [s.stats["request_id"]
                     for s in _named(spans, "sched.admit")]
         evicted = {s.stats["request_id"]: s.stats
@@ -307,7 +341,7 @@ class TestSpans:
     def test_queue_wait_counts_from_arrival(self, served):
         """Two slots, seven requests submitted at once: the third waits
         until a slot frees, and its wait is in its time to first token."""
-        spans, finished, _ = served
+        spans, finished, _, _ = served
         by_id = {f.request_id: f for f in finished}
         for fin in finished:
             assert 0 <= fin.queue_s < fin.ttft_s <= fin.total_s
@@ -321,6 +355,100 @@ class TestSpans:
         admits = {s.stats["request_id"]: s.stats["queue_us"]
                   for s in _named(spans, "sched.admit")}
         assert admits[2] == int(third.queue_s * 1e6)
+
+    def test_both_identities_hold_for_every_admission(self, served):
+        """Whole microseconds, computed once: the wait is its three causes
+        and the time to first token is the wait plus the admission."""
+        spans, finished, _, _ = served
+        admits = _named(spans, "sched.admit")
+        assert len(admits) == 7
+        for a in admits:
+            st = a.stats
+            assert st["queue_us"] == (st["wait_prefill_us"]
+                                      + st["wait_decode_us"]
+                                      + st["wait_other_us"])
+            assert st["ttft_us"] == st["queue_us"] + st["admit_us"]
+            assert min(st["wait_prefill_us"], st["wait_decode_us"],
+                       st["wait_other_us"], st["admit_us"]) >= 0
+        by_id = {f.request_id: f for f in finished}
+        for a in admits:
+            assert a.stats["ttft_us"] == int(
+                by_id[a.stats["request_id"]].ttft_s * 1e6)
+
+    def test_a_wait_is_accounted_by_what_the_scheduler_was_doing(
+            self, served):
+        """Two slots, seven requests submitted at once: the second waits
+        out the first's prefill in the same join loop; the third waits for
+        a slot, through both prefills and every decode step until one
+        frees."""
+        spans, finished, _, _ = served
+        admits = {s.stats["request_id"]: s.stats
+                  for s in _named(spans, "sched.admit")}
+        prefills = {s.stats["request_id"]: s
+                    for s in _named(spans, "engine.prefill")}
+        assert admits[0]["prefills_ahead"] == 0
+        assert admits[0]["wait_prefill_us"] == admits[0]["wait_decode_us"] == 0
+        assert admits[1]["prefills_ahead"] == 1
+        assert admits[1]["wait_prefill_us"] >= int(prefills[0].seconds * 1e6)
+        assert admits[1]["wait_decode_us"] == 0
+        assert admits[2]["prefills_ahead"] == 2
+        assert admits[2]["wait_decode_us"] > 0
+        freed = min(s.t0 for s in _named(spans, "sched.evict"))
+        decodes = [s for s in _named(spans, "engine.decode") if s.t1 <= freed]
+        assert admits[2]["wait_decode_us"] >= int(
+            sum(s.seconds for s in decodes) * 1e6)
+        by_id = {f.request_id: f for f in finished}
+        assert by_id[2].wait_decode_s > 0 and by_id[0].wait_decode_s == 0
+
+    def test_submission_is_a_span_that_joins_to_the_admission(self, served):
+        """Every stage of a request's life carries its ``request_id``:
+        submit, admit, prefill, evict. Here the scheduler stamped the
+        arrival itself, so nothing was late."""
+        spans, _, _, _ = served
+        submits = _named(spans, "sched.submit")
+        assert [s.stats["request_id"] for s in submits] == list(range(7))
+        assert [s.stats["queued"] for s in submits] == list(range(7))
+        admits = {s.stats["request_id"]: s.stats
+                  for s in _named(spans, "sched.admit")}
+        for s in submits:
+            assert set(s.stats) == {"request_id", "prompt_len", "late_us",
+                                    "queued", "n_active"}
+            admit = admits[s.stats["request_id"]]
+            assert s.stats["late_us"] == 0 <= admit["queue_us"]
+            assert s.stats["prompt_len"] == admit["prompt_len"]
+            assert s.parent is None      # submitted between steps
+        for stage in ("sched.admit", "engine.prefill", "sched.evict"):
+            assert sorted(s.stats["request_id"]
+                          for s in _named(spans, stage)) == list(range(7))
+
+    def test_a_dispatch_is_its_inputs_and_its_call(self, served):
+        spans, _, _, _ = served
+        for engine_call in ("engine.prefill", "engine.decode"):
+            dispatches = _named(spans, engine_call + ".dispatch")
+            assert dispatches
+            for d in dispatches:
+                inside = [s for s in spans if s.parent is d]
+                assert [s.name for s in inside] == [
+                    engine_call + ".dispatch.inputs",
+                    engine_call + ".dispatch.call"]
+                inputs, call = inside
+                assert d.t0 <= inputs.t0 <= inputs.t1 <= call.t0
+                assert call.t1 <= d.t1
+                assert not [s for s in spans if s.parent in (inputs, call)]
+        # they cover the dispatch to within the spans' own overhead (the
+        # median: one descheduled step must not decide it)
+        import statistics
+        gaps = [d.seconds - sum(s.seconds for s in spans if s.parent is d)
+                for d in _named(spans, "engine.decode.dispatch")]
+        assert 0 <= statistics.median(gaps) < 200e-6
+        assert "executables" in _named(
+            spans, "engine.decode.dispatch")[0].stats
+
+    def test_the_session_leaves_the_python_tracer_off(self, served):
+        """``profile_trace`` is for the spans and the runtime's own events;
+        Python frames would slow the host it measures."""
+        spans, *_, python_frames = served
+        assert spans and python_frames == 0
 
     def test_a_front_end_can_say_when_a_request_arrived(self):
         from pytorch_distributed_tpu.serving import (
@@ -339,6 +467,116 @@ class TestSpans:
         (fin,) = sched.run()
         assert fin.queue_s >= 0.25 and fin.ttft_s > fin.queue_s
         assert sched.ttft.percentile(50) == fin.ttft_s
+
+    def test_an_arrival_in_the_past_is_accounted_from_that_instant(
+            self, tmp_path):
+        """A front end that names the due instant: the request was due a
+        quarter second ago, while another's prefill was in progress. Of that
+        prefill only the part AFTER the due instant is its wait, and its
+        submission says how late it came."""
+        from pytorch_distributed_tpu.observability import profile_trace
+        from pytorch_distributed_tpu.serving import (
+            InferenceEngine,
+            Request,
+            Scheduler,
+        )
+
+        model, variables = _tiny_gpt2()
+        engine = InferenceEngine(model, variables, n_slots=2, max_len=32,
+                                 prefill_len=8)
+        sched = Scheduler(engine, emit_events=False)
+        sched.submit(Request(prompt=[1, 2, 3], max_new_tokens=2))
+        sched.run()                      # compiles, outside the session
+        prefill = engine.prefill
+
+        def slow_prefill(*args, **kw):
+            time.sleep(0.4)
+            return prefill(*args, **kw)
+
+        with profile_trace(str(tmp_path)):
+            engine.prefill = slow_prefill
+            sched.submit(Request(prompt=[4, 5, 6], max_new_tokens=4))
+            sched.step()                 # 0.4 s inside the first's prefill
+            engine.prefill = prefill
+            due = time.perf_counter() - 0.25
+            rid = sched.submit(Request(prompt=[7, 8], max_new_tokens=2,
+                                       arrival_s=due))
+            finished = sched.run()
+        t0, t1, was_prefill = [c for c in sched._engine_calls if c[2]][-2]
+        assert was_prefill and t1 - t0 >= 0.4 and t0 < due < t1
+        spans = _pdt_spans(tmp_path)
+        (submit,) = [s for s in _named(spans, "sched.submit")
+                     if s.stats["request_id"] == rid]
+        (admit,) = [s for s in _named(spans, "sched.admit")
+                    if s.stats["request_id"] == rid]
+        assert 250_000 <= submit.stats["late_us"] <= admit.stats["queue_us"]
+        st = admit.stats
+        assert st["prefills_ahead"] == 1
+        assert st["wait_prefill_us"] == int((t1 - due) * 1e6)
+        assert st["wait_prefill_us"] < int((t1 - t0) * 1e6)
+        assert st["wait_decode_us"] > 0          # the step's decode
+        assert st["queue_us"] == (st["wait_prefill_us"] + st["wait_decode_us"]
+                                  + st["wait_other_us"])
+        (fin,) = [f for f in finished if f.request_id == rid]
+        assert fin.wait_prefill_s == pytest.approx(t1 - due)
+        assert fin.queue_s >= 0.25
+
+    def test_finished_requests_carry_their_waits_without_a_session(self):
+        """What an operator without a profiler reads: the same accounting
+        on ``FinishedRequest``, and the history it is made from bounded."""
+        from pytorch_distributed_tpu.serving import (
+            InferenceEngine,
+            Request,
+            Scheduler,
+        )
+
+        model, variables = _tiny_gpt2()
+        sched = Scheduler(InferenceEngine(model, variables, n_slots=1,
+                                          max_len=32, prefill_len=8),
+                          emit_events=False)
+        for _ in range(3):
+            sched.submit(Request(prompt=[1, 2, 3], max_new_tokens=3))
+        first, second, third = sched.run()
+        assert first.wait_prefill_s == first.wait_decode_s == 0.0
+        # one slot: the second waits out the first's prefill and decode steps
+        assert second.wait_prefill_s > 0 and second.wait_decode_s > 0
+        assert third.wait_prefill_s > second.wait_prefill_s
+        for fin in (first, second, third):
+            assert (fin.wait_prefill_s + fin.wait_decode_s
+                    <= fin.queue_s < fin.ttft_s)
+
+    def test_the_history_of_engine_calls_is_bounded(self):
+        """A thousand steps leave the deque at its ``maxlen`` or under:
+        nothing grows with the steps served."""
+        from pytorch_distributed_tpu.serving import Request, Scheduler
+
+        class Engine:
+            """Just enough of an engine: one slot, a token a call."""
+            n_slots, spec_k, cache_kind, max_len = 1, 0, "slotted", 1 << 30
+
+            class _Cache:
+                def evict(self, slot):
+                    return self
+
+            def init_cache(self):
+                return self._Cache()
+
+            def init_draft_cache(self):
+                return None
+
+            def prefill(self, cache, slot, prompt, **kw):
+                return cache, 0
+
+            def decode(self, cache, last_tokens, active):
+                return cache, np.zeros((1,), np.int32)
+
+        sched = Scheduler(Engine(), emit_events=False)
+        sched._engine_calls = type(sched._engine_calls)(maxlen=64)
+        sched.submit(Request(prompt=[1], max_new_tokens=1001))
+        (fin,) = sched.run()
+        assert sched.decode_steps == 1000 and len(fin.tokens) == 1001
+        assert len(sched._engine_calls) == 64
+        assert Scheduler(Engine())._engine_calls.maxlen == 4096
 
     def test_one_dispatch_span_a_step_from_one_executable(self, trained):
         spans, (dispatches, executables) = trained["spans"], trained["counts"]
@@ -412,3 +650,93 @@ class TestSpans:
             pg.all_reduce(np.ones(4, np.float32))
         (found,) = _named(_pdt_spans(tmp_path), "pg.all_reduce")
         assert found.stats["group"] == "spans"
+
+
+# -- the benchmark's reader of ratios over the program's spans ---------------
+def _handmade_context():
+    """Twelve admissions in a 100 ms window, one after it: request i waited
+    ``100 * i`` us on others' prefills and took 1,000 us to admit; its
+    prefill computed a 64-token bucket for ``40 + i`` real tokens in
+    ``(i + 1)`` ms. One admission carries no ``ttft_us`` (an older program's
+    span) and one lies outside the window."""
+    from chipbench import trace_reduce
+    from chipbench.program_trace import HostSpan
+
+    spans = []
+    for i in range(12):
+        t0 = 0.001 + 0.008 * i
+        spans.append(HostSpan("sched.admit", t0, t0 + 0.002, {
+            "request_id": i, "wait_prefill_us": 100 * i, "admit_us": 1000,
+            "ttft_us": 1000 + 100 * i}))
+        spans.append(HostSpan("engine.prefill", t0, t0 + 0.001 * (i + 1), {
+            "request_id": i, "n_real": 40 + i, "bucket": 64}))
+    spans.append(HostSpan("sched.admit", 0.0995, 0.0999, {"request_id": 98}))
+    spans.append(HostSpan("sched.admit", 0.2, 0.3, {
+        "request_id": 99, "wait_prefill_us": 10 ** 6, "admit_us": 1,
+        "ttft_us": 10 ** 6 + 1}))
+    return {"program_spans": spans,
+            "trace": trace_reduce.Reduced(devices=[], spans=[],
+                                          window=(0.0, 0.1))}
+
+
+@pytest.mark.parametrize("args, expected", [
+    # a ratio of sums: real tokens over the tokens the buckets computed
+    (dict(span="engine.prefill", num="n_real", den="bucket", scale=100.0),
+     100.0 * sum(40 + i for i in range(12)) / (12 * 64)),
+    # ``seconds`` is the span's own duration: ms a thousand real tokens
+    (dict(span="engine.prefill", num="seconds", den="n_real", scale=1e6),
+     1e6 * sum(0.001 * (i + 1) for i in range(12))
+     / sum(40 + i for i in range(12))),
+    # the tail: at or above the 80th percentile of ttft_us (nearest rank of
+    # twelve: the ninth smallest), so requests 9, 10, 11
+    (dict(span="sched.admit", num="wait_prefill_us", den="ttft_us",
+          scale=100.0, tail_of="ttft_us", tail_percentile=80, min_spans=10),
+     100.0 * (900 + 1000 + 1100) / (1900 + 2000 + 2100)),
+    (dict(span="sched.admit", num="admit_us", den="ttft_us", scale=100.0,
+          tail_of="ttft_us", tail_percentile=80, min_spans=10),
+     100.0 * 3000 / (1900 + 2000 + 2100)),
+    # nothing to read: an absent span, an absent statistic, too few spans
+    (dict(span="sched.nothing", num="admit_us", den="ttft_us"), None),
+    (dict(span="sched.admit", num="nothing_us", den="ttft_us"), None),
+    (dict(span="sched.admit", num="admit_us", den="ttft_us",
+          tail_of="ttft_us", tail_percentile=80, min_spans=13), None),
+], ids=["ratio", "seconds", "tail_wait", "tail_admit", "absent_span",
+        "absent_stat", "under_min_spans"])
+def test_program_span_ratio_on_hand_made_spans(args, expected):
+    from chipbench.readers import program_span_ratio
+
+    got = program_span_ratio.read(_handmade_context(), **args)
+    if expected is None:
+        assert got is None
+    else:
+        assert got == pytest.approx(expected)
+    # a run that was not traced, and a program without the spans
+    assert program_span_ratio.read({"trace": None}, **args) is None
+    bare = dict(_handmade_context(), program_spans=[])
+    assert program_span_ratio.read(bare, **args) is None
+
+
+def test_every_metric_of_the_waits_names_a_reader_and_its_cells():
+    """The ten metrics of ISSUE 42 resolve to their readers with the
+    arguments their readers take, in the cells that list them."""
+    import inspect
+
+    from chipbench import cells
+
+    bench = cells.load_benchmark()
+    serve = ["gpt2-125m.serve-chat", "xing4.0-29b-a4b.serve-docqa",
+             "k-exaone-236b-a23b.serve-mixed-len"]
+    expected = {
+        "ttft_wait_prefill_ms_mean": serve, "ttft_wait_decode_ms_mean": serve,
+        "ttft_wait_other_ms_mean": serve, "ttft_admit_ms_mean": serve,
+        "ttft_tail_wait_prefill_pct": serve, "ttft_tail_admit_pct": serve,
+        "prefill_ms_per_ktok": serve, "prefill_real_tokens_pct": serve,
+        "decode_dispatch_inputs_ms_p50": serve[:1],
+        "decode_dispatch_call_ms_p50": serve[:1]}
+    listed = {m["name"]: m for m in bench["per_layer"]}
+    assert [m["name"] for m in bench["per_layer"]][-10:] == list(expected)
+    for name, where in expected.items():
+        assert listed[name]["workloads"] == where
+        read, args = cells.load_reader(name)
+        inspect.signature(read).bind({}, **args)
+        assert read({"trace": None}, **args) is None
