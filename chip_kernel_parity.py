@@ -17,7 +17,13 @@ and T = 5 at random offsets, held to a float32 reference that EXPANDS K and
 V from the rows as stored (no absorption). Run it BEFORE a cell, after any
 change to a kernel (``slotted`` or ``latent`` alone runs that half):
 
-    chiprun --chips 1 -- python3 chip_kernel_parity.py [slotted|latent|gqa [prefill|share]]
+    chiprun --chips 1 -- python3 chip_kernel_parity.py [slotted|latent|gqa [prefill|share]|kda]
+
+``kda`` (alone) is the gated delta rule of the
+``kimi-linear-48b-a3b.serve-long-answer`` cell (``kda_cases``): no Pallas
+kernel, but two forms of one function whose float32 arithmetic
+(``Precision.HIGHEST``, exponentials of sums of logarithms) the TPU's
+compiler lowers its own way.
 
 ``gqa`` (alone; the default runs the other two) is the cache of two depths
 of the ``k-exaone-236b-a23b.serve-mixed-len`` cell: ``ops.gqa_attention``'s
@@ -510,6 +516,98 @@ def share_cases():
     return ok
 
 
+def kda_cases():
+    """``ops.kda``'s two forms of the gated delta rule on the chip at the
+    shapes of the ``kimi-linear-48b-a3b.serve-long-answer`` cell (32 heads
+    of 128, bfloat16 q / k / v, float32 state): the chunked form (chunks of
+    64) against the recurrent form over a prompt of 4,096 tokens, values and
+    final state, with the decay drawn as the model's initialiser draws it,
+    with ``a`` near 1 and with ``a`` near 0; a bucket of 4,096 holding 2,500
+    real tokens against the recurrent form over those alone; then both
+    forms timed, a layer: a prompt of 4,096 (chunked) and a decode step of
+    128 slots (recurrent, the states donated)."""
+    from pytorch_distributed_tpu.ops import kda
+
+    H, d, T = 32, 128, 4096
+    ok = True
+
+    def operands(seed, decay, B=1, T=T):
+        ks = jax.random.split(jax.random.key(seed), 7)
+        bf = jnp.bfloat16
+        q = kda._unit(jax.random.normal(ks[0], (B, T, H, d), bf).astype(
+            jnp.float32)) * d ** -0.5
+        k = kda._unit(jax.random.normal(ks[1], (B, T, H, d), bf).astype(
+            jnp.float32))
+        v = jax.random.normal(ks[2], (B, T, H, d), bf).astype(jnp.float32)
+        if decay == "model":        # A_log, dt_bias as models.kimi_linear
+            rate = jax.random.uniform(ks[3], (H, 1), minval=1.0, maxval=16.0)
+            dt = jnp.exp(jax.random.uniform(ks[4], (H, d)) * np.log(100.0)
+                         + np.log(1e-3))
+            log_a = -rate * jax.nn.softplus(
+                dt + jnp.log(-jnp.expm1(-dt))
+                + 0.05 * jax.random.normal(ks[5], (B, T, H, d)))
+        else:
+            lo, hi = decay
+            log_a = jnp.log(jax.random.uniform(ks[3], (B, T, H, d),
+                                               minval=lo, maxval=hi))
+        beta = jax.nn.sigmoid(jax.random.normal(ks[6], (B, T, H)))
+        return q, k, v, log_a, beta, jnp.zeros((B, H, d, d), jnp.float32)
+
+    recurrent = jax.jit(kda.gated_delta_rule)
+    chunked = jax.jit(functools.partial(kda.gated_delta_rule,
+                                        chunk=kda.CHUNK))
+    masked = jax.jit(lambda *x, valid: kda.gated_delta_rule(
+        *x, chunk=kda.CHUNK, valid=valid))
+    for n, (name, decay, real) in enumerate([
+            ("model", "model", T), ("a_near_1", (0.99, 0.99999), T),
+            ("a_near_0", (1e-4, 1e-2), T), ("pad", "model", 2500)]):
+        x = operands(n, decay)
+        if real < T:
+            o, state = masked(*x, valid=jnp.arange(T)[None] < real)
+            want_o, want_state = recurrent(*(a[:, :real] for a in x[:5]),
+                                           x[5])
+            o = o[:, :real]
+        else:
+            o, state = chunked(*x)
+            want_o, want_state = recurrent(*x)
+        o, state, want_o, want_state = (np.asarray(a) for a in (
+            o, state, want_o, want_state))
+        line = {
+            "case": f"kda_{name}", "T": T, "real": real, "heads": H, "d": d,
+            "o_range": float(want_o.max() - want_o.min()),
+            "state_range": float(want_state.max() - want_state.min()),
+            "chunked_vs_recurrent_o": float(np.abs(o - want_o).max()),
+            "chunked_vs_recurrent_state": float(
+                np.abs(state - want_state).max()),
+            "finite": bool(np.isfinite(o).all() and np.isfinite(state).all()),
+        }
+        # float32 at Precision.HIGHEST on both sides: orders of sums differ
+        line["within_tolerance"] = bool(
+            line["chunked_vs_recurrent_o"] <= 1e-4 * max(line["o_range"], 1)
+            and line["chunked_vs_recurrent_state"]
+            <= 1e-4 * max(line["state_range"], 1))
+        ok &= line["within_tolerance"] and line["finite"]
+        print(json.dumps(line), flush=True)
+    x = operands(9, "model")
+    line = {"case": "kda_times", "prefill_4096_chunked_ms_layer":
+            _timed(chunked, *x, calls=5)}
+    S = 128
+    q, k, v, log_a, beta, _ = operands(10, "model", B=S, T=1)
+    step = jax.jit(kda.gated_delta_rule, donate_argnums=5)
+    state = jnp.ones((S, H, d, d), jnp.float32)
+    _, state = step(q, k, v, log_a, beta, state)
+    jax.block_until_ready(state)
+    t0 = time.perf_counter()
+    for _ in range(20):
+        out, state = step(q, k, v, log_a, beta, state)
+    jax.block_until_ready(out)
+    ms = (time.perf_counter() - t0) / 20 * 1e3
+    line["decode_128_slots_recurrent_ms_layer"] = ms
+    line["decode_state_gb_s"] = 2 * S * H * d * d * 4 / ms / 1e6
+    print(json.dumps(line), flush=True)
+    return ok
+
+
 def main():
     device = jax.devices()[0]
     if device.platform != "tpu":
@@ -517,8 +615,8 @@ def main():
                           "kernel's arithmetic exists only on a TPU"}))
         return 1
     which = sys.argv[1] if len(sys.argv) > 1 else "both"
-    if which == "gqa":
-        ok = gqa_cases(*sys.argv[2:3])
+    if which in ("gqa", "kda"):
+        ok = gqa_cases(*sys.argv[2:3]) if which == "gqa" else kda_cases()
         print(json.dumps({"ok": ok, "device": {
             "platform": device.platform, "kind": device.device_kind}}))
         return 0 if ok else 1

@@ -17,6 +17,7 @@ from pytorch_distributed_tpu.models.resnet import (
 from pytorch_distributed_tpu.models.gpt2 import GPT2, GPT2Config, gpt2_125m
 from pytorch_distributed_tpu.models.xing4 import Xing4, Xing4Config
 from pytorch_distributed_tpu.models.exaone_moe import ExaoneMoE, ExaoneMoEConfig
+from pytorch_distributed_tpu.models.kimi_linear import KimiLinear, KimiLinearConfig
 
 __all__ = [
     "ResNet",
@@ -31,4 +32,6 @@ __all__ = [
     "Xing4Config",
     "ExaoneMoE",
     "ExaoneMoEConfig",
+    "KimiLinear",
+    "KimiLinearConfig",
 ]

@@ -4,6 +4,8 @@ The inference face of the framework, reusing the training stack end to end:
 
   * :mod:`kv_cache`  — preallocated slotted KV cache, a donated jit pytree
     with multi-token append + rejection rollback
+  * :mod:`state_cache` — the hybrid stack's: a fixed-size recurrent state a
+    slot for the KDA layers beside a latent cache's rows for the MLA layers
   * :mod:`paging`    — the paged alternative: fixed-size K/V pages + block
     tables (:class:`PagedKVCache`), a refcounted COW allocator, and a radix
     tree that maps shared prompt prefixes to live page chains so repeat
@@ -33,6 +35,7 @@ from pytorch_distributed_tpu.serving.engine import (
 )
 from pytorch_distributed_tpu.serving.kv_cache import KVCache, LatentCache
 from pytorch_distributed_tpu.serving.window_cache import WindowedKVCache
+from pytorch_distributed_tpu.serving.state_cache import HybridStateCache
 from pytorch_distributed_tpu.serving.paging import (
     CapacityError,
     PageAllocator,
@@ -67,6 +70,7 @@ __all__ = [
     "KVCache",
     "LatentCache",
     "WindowedKVCache",
+    "HybridStateCache",
     "PagedKVCache",
     "PageAllocator",
     "RadixTree",

@@ -777,9 +777,10 @@ class InferenceEngine:
 def _slotted_cache_class(model, cache_kind, cache_sharding, spec_k):
     """The class of the slotted cache a model is served from: ``KVCache``
     unless the model names its own (``model.cache_class``; ``models.xing4``
-    names ``LatentCache``, ``models.exaone_moe`` ``WindowedKVCache``). What
-    such a cache does not support raises here, at construction, with a
-    sentence (the class's ``UNSUPPORTED_BECAUSE``). A class's ``STEP_STATS`` name the
+    names ``LatentCache``, ``models.exaone_moe`` ``WindowedKVCache``,
+    ``models.kimi_linear`` ``HybridStateCache``). What such a cache does not
+    support raises here, at construction, with a sentence (the class's
+    ``UNSUPPORTED_BECAUSE``). A class's ``STEP_STATS`` name the
     counts its model leaves in ``cache.step_stats`` each step: the decode
     program sends them to the host behind the step's tokens, in the one
     read the step makes anyway, onto the ``pdt.engine.decode`` span."""

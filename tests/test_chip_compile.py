@@ -607,3 +607,102 @@ def test_windowed_prefill_bucket_32768_fits_beside_the_resident_state_for_v5e(
     assert not [line for body in computations.values()
                 for _, _, elements, line in body
                 if elements >= 32768 * 32768 and "= f32[" in line]
+
+
+def _kimi_engine(v5e_device, monkeypatch):
+    """The engine of ``kimi-linear-48b-a3b.serve-long-answer`` (the
+    configuration file as it is: published widths, bfloat16, layers 1-8, 64
+    of 256 experts; the traffic file's slots and positions) over described
+    shapes."""
+    from chipbench import cells
+    from chipbench.families import kimi_linear as family
+    from pytorch_distributed_tpu.ops import decode_attention
+    from pytorch_distributed_tpu.serving import InferenceEngine
+
+    monkeypatch.setattr(decode_attention, "_platform", lambda: "tpu")
+    cell = cells.resolve(cells.load_benchmark(),
+                         "kimi-linear-48b-a3b.serve-long-answer")
+    model = family.build_model(cell.config)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=v5e_device), tree)
+
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    engine = InferenceEngine(model, params, n_slots=cell.traffic["n_slots"],
+                             max_len=cell.traffic["max_len"])
+    cache = described(jax.eval_shape(engine.init_cache))
+    rng = described(jax.eval_shape(lambda: jax.random.key(0)))
+    return engine, described(params), cache, rng
+
+
+def test_hybrid_decode_program_rewrites_the_states_where_they_lie_for_v5e(
+        v5e_device, monkeypatch):
+    """3.77 G parameters (the issue's arithmetic), six float32 states of
+    268 MB and two layers of latent rows donated: every leaf of the cache
+    but the step's counts is aliased to an output, the step's temporaries
+    are under a fiftieth of the cache (no second copy of a state), the MLA
+    layers read with two calls of the latent cache's Mosaic kernel, and a
+    KDA layer passes over its state in two fusions (the reduction over k,
+    then the update with the output's reduction), never more."""
+    from pytorch_distributed_tpu.analysis.ir.hlo import aliased_param_indices
+
+    engine, params, cache, rng = _kimi_engine(v5e_device, monkeypatch)
+    assert sum(a.size for a in jax.tree_util.tree_leaves(params)) \
+        == 3_772_368_832
+    slots = cache.n_slots
+    assert {s.shape for s in cache.state} == {(slots, 32, 128, 128)}
+    assert {t.shape for t in cache.tail} == {(slots, 3, 12288)}
+    assert cache.latent.rows.shape == (2, slots, 6144, 640)
+    assert cache.slot_state_bytes() == 25_608_192
+    compiled = engine._decode.lower(
+        params, cache,
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_device),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e_device), rng,
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < _bytes(cache) / 50
+    text = compiled.as_text()
+    first = len(jax.tree_util.tree_leaves(params))
+    leaves = len(jax.tree_util.tree_leaves(cache))
+    # 6 states, 6 tails, rows, lengths and the latent cache's (unused)
+    # counts pass through; the step's own counts are new
+    assert leaves == 16
+    assert len(aliased_param_indices(text)) == 15
+    assert set(aliased_param_indices(text)) <= set(
+        range(first, first + leaves))
+    kernels = re.findall(r"[^\n]*latent_attention_read/pallas_call[^\n]*",
+                         text)
+    assert len([k for k in kernels if "tpu_custom_call" in k]) == 2
+    # every read of a state is one of two fusions a layer
+    reads = [line for line in text.splitlines()
+             if " fusion(" in line and "%cache_state_" in line]
+    assert len(reads) == 12 and all("pdt.kda.decode" in r for r in reads)
+    computations, _ = _computations(text)
+    state = slots * 32 * 128 * 128
+    assert not [line for body in computations.values()
+                for _, opcode, elements, line in body
+                if opcode in ("copy", "transpose") and elements >= state]
+
+
+def test_hybrid_prefill_bucket_4096_fits_beside_the_resident_state_for_v5e(
+        v5e_device, monkeypatch):
+    """The traffic's longest bucket: its temporaries (the chunked scan's a
+    chunk at a time, the MLA layers' blocks of scores, 16,384 expert rows)
+    beside 11.2 GB of weights and cache leave three gigabytes free, and the
+    scan over chunks is there once a KDA layer."""
+    engine, params, cache, rng = _kimi_engine(v5e_device, monkeypatch)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e_device)
+    compiled = engine._prefill.lower(
+        params, cache,
+        jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=v5e_device),
+        i32, i32, rng).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    resident = _bytes(params) + _bytes(cache)
+    assert 11.1e9 < resident < 11.4e9
+    assert resident + temp < V5E_BYTES_LIMIT - 3.0e9, (resident, temp)
+    text = compiled.as_text()
+    loops = set(re.findall(
+        r"layer_(\d)_attn/pdt\.kda\.prefill/[^\"]*while", text))
+    assert loops == {"0", "1", "2", "4", "5", "6"}
