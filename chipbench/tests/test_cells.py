@@ -11,6 +11,9 @@ from chipbench import cells
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+#: what ``reduced`` may never name: a width of the model
+WIDTH = re.compile(r"(_dim|_rank|hidden_size|intermediate_size|head_size"
+                   r"|n_embd|experts_per_tok)$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 BENCH = cells.load_benchmark()
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -62,8 +65,14 @@ def test_names_units_and_keys():
 @pytest.mark.parametrize("name", CELLS)
 def test_cell_resolves_to_files(name):
     cell = cells.resolve(BENCH, name)
-    assert cell.config["reduced"] == []          # nothing is cut
-    assert cell.traffic["kind"] in ("train", "serve_open_loop")
+    # a cell states its cuts once: the file's list is the entry's, and a
+    # width is never among them
+    entry = next(c for c in BENCH["configs"] if c["name"] == cell.config_name)
+    assert cell.config["reduced"] == entry["reduced"]
+    assert len(entry["reduced"]) <= 16
+    assert not any(WIDTH.search(key) for key in entry["reduced"]), entry
+    # a kind of traffic is a driver of that name, whichever PR brought it
+    assert (cells.HERE / "drivers" / f"{cell.traffic['kind']}.py").is_file()
     assert cells.load_driver(cell.traffic["kind"]).run
     e2e = {m["name"] for m in cell.end_to_end}
     assert "setup_s" in e2e and len(e2e) >= 2 and cell.per_layer
