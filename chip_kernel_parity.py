@@ -17,7 +17,12 @@ and T = 5 at random offsets, held to a float32 reference that EXPANDS K and
 V from the rows as stored (no absorption). Run it BEFORE a cell, after any
 change to a kernel (``slotted`` or ``latent`` alone runs that half):
 
-    chiprun --chips 1 -- python3 chip_kernel_parity.py [slotted|latent|gqa [prefill|share]|kda]
+    chiprun --chips 1 -- python3 chip_kernel_parity.py [slotted|latent|gqa [prefill|share]|kda|grouped [sweep]]
+
+``grouped`` (alone; PR 48) is ``ops.grouped_matmul``, the experts' grouped
+products of the three mixture-of-experts cells, against ``ragged_dot`` at
+their real shapes in both regimes (``grouped_cases``; ``grouped sweep``
+also times other tiles than the kernel takes by itself).
 
 ``kda`` (alone) is the gated delta rule of the
 ``kimi-linear-48b-a3b.serve-long-answer`` cell (``kda_cases``): no Pallas
@@ -608,6 +613,91 @@ def kda_cases():
     return ok
 
 
+#: (cell, regime, rows m, held rows, E, d, F): the grouped products of the
+#: three serving configurations at their real shapes
+GROUPED = [
+    ("k-exaone", "decode", 128, 40, 16, 6144, 2048),
+    ("k-exaone", "prefill", 8192, 4250, 16, 6144, 2048),
+    ("xing4.0", "decode", 192, 192, 64, 3584, 1024),
+    ("xing4.0", "prefill", 32768, 32768, 64, 3584, 1024),
+    ("kimi-linear", "decode", 512, 200, 64, 2304, 1024),
+    ("kimi-linear", "prefill", 16384, 8192, 64, 2304, 1024),
+]
+#: other tiles than ``grouped_matmul`` takes by itself, timed by ``grouped
+#: sweep`` only: (rows of a tile, bytes of a matrix block)
+SWEEP = [(256, 8 << 20), (128, 4 << 20), (128, 16 << 20), (64, 8 << 20)]
+
+
+def grouped_cases(sweep=None):
+    """``ops.grouped_matmul`` against ``jax.lax.ragged_dot`` (float32 sums,
+    cast back) at the cells' shapes: both products of an expert (``[m, d] x
+    [E, d, F]`` and ``[m, F] x [E, F, d]``), group sizes drawn as a routing
+    draws them (multinomial over the experts; a decode step's held rows are
+    few and leave experts empty), NaN planted in the operand rows past the
+    last group. Each is held to the float32 product of the same bfloat16
+    operands: the kernel may lie no further from it than ``ragged_dot``
+    does, and its rows past the groups are exactly 0."""
+    from pytorch_distributed_tpu.ops import grouped_matmul as gm
+
+    @jax.jit
+    def ragged(rows, w, sizes):
+        return jax.lax.ragged_dot(
+            rows, w, sizes, preferred_element_type=jnp.float32
+        ).astype(rows.dtype)
+
+    @jax.jit
+    def exact(rows, w, sizes):
+        return jax.lax.ragged_dot(
+            rows.astype(jnp.float32), w.astype(jnp.float32), sizes,
+            precision=jax.lax.Precision.HIGHEST)
+
+    kernel = jax.jit(gm.grouped_matmul)
+    ok = True
+    for n, (cell, regime, m, held, E, d, F) in enumerate(GROUPED):
+        rng = np.random.default_rng(n)
+        sizes = jnp.asarray(rng.multinomial(held, np.ones(E) / E), jnp.int32)
+        hit = int((np.asarray(sizes) > 0).sum())
+        for product, (K, N) in (("gate", (d, F)), ("down", (F, d))):
+            kr, kw = jax.random.split(jax.random.key(2 * n + (K < N)))
+            rows = jax.random.normal(kr, (m, K), jnp.bfloat16)
+            w = jax.random.normal(kw, (E, K, N), jnp.bfloat16) * K ** -0.5
+            planted = rows.at[held:].set(jnp.nan)
+            ref = np.asarray(exact(rows, w, sizes))[:held]
+            out = np.asarray(kernel(planted, w, sizes), np.float32)
+            old = np.asarray(ragged(rows, w, sizes), np.float32)[:held]
+            tiles = (gm.row_tile(m),) + gm._column_tiles(K, N, 2)
+            line = {
+                "case": f"grouped/{cell}/{regime}/{product}",
+                "m": m, "K": K, "N": N, "E": E, "held_rows": held,
+                "groups_hit": hit, "tiles": list(tiles),
+                "kernel_vs_float32": float(np.abs(out[:held] - ref).max()),
+                "ragged_dot_vs_float32": float(np.abs(old - ref).max()),
+                "kernel_vs_ragged_dot": float(np.abs(out[:held] - old).max()),
+                "rows_past_groups_zero": bool((out[held:] == 0).all()),
+                "finite": bool(np.isfinite(out).all()),
+                "kernel_ms": _timed(kernel, planted, w, sizes, calls=20),
+                "ragged_dot_ms": _timed(ragged, rows, w, sizes, calls=20),
+            }
+            line["kernel_matrix_gb_s"] = (hit * K * N * 2 / line["kernel_ms"]
+                                          / 1e6)
+            line["kernel_tflop_s"] = (2 * held * K * N / line["kernel_ms"]
+                                      / 1e9)
+            line["ok"] = (line["finite"] and line["rows_past_groups_zero"]
+                          and line["kernel_vs_float32"]
+                          <= line["ragged_dot_vs_float32"] * 1.0001 + 1e-6)
+            ok &= line["ok"]
+            for tm, block in (SWEEP if sweep == "sweep" else []):
+                other = (tm,) + gm._column_tiles(K, N, 2, block)
+                if m % tm or other == tiles:
+                    continue
+                call = functools.partial(gm._grouped_call, tiles=other)
+                line["ms_at_" + "x".join(map(str, other))] = _timed(
+                    call, gm.grouped_schedule(sizes, m, tm), planted, w,
+                    calls=20)
+            print(json.dumps(line), flush=True)
+    return ok
+
+
 def main():
     device = jax.devices()[0]
     if device.platform != "tpu":
@@ -615,8 +705,9 @@ def main():
                           "kernel's arithmetic exists only on a TPU"}))
         return 1
     which = sys.argv[1] if len(sys.argv) > 1 else "both"
-    if which in ("gqa", "kda"):
-        ok = gqa_cases(*sys.argv[2:3]) if which == "gqa" else kda_cases()
+    if which in ("gqa", "kda", "grouped"):
+        ok = {"gqa": gqa_cases, "kda": kda_cases,
+              "grouped": grouped_cases}[which](*sys.argv[2:3])
         print(json.dumps({"ok": ok, "device": {
             "platform": device.platform, "kind": device.device_kind}}))
         return 0 if ok else 1

@@ -5,13 +5,14 @@ and run each expert's rows through its own matrices with a grouped matmul.
 places and drops what does not fit (``make_dispatch_masks``, an ``[n, E,
 capacity]`` mask). Here no token is dropped at any imbalance: the ``n * k``
 pairs are sorted by expert (stable: a token's order inside an expert is its
-order in the batch), ``jax.lax.ragged_dot`` multiplies each expert's
-contiguous group of rows by that expert's matrix (the TPU compiler lowers
-it natively: the FLOPs are those of the rows present, 1.41 GFLOP for 192
-rows of ``[3584, 1024]``, not E times that; compile result, PERF.md PR 33),
-and the results go back to their tokens by the inverse permutation and are
-summed under their gates in float32. One expert taking every token is one
-group of ``n * k`` rows and 63 empty ones.
+order in the batch), a grouped product multiplies each expert's contiguous
+group of rows by that expert's matrix (``ops.grouped_matmul``'s Pallas
+kernel on a TPU, ``jax.lax.ragged_dot`` on any other backend and for shapes
+the kernel cannot tile: the FLOPs are those of the rows present, 1.41 GFLOP
+for 192 rows of ``[3584, 1024]``, not E times that; float32 sums, a result
+in ``x``'s dtype either way), and the results go back to their tokens by
+the inverse permutation and are summed under their gates in float32. One
+expert taking every token is one group of ``n * k`` rows and 63 empty ones.
 
 A HOLDER of ``count`` of ``num_experts`` experts (``held_share``; an
 expert-parallel deployment's exchange hands a chip its own pairs and no
@@ -33,10 +34,17 @@ paragraph to the letter.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from pytorch_distributed_tpu.ops.grouped_matmul import (
+    grouped_matmul,
+    grouped_schedule,
+    kernel_groups,
+)
 
 __all__ = ["route_sigmoid_topk", "dropless_experts", "held_share",
            "share_rows", "share_passes"]
@@ -97,9 +105,29 @@ def dropless_experts(x, experts, gates, w_gate, w_up, w_down,
     ``held_share`` made of the routing (module docstring: the held pairs
     alone are gathered and multiplied, ``share_rows`` of them a pass).
     Without it such a routing's pairs are all sorted and multiplied at
-    once, and what the other holders' pairs add is zero only where
-    ``ragged_dot`` leaves the rows past its groups zero (it does on the
-    CPU; on the TPU they once read NaN: PERF.md, PR 41)."""
+    once, and what the other holders' pairs add is zero only where the
+    grouped product leaves the rows past its groups zero: the kernel writes
+    zeros there, ``ragged_dot`` does on the CPU (on the TPU they once read
+    NaN: PERF.md, PR 41).
+
+    The grouped products are ``ops.grouped_matmul``'s Pallas kernel where
+    it can take them (``kernel_groups``: a TPU, whole tiles) and
+    ``jax.lax.ragged_dot`` anywhere else, decided here on the operands'
+    shapes. The arithmetic is one module-level ``jax.jit``
+    (``_dropless_experts``): the layers of a program share one trace and one
+    lowered function of it (PERF.md, "where a warm set-up goes")."""
+    n, k = experts.shape
+    rows = jax.ShapeDtypeStruct(
+        (share_rows(n * k, w_gate.shape[0], num_experts or w_gate.shape[0]),
+         x.shape[-1]), x.dtype)
+    return _dropless_experts(x, experts, gates, w_gate, w_up, w_down,
+                             num_experts=num_experts,
+                             kernel=kernel_groups(rows, w_gate))
+
+
+@functools.partial(jax.jit, static_argnames=("num_experts", "kernel"))
+def _dropless_experts(x, experts, gates, w_gate, w_up, w_down, *,
+                      num_experts, kernel):
     n, k = experts.shape
     n_experts = w_gate.shape[0]
     cap = share_rows(n * k, n_experts, num_experts or n_experts)
@@ -108,10 +136,17 @@ def dropless_experts(x, experts, gates, w_gate, w_up, w_down,
     sizes = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
 
     def ffn(rows, sizes):
-        def grouped(a, w):
-            return jax.lax.ragged_dot(
-                a, w, sizes, preferred_element_type=jnp.float32
-            ).astype(x.dtype)
+        if kernel:
+            # one schedule of row tiles and groups for the three products
+            schedule = grouped_schedule(sizes, rows.shape[0])
+
+            def grouped(a, w):
+                return grouped_matmul(a, w, sizes, schedule=schedule)
+        else:
+            def grouped(a, w):
+                return jax.lax.ragged_dot(
+                    a, w, sizes, preferred_element_type=jnp.float32
+                ).astype(x.dtype)
 
         return grouped(
             jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up), w_down)

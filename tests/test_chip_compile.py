@@ -279,6 +279,25 @@ def _bytes(tree):
                for a in jax.tree_util.tree_leaves(tree))
 
 
+def _grouped_kernels(text, layers):
+    """The compiled module multiplies the experts' groups with
+    ``ops.grouped_matmul``'s kernel alone: three products a trace of the
+    experts' function a layer, each under its layer's ``moe/experts``
+    scope (what ``decode_moe_ms_step`` and ``prefill_moe_ms_p50`` read)."""
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if "tpu_custom_call" in line and "grouped_matmul" in line]
+    assert calls and len(calls) % (3 * layers) == 0, calls
+    by_layer = {}
+    for op_name in calls:
+        layer = re.search(r"layer_(\d+)_\S*?/moe/experts/", op_name)
+        assert layer and op_name.endswith("/pallas_call"), op_name
+        by_layer[layer.group(1)] = by_layer.get(layer.group(1), 0) + 1
+    assert len(by_layer) == layers and len(set(by_layer.values())) == 1
+    return calls
+
+
 def test_latent_decode_program_writes_the_cache_in_place_for_v5e(
         v5e_device, monkeypatch):
     """Six layers (4.79 G parameters), the cache donated: the step keeps
@@ -335,6 +354,8 @@ def test_latent_decode_program_writes_the_cache_in_place_for_v5e(
     ).as_text()
     assert len(re.findall(r"func.func private @_kernel_read", lowered)) == 1
     assert len(re.findall(r"call @_kernel_read", lowered)) == 6
+    # five layers of experts, every pair held: one trace, three products
+    assert len(_grouped_kernels(text, layers=5)) == 15
     # no fusion but the row writes takes the cache or a layer's slab of it
     # (weights this large there are: the 131,072-row embedding and head)
     readers = [c for c in fused
@@ -360,6 +381,7 @@ def test_latent_prefill_bucket_8192_fits_beside_the_resident_state_for_v5e(
     resident = 9_596_580_368 + 6 * 48 * 8192 * 640 * 2
     assert temp < 3.4e9, temp
     assert resident + temp < V5E_BYTES_LIMIT - 0.5e9, (resident, temp)
+    _grouped_kernels(compiled.as_text(), layers=1)
 
 
 # -- the FSDP train step: parameters are gathered, activations are not -------
@@ -574,6 +596,8 @@ def test_windowed_decode_program_reads_what_the_slots_hold_for_v5e(
     assert aliased_param_indices(text) == list(range(first, first + 5))
     kernels = re.findall(r"[^\n]*gqa_attention_read/pallas_call[^\n]*", text)
     assert len([k for k in kernels if "tpu_custom_call" in k]) == 5
+    # four layers of experts: the first pass in line, the others in a loop
+    assert len(_grouped_kernels(text, layers=4)) == 24
     slab = slots * 32768 * 1024
     computations, _ = _computations(text)
     relayouts = [line for body in computations.values()
@@ -602,6 +626,7 @@ def test_windowed_prefill_bucket_32768_fits_beside_the_resident_state_for_v5e(
     kernels = re.findall(r"[^\n]*gqa_attention_prefill/pallas_call[^\n]*",
                          text)
     assert len([k for k in kernels if "tpu_custom_call" in k]) == 1
+    _grouped_kernels(text, layers=4)
     computations, _ = _computations(text)
     # (the cache's full layer has that many elements itself, in bfloat16)
     assert not [line for body in computations.values()
@@ -675,6 +700,7 @@ def test_hybrid_decode_program_rewrites_the_states_where_they_lie_for_v5e(
     kernels = re.findall(r"[^\n]*latent_attention_read/pallas_call[^\n]*",
                          text)
     assert len([k for k in kernels if "tpu_custom_call" in k]) == 2
+    assert len(_grouped_kernels(text, layers=7)) == 42
     # every read of a state is one of two fusions a layer
     reads = [line for line in text.splitlines()
              if " fusion(" in line and "%cache_state_" in line]
@@ -706,3 +732,81 @@ def test_hybrid_prefill_bucket_4096_fits_beside_the_resident_state_for_v5e(
     loops = set(re.findall(
         r"layer_(\d)_attn/pdt\.kda\.prefill/[^\"]*while", text))
     assert loops == {"0", "1", "2", "4", "5", "6"}
+    _grouped_kernels(text, layers=7)
+
+
+# -- the experts' grouped products: one kernel, lowered once a shape ----------
+
+#: (the engine of a cell, the prefill bucket lowered beside the decode
+#: program, the traces of the experts' function a program holds: a holder of
+#: a share multiplies its first pass in line and the later ones in a loop's
+#: body, and JAX lowers a jitted function called from both places twice (it
+#: rewrites an in-line call's jaxpr when it prunes a program's unused
+#: inputs and not one inside a loop, so its cache of lowered functions sees
+#: two), copying the ONE lowered kernel into each)
+_GROUPED = {
+    "xing4": (functools.partial(_xing4_engine, n_layer=2), 2048, 1),
+    "exaone": (_exaone_engine, 8192, 2),
+    "kimi": (_kimi_engine, 4096, 2),
+}
+
+
+def _mosaic_lowerings(monkeypatch):
+    """The calls of Pallas's Mosaic lowering from here on, as a list that
+    grows: what a process pays a program whether its compile cache is warm
+    or not."""
+    from jax._src.pallas.mosaic import pallas_call_registration as reg
+
+    calls = []
+    lower = reg.lowering.lower_jaxpr_to_module
+
+    def counted(ctx, grid_mapping, jaxpr, **kwargs):
+        calls.append(str(jaxpr.debug_info.func_src_info))  # function at file
+        return lower(ctx, grid_mapping, jaxpr, **kwargs)
+
+    monkeypatch.setattr(reg.lowering, "lower_jaxpr_to_module", counted)
+    return calls
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("family", sorted(_GROUPED))
+def test_grouped_products_lower_one_kernel_a_shape_for_v5e(
+        v5e_device, monkeypatch, family, program):
+    """Every grouped product of every layer of a serving program is
+    ``ops.grouped_matmul``'s kernel, never ``ragged_dot``, and the kernel
+    is LOWERED (Pallas to a Mosaic module: what a process pays with a warm
+    compile cache too) once a distinct ``(m, K, N)``, two a program,
+    whatever the layers: the lowered text holds each shape's
+    ``tpu_custom_call`` once a trace of the experts' function, called from
+    every layer, where a bare ``pallas_call`` would be one a call site (24
+    in cell 6's programs). Counts, not times: this fails here when a later
+    edit lets the call sites multiply, not on the chip as a set-up 5 s
+    longer (PR 47)."""
+    build, bucket, traces = _GROUPED[family]
+    engine, params, cache, rng = build(v5e_device, monkeypatch)
+    lowerings = _mosaic_lowerings(monkeypatch)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e_device)
+    if program == "decode":
+        slots = engine.n_slots
+        lowered = engine._decode.lower(
+            params, cache,
+            jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_device),
+            jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e_device),
+            rng)
+    else:
+        lowered = engine._prefill.lower(
+            params, cache,
+            jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=v5e_device),
+            i32, i32, rng)
+    text = lowered.as_text()
+    assert "ragged_dot" not in text
+    # operands (schedule, rows, matrices) and result of each call of it
+    names = re.findall(r'kernel_name = "grouped_matmul"[^\n]*(tensor<\d+x\d+x'
+                       r'\w+>, tensor<\d+x\d+x\d+x\w+>\) -> tensor<[\dx]+\w+>)',
+                       text)
+    shapes = sorted(set(names))
+    # [m, d] x [E, d, F] (gate and up alike) and [m, F] x [E, F, d]
+    assert len(shapes) == 2, shapes
+    assert [names.count(s) for s in shapes] == [traces, traces], names
+    ours = [n for n in lowerings if "ops/grouped_matmul.py" in n]
+    assert len(ours) == len(shapes), lowerings
