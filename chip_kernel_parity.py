@@ -17,7 +17,15 @@ and T = 5 at random offsets, held to a float32 reference that EXPANDS K and
 V from the rows as stored (no absorption). Run it BEFORE a cell, after any
 change to a kernel (``slotted`` or ``latent`` alone runs that half):
 
-    chiprun --chips 1 -- python3 chip_kernel_parity.py [slotted|latent|gqa [prefill|share]|kda|grouped [sweep]]
+    chiprun --chips 1 -- python3 chip_kernel_parity.py [slotted|latent|gqa [prefill|share]|gqa_uneven|kda|grouped [sweep]]
+
+``gqa_uneven`` (alone; PR 49) is ``ops.gqa_attention`` at the
+``mimo-v2.5.serve-code-agent`` cell's shapes (``gqa_uneven_cases``): K heads
+of 192 beside V heads of 128, 64 query heads on 4 K/V heads over full layers
+``[2, 40, 24576, 768 | 512]`` and on 8 over rings ``[5, 40, 128, 1536 |
+1024]`` with and without a sink, rings wrapped, slots of no row and of one;
+the prefill kernel at 16 query heads a K/V head against the T x T softmax
+at 2,048 tokens and timed at 24,576, and the window band with a sink.
 
 ``grouped`` (alone; PR 48) is ``ops.grouped_matmul``, the experts' grouped
 products of the three mixture-of-experts cells, against ``ragged_dot`` at
@@ -371,6 +379,174 @@ def gqa_cases(only=None):
     return ok & share_cases()
 
 
+def _uneven_reference(q, k, v, layer, n_rows, sink):
+    """float32 attention over the rows as stored (K unpacked), a slot at a
+    time, the sink one more column of the softmax; ``sink`` None: none."""
+    from pytorch_distributed_tpu.ops import gqa_attention
+
+    hi = jax.lax.Precision.HIGHEST
+    depth = k.shape[2]
+    Hq, D = q.shape[1:]
+    Hkv = k.shape[3] // D
+
+    def slot(args):
+        q, k, v, n = args
+        keys = gqa_attention._unpack_keys(k.astype(jnp.float32), D)
+        values = v.astype(jnp.float32).reshape(depth, Hkv, -1)
+        qg = q.astype(jnp.float32).reshape(Hkv, Hq // Hkv, D)
+        scores = jnp.einsum("hgd,rhd->hgr", qg, keys,
+                            precision=hi) * D ** -0.5
+        scores = jnp.where(jnp.arange(depth) < n, scores, -jnp.inf)
+        if sink is not None:
+            scores = jnp.concatenate(
+                [scores, sink.reshape(Hkv, -1, 1)], axis=-1)
+        probs = jnp.nan_to_num(jax.nn.softmax(scores, -1))[..., :depth]
+        return jnp.einsum("hgr,rhd->hgd", probs, values,
+                          precision=hi).reshape(Hq, -1)
+
+    return jax.lax.map(slot, (q, k[layer], v[layer], n_rows))
+
+
+def gqa_uneven_cases():
+    """``ops.gqa_attention`` at MiMo-V2.5's widths (module docstring)."""
+    from pytorch_distributed_tpu.ops import gqa_attention
+
+    ok = True
+    S, Hq, D, Dv = 40, 64, 192, 128
+
+    def some_rows(rng):          # 31 of 40 live, 3k-24k deep, one with a row
+        n = np.where(rng.random(S) < 0.78, np.exp(rng.uniform(
+            np.log(3000), np.log(24576), S)).astype(np.int64), 0)
+        n[:3] = (1, 0, 24576)
+        return n
+
+    def ring_rows(rng):          # wrapped (128), young, empty, one row
+        n = np.minimum(rng.integers(0, 400, S), 128)
+        n[:4] = (1, 0, 128, 77)
+        return n
+
+    cases = [("full_mixed", 2, 24576, 4, some_rows, False),
+             ("ring_mixed", 5, 128, 8, ring_rows, False),
+             ("ring_mixed_sink", 5, 128, 8, ring_rows, True),
+             ("ring_wrapped_sink", 5, 128, 8,
+              lambda rng: np.full(S, 128), True)]
+    for n, (case, layers, depth, Hkv, rows_of, with_sink) in enumerate(cases):
+        rng = np.random.default_rng(490 + n)
+        n_rows = jnp.asarray(rows_of(rng), jnp.int32)
+        kq, kk, kv, ks = jax.random.split(jax.random.key(490 + n), 4)
+        q = jax.random.normal(kq, (S, Hq, D), jnp.bfloat16)
+        stale = jnp.where(jnp.arange(depth)[None, :, None]
+                          < n_rows[:, None, None], 1.0, 30.0
+                          ).astype(jnp.bfloat16)
+        k = jax.random.normal(kk, (layers, S, depth, Hkv * D),
+                              jnp.bfloat16) * stale
+        v = jax.random.normal(kv, (layers, S, depth, Hkv * Dv),
+                              jnp.bfloat16) * stale
+        sink = jax.random.normal(ks, (Hq,)) if with_sink else None
+        layer = layers - 1
+        read = jax.jit(gqa_attention.cached_read,
+                       static_argnames=("kernel",))
+        live = np.asarray(n_rows) > 0
+        ref = np.asarray(jax.jit(_uneven_reference, static_argnums=3)(
+            q, k, v, layer, n_rows, sink))
+        dense = np.asarray(read(q, k, v, layer, n_rows, sink=sink),
+                           np.float32)
+        kern = np.asarray(read(q, k, v, layer, n_rows, sink=sink,
+                               kernel=True), np.float32)
+        line = {
+            "op": "gqa_uneven", "case": case, "rows_held": int(n_rows.sum()),
+            "kernel_vs_reference": float(np.abs(kern - ref).max()),
+            "dense_vs_reference": float(np.abs(dense - ref).max()),
+            "kernel_within_tolerance": bool(
+                np.allclose(kern, ref, rtol=RTOL, atol=ATOL)),
+            "dense_within_tolerance": bool(
+                np.allclose(dense, ref, rtol=RTOL, atol=ATOL)),
+            "idle_slots_zero": bool(not np.abs(kern[~live]).any()),
+            "finite": bool(np.isfinite(kern).all()),
+        }
+        if with_sink:        # and the sink is not nothing
+            none = np.asarray(read(q, k, v, layer, n_rows, kernel=True),
+                              np.float32)
+            line["sink_moves"] = float(np.abs(kern - none).max())
+            ok &= line["sink_moves"] > 2 * line["kernel_vs_reference"]
+        for name, kernel in (("dense_read_ms", False),
+                             ("kernel_read_ms", True)):
+            def all_layers(q, k, v, n_rows, kernel=kernel):
+                return sum(gqa_attention.cached_read(
+                    q, k, v, i, n_rows, sink=sink,
+                    kernel=kernel).astype(jnp.float32)
+                    for i in range(layers))
+            line[name] = _timed(jax.jit(all_layers), q, k, v, n_rows,
+                                calls=20)
+        line["kernel_roofline_pct"] = 100 * (
+            layers * int(n_rows.sum()) * Hkv * (D + Dv) * 2
+            / (line["kernel_read_ms"] * 1e-3) / 819e9)
+        ok &= (line["kernel_within_tolerance"] and line["finite"]
+               and line["idle_slots_zero"])
+        print(json.dumps(line), flush=True)
+        del k, v
+
+    def plain(q, k, v, window, sink):
+        T, G = q.shape[1], q.shape[2] // k.shape[2]
+        hi = jax.lax.Precision.HIGHEST
+        q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+        k, v = jnp.repeat(k, G, axis=2), jnp.repeat(v, G, axis=2)
+        scores = jnp.einsum("bthd,bshd->bhts", q, k, precision=hi) \
+            * D ** -0.5
+        s, p = jnp.arange(T)[None, :], jnp.arange(T)[:, None]
+        seen = (s <= p) & ((s > p - window) if window else True)
+        scores = jnp.where(seen, scores, -jnp.inf)
+        if sink is not None:
+            scores = jnp.concatenate([scores, jnp.broadcast_to(
+                sink[None, :, None, None], scores.shape[:3] + (1,))], -1)
+        probs = jax.nn.softmax(scores, -1)[..., :T]
+        return jnp.einsum("bhts,bshd->bthd", probs, v, precision=hi)
+
+    blocks = (gqa_attention._KERNEL_QUERY_BLOCK,
+              gqa_attention._KERNEL_KEY_BLOCK)
+    sink = jax.random.normal(jax.random.key(49), (Hq,))
+    # (window, K/V heads, sink, kernel, blocks): the full layers' two forms
+    # (and the kernel at 128 positions x 512 keys a step), the band's two
+    for window, Hkv, with_sink, kernel, (bq, bk) in (
+            (None, 4, False, False, blocks), (None, 4, False, True, blocks),
+            (None, 4, False, True, (256, 512)),
+            (128, 8, False, False, blocks), (128, 8, True, False, blocks)):
+        gqa_attention._KERNEL_QUERY_BLOCK = bq
+        gqa_attention._KERNEL_KEY_BLOCK = bk
+        b = sink if with_sink else None
+        attend = jax.jit(functools.partial(
+            gqa_attention.prefill_attention, window=window, kernel=kernel,
+            sink=b))
+        line = {"op": "gqa_uneven_prefill", "window": window,
+                "kv_heads": Hkv, "sink": with_sink, "kernel": kernel}
+        if kernel:
+            line["blocks"] = [gqa_attention._kernel_query_block(Hq // Hkv),
+                              bk]
+        for T in (2048, 8192, 24576):
+            kq, kk, kv = jax.random.split(jax.random.key(T), 3)
+            q = jax.random.normal(kq, (1, T, Hq, D), jnp.bfloat16)
+            k = jax.random.normal(kk, (1, T, Hkv, D), jnp.bfloat16)
+            v = jax.random.normal(kv, (1, T, Hkv, Dv), jnp.bfloat16)
+            out = jax.block_until_ready(attend(q, k, v))
+            if T == 2048:
+                ref = np.asarray(jax.jit(plain, static_argnums=3)(
+                    q, k, v, window, b))
+                got = np.asarray(out, np.float32)
+                line["vs_reference"] = float(np.abs(got - ref).max())
+                line["within_tolerance"] = bool(
+                    np.allclose(got, ref, rtol=RTOL, atol=ATOL))
+                ok &= line["within_tolerance"]
+            line[f"T{T}_ms"] = _timed(attend, q, k, v, calls=5)
+        pairs = (24576 * 128 - 128 * 127 // 2 if window
+                 else 24576 * 24577 // 2)
+        line["T24576_pct_of_bf16_peak"] = 100 * (
+            2.0 * pairs * Hq * (D + Dv) / (line["T24576_ms"] * 1e-3)
+            / 197e12)
+        print(json.dumps(line), flush=True)
+    gqa_attention._KERNEL_QUERY_BLOCK, gqa_attention._KERNEL_KEY_BLOCK = blocks
+    return ok
+
+
 def _timed(fn, *args, calls=10):
     out = jax.block_until_ready(fn(*args))
     t0 = time.perf_counter()
@@ -705,8 +881,9 @@ def main():
                           "kernel's arithmetic exists only on a TPU"}))
         return 1
     which = sys.argv[1] if len(sys.argv) > 1 else "both"
-    if which in ("gqa", "kda", "grouped"):
-        ok = {"gqa": gqa_cases, "kda": kda_cases,
+    if which in ("gqa", "gqa_uneven", "kda", "grouped"):
+        ok = {"gqa": gqa_cases, "gqa_uneven": gqa_uneven_cases,
+              "kda": kda_cases,
               "grouped": grouped_cases}[which](*sys.argv[2:3])
         print(json.dumps({"ok": ok, "device": {
             "platform": device.platform, "kind": device.device_kind}}))

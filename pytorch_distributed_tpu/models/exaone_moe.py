@@ -41,6 +41,21 @@ of the pairs it HOLDS (``ops.dropless_experts.share_rows``: twice the
 expected share, a quarter of the tokens' eight pairs for 16 of 128), so it
 takes chunks of its own, up to ``_EXPERT_CHUNK`` tokens (``ExpertShare``).
 
+ONE BLOCK, CONFIGURED (``ROADMAP.md`` R1). The defaults of
+``ExaoneMoEConfig`` are the equations above; its last group of fields
+states where another family's block differs, and ``mimo_v2``
+(``XiaomiMiMo/MiMo-V2.5``; ``chipbench/references/mimo_v2.py`` writes its
+equations out) sets every one of them: ``norm_first`` (``h + f(RMSNorm(h))``
+in both sublayers, where the default is ``h + RMSNorm(f(h))``), ``qk_norm``
+off, ``v_head_dim`` (V heads narrower than K heads; ``o`` is ``H_q * D_v``
+deep), ``window_key_value_heads`` (a window layer's own H_kv),
+``rotary_dim`` (the first R columns of a head turn, the rest pass),
+``full_rope_theta`` (a full layer turns too, by its own base),
+``value_scale`` (on V before it is stored), ``window_sink`` (a learned
+scalar a query head in the window layers' softmax: ``ops.gqa_attention``),
+``num_shared_experts`` 0. A static branch each: a configuration that sets
+none of them traces the program it traced before they were there.
+
 Not in the served model: the multi-token-prediction layer
 (``num_nextn_predict_layers``), which the main model's logits do not depend
 on. Dtypes: weights and compute ``param_dtype`` / ``dtype`` (bfloat16 when
@@ -51,7 +66,7 @@ the sum over a token's experts in float32.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Tuple
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -107,6 +122,19 @@ class ExaoneMoEConfig:
     held_experts: Tuple[int, int] = (0, 128)
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
+    # -- where another family's block differs (module docstring) ----------
+    norm_first: bool = False
+    qk_norm: bool = True
+    #: None: ``head_dim``
+    v_head_dim: Optional[int] = None
+    #: None: ``num_key_value_heads``
+    window_key_value_heads: Optional[int] = None
+    #: None: all ``head_dim`` columns
+    rotary_dim: Optional[int] = None
+    #: None: a full layer has no positions; ``rope_theta`` is the window's
+    full_rope_theta: Optional[float] = None
+    value_scale: float = 1.0
+    window_sink: bool = False
 
     def __post_init__(self):
         if not (len(self.layer_types) == len(self.mlp_layer_types)
@@ -157,52 +185,92 @@ def _gated_mlp(x, gate, up, down):
     return (jax.nn.silu(x @ gate) * (x @ up)) @ down
 
 
+def _sink_init(key, shape, dtype):
+    """normal(4, 1), not ``initializer_range``: a sink is there to take a
+    visible share of a softmax. Among a full window's 128 scores of random
+    weights (deviation about 1.6, so their exponentials sum to about 490) a
+    sink of 4 holds a tenth and one of 5 a quarter; drawn normal(0, 1) it
+    holds 0.2-0.6%, and a forward WITHOUT the sink then lies nearer the
+    float32 reference than the bfloat16 forward with it does (PERF.md,
+    PR 49)."""
+    return 4.0 + jax.random.normal(key, shape, dtype)
+
+
 class Attention(_Weights):
-    """``h + RMSNorm(a W_o)`` of one layer, ``h [N, d]`` the ``B x T``
-    tokens in a row. Everything but the attention itself is tokenwise and
-    runs in chunks: at 32,768 tokens the queries' float32 copies under the
-    norm and the rotation alone would be 2 GB."""
+    """``h + RMSNorm(a W_o)`` of one layer (``norm_first``: ``h + a W_o``
+    of ``RMSNorm(h)``; ``gain`` is that one norm's), ``h [N, d]`` the ``B x
+    T`` tokens in a row. Everything but the attention itself is tokenwise
+    and runs in chunks: at 32,768 tokens the queries' float32 copies under
+    the norm and the rotation alone would be 2 GB."""
     windowed: bool = False
 
     @nn.compact
-    def __call__(self, h, positions, cache, layer, position_offset, out_gain):
+    def __call__(self, h, positions, cache, layer, position_offset, gain):
         cfg = self.cfg
         B, T = positions.shape
         d = h.shape[-1]
-        Hq, Hkv, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                      cfg.head_dim)
+        Hq, D = cfg.num_attention_heads, cfg.head_dim
+        Hkv = (self.windowed and cfg.window_key_value_heads
+               or cfg.num_key_value_heads)
+        Dv, R = cfg.v_head_dim or D, cfg.rotary_dim or D
         eps = cfg.rms_norm_eps
         w_q, w_k, w_v = (self.w("q", (d, Hq * D)), self.w("k", (d, Hkv * D)),
-                         self.w("v", (d, Hkv * D)))
-        q_gain, k_gain = self.gain("q_norm", D), self.gain("k_norm", D)
-        w_o = self.w("o", (Hq * D, d))
-        inv_freq = gqa_attention.rope_inv_freq(D, cfg.rope_theta)
+                         self.w("v", (d, Hkv * Dv)))
+        q_gain, k_gain = ((self.gain("q_norm", D), self.gain("k_norm", D))
+                          if cfg.qk_norm else (None, None))
+        w_o = self.w("o", (Hq * Dv, d))
+        # a full layer has no positions unless it has a base of its own
+        theta = cfg.rope_theta if self.windowed else cfg.full_rope_theta
+        inv_freq = theta and gqa_attention.rope_inv_freq(R, theta)
+        kwargs = {}
+        if self.windowed and cfg.window_sink:
+            kwargs["sink"] = self.param("sink", _sink_init, (Hq,),
+                                        jnp.float32)
+
+        def heads(x, w, H, g):
+            y = (x @ w).reshape(x.shape[0], H, D)
+            return y if g is None else _rms(y, g, eps)
+
+        def turned(x, at):
+            if R == D:
+                return rotate(x[None], at[None], inv_freq)[0]
+            return jnp.concatenate(
+                [rotate(x[None, ..., :R], at[None], inv_freq)[0], x[..., R:]],
+                axis=-1)
 
         def project(x, at):
-            n = x.shape[0]
-            q = _rms((x @ w_q).reshape(n, Hq, D), q_gain, eps)
-            k = _rms((x @ w_k).reshape(n, Hkv, D), k_gain, eps)
-            if self.windowed:           # a full layer has no positions
-                q = rotate(q[None], at[None], inv_freq)[0]
-                k = rotate(k[None], at[None], inv_freq)[0]
-            return q, k, (x @ w_v).reshape(n, Hkv, D)
+            if cfg.norm_first:
+                x = _rms(x, gain, eps)
+            q, k = heads(x, w_q, Hq, q_gain), heads(x, w_k, Hkv, k_gain)
+            if theta:
+                q, k = turned(q, at), turned(k, at)
+            v = (x @ w_v).reshape(x.shape[0], Hkv, Dv)
+            return q, k, v if cfg.value_scale == 1 else v * cfg.value_scale
 
-        q, k, v = (a.reshape((B, T) + a.shape[1:]) for a in _by_chunks(
-            project, h, positions.reshape(B * T)))
+        def output(y, x):
+            y = y @ w_o
+            return x + (y if cfg.norm_first else _rms(y, gain, eps))
+
+        with jax.named_scope("attn/proj"):
+            q, k, v = (a.reshape((B, T) + a.shape[1:]) for a in _by_chunks(
+                project, h, positions.reshape(B * T)))
         with jax.named_scope("attn/window" if self.windowed else "attn/full"):
             if cache is None:
                 y = gqa_attention.blockwise_attention(
-                    q, k, v,
+                    q, k, v, **kwargs,
                     window=cfg.sliding_window if self.windowed else None)
             else:
-                y, cache = cache.attend(layer, q, k, v, position_offset)
-        return _by_chunks(lambda y, x: x + _rms(y @ w_o, out_gain, eps),
-                          y.reshape(B * T, Hq * D), h), cache
+                y, cache = cache.attend(layer, q, k, v, position_offset,
+                                        **kwargs)
+        with jax.named_scope("attn/proj"):
+            return _by_chunks(output, y.reshape(B * T, Hq * Dv), h), cache
 
 
 class ExpertShare(_Weights):
     """``h + RMSNorm(sum g_i FFN_i(h) + FFN_shared(h))`` over the experts
-    this model holds, ``h [N, d]``. Returns ``(h, (hit, fill, spill))``,
+    this model holds (``norm_first``: the norm on the sublayer's input; no
+    shared expert where ``num_shared_experts`` is 0), ``h [N, d]``. Returns
+    ``(h, (hit, fill, spill))``,
     which ``serving.window_cache.WindowedKVCache.STEP_STATS`` names
     ``experts_hit``, ``experts_fill_pct``, ``experts_spill``: the held
     experts that got a token and the pairs routed to them against the rows
@@ -220,7 +288,7 @@ class ExpertShare(_Weights):
     3.9: PERF.md, PR 41.)"""
 
     @nn.compact
-    def __call__(self, h, out_gain):
+    def __call__(self, h, gain):
         cfg = self.cfg
         d = h.shape[-1]
         E, F = cfg.num_experts, cfg.moe_intermediate_size
@@ -231,15 +299,16 @@ class ExpertShare(_Weights):
         w_gate = self.w("experts_gate", (held, d, F))
         w_up = self.w("experts_up", (held, d, F))
         w_down = self.w("experts_down", (held, F, d))
-        shared = GatedMLPWeights(cfg, width=F * cfg.num_shared_experts,
-                                 name="shared")(d)
+        shared = cfg.num_shared_experts and GatedMLPWeights(
+            cfg, width=F * cfg.num_shared_experts, name="shared")(d)
 
         k = cfg.num_experts_per_tok
         chunk = (_EXPERT_CHUNK
                  if share_rows(_EXPERT_CHUNK * k, held, E) <= _TOKEN_CHUNK * k
                  else _TOKEN_CHUNK)
 
-        def tokens(x):
+        def tokens(h):
+            x = _rms(h, gain, cfg.rms_norm_eps) if cfg.norm_first else h
             with jax.named_scope("moe/route"):
                 experts, gates = held_share(*route_sigmoid_topk(
                     x, router, bias, k, cfg.routed_scaling_factor),
@@ -248,11 +317,13 @@ class ExpertShare(_Weights):
             with jax.named_scope("moe/experts"):
                 y, hit = dropless_experts(x, experts, gates, w_gate, w_up,
                                           w_down, num_experts=E)
-            with jax.named_scope("moe/shared"):
-                y = y + _gated_mlp(x, *shared)
+            if shared:
+                with jax.named_scope("moe/shared"):
+                    y = y + _gated_mlp(x, *shared)
             fill = 100 * pairs // share_rows(experts.size, held, E)
-            return (x + _rms(y, out_gain, cfg.rms_norm_eps), hit, fill,
-                    jnp.maximum(passes - 1, 0))
+            if not cfg.norm_first:
+                y = _rms(y, gain, cfg.rms_norm_eps)
+            return h + y, hit, fill, jnp.maximum(passes - 1, 0)
 
         h, hit, fill, spill = _by_chunks(tokens, h, chunk=chunk)
         return h, (hit.max(), fill.max(), spill.sum())
@@ -302,16 +373,21 @@ class ExaoneMoE(nn.Module):
                 cfg, windowed=cfg.layer_windowed[i], name=f"layer_{i}_attn")(
                     h, positions, kv_cache, i, position_offset,
                     gain(f"layer_{i}_attn_norm"))
-            out_gain = gain(f"layer_{i}_mlp_norm")
+            mlp_gain = gain(f"layer_{i}_mlp_norm")
             if cfg.mlp_layer_types[i] == "dense":
                 mlp = GatedMLPWeights(cfg, width=cfg.intermediate_size,
                                       name=f"layer_{i}_mlp")(d)
+
+                def dense(x):
+                    if cfg.norm_first:
+                        return x + _gated_mlp(_rms(x, mlp_gain, eps), *mlp)
+                    return x + _rms(_gated_mlp(x, *mlp), mlp_gain, eps)
+
                 with jax.named_scope("mlp"):
-                    h = _by_chunks(lambda x: x + _rms(
-                        _gated_mlp(x, *mlp), out_gain, eps), h)
+                    h = _by_chunks(dense, h)
             else:
                 h, layer = ExpertShare(cfg, name=f"layer_{i}_moe")(
-                    h, out_gain)
+                    h, mlp_gain)
                 hit, fill, spill = (hit + layer[0],
                                     jnp.maximum(fill, layer[1]),
                                     spill + layer[2])

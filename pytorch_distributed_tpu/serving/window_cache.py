@@ -11,8 +11,16 @@ minor dimension (``H_kv * D`` wide, not the model's width: 8 K/V heads of
 norm and rotation, so a row carries its position in itself and a ring's
 order does not matter to the softmax.
 
-    full layers    k, v  [L_full, S, max_len, H_kv * D]   row of p at p
-    window layers  k, v  [L_win,  S, window,  H_kv * D]   row of p at p % window
+    full layers    k  [L_full, S, max_len, H_full * D]    row of p at p
+                   v  [L_full, S, max_len, H_full * D_v]
+    window layers  k  [L_win,  S, window,  H_win * D]     p at p % window
+                   v  [L_win,  S, window,  H_win * D_v]
+
+Four widths: a kind of layer has its own number of K/V heads (``H_full``,
+``H_win``: 4 and 8 under 64 query heads) and a V head may be narrower than
+a K head (``D`` 192, ``D_v`` 128: rows of 768 / 512 and 1,536 / 1,024
+columns). How a K head that is not whole lane tiles lies in its row is
+``ops.gqa_attention.pack_keys``'s business; no column is padding.
 
 Held as ``KVCache`` holds rows, five layers x 32 slots x 32,768 rows x 4 KB
 would be 21.5 GB; by kind, one full layer is 4.29 GB and four rings 67 MB.
@@ -80,7 +88,8 @@ class WindowedKVCache(struct.PyTreeNode):
                dtype: Any = None) -> "WindowedKVCache":
         """Zero-filled cache for a config with ``layer_windowed``,
         ``sliding_window``, ``num_key_value_heads``, ``head_dim``,
-        ``n_positions``, ``dtype``."""
+        ``n_positions``, ``dtype`` and, where they are not those two,
+        ``window_key_value_heads`` and ``v_head_dim``."""
         if max_len > cfg.n_positions:
             raise ValueError(
                 f"max_len {max_len} exceeds model n_positions "
@@ -88,15 +97,20 @@ class WindowedKVCache(struct.PyTreeNode):
         if n_slots < 1:
             raise ValueError("n_slots must be >= 1")
         windowed = tuple(cfg.layer_windowed)
-        width = cfg.num_key_value_heads * cfg.head_dim
+        h_full = cfg.num_key_value_heads
+        h_win = getattr(cfg, "window_key_value_heads", None) or h_full
+        d_k = cfg.head_dim
+        d_v = getattr(cfg, "v_head_dim", None) or d_k
         dtype = dtype or cfg.dtype
         n_win = sum(windowed)
-        full = (len(windowed) - n_win, n_slots, max_len, width)
-        ring = (n_win, n_slots, cfg.sliding_window, width)
+        full = (len(windowed) - n_win, n_slots, max_len)
+        ring = (n_win, n_slots, cfg.sliding_window)
         return cls(
             # an array each: a donated tree may not hold one buffer twice
-            k_full=jnp.zeros(full, dtype), v_full=jnp.zeros(full, dtype),
-            k_ring=jnp.zeros(ring, dtype), v_ring=jnp.zeros(ring, dtype),
+            k_full=jnp.zeros(full + (h_full * d_k,), dtype),
+            v_full=jnp.zeros(full + (h_full * d_v,), dtype),
+            k_ring=jnp.zeros(ring + (h_win * d_k,), dtype),
+            v_ring=jnp.zeros(ring + (h_win * d_v,), dtype),
             lengths=jnp.zeros((n_slots,), jnp.int32),
             step_stats=jnp.zeros((len(cls.STEP_STATS),), jnp.int32),
             windowed=windowed)
@@ -122,12 +136,15 @@ class WindowedKVCache(struct.PyTreeNode):
             "a cache of two depths lies whole on one device (ROADMAP: a "
             "tensor-parallel plan over the K/V heads)")
 
-    def attend(self, layer: int, q, k_new, v_new, position_offset):
+    def attend(self, layer: int, q, k_new, v_new, position_offset, *,
+               sink=None):
         """Write the new tokens' K/V rows into ``layer`` and attend:
-        ``(y [B, T, H_q, D], cache)``; ``q [B, T, H_q, D]``, ``k_new, v_new
-        [B, T, H_kv, D]``, batch row b is slot b. ``position_offset=None``
-        is the fresh prefill of ``lengths[b]`` real tokens (nothing read);
-        otherwise one new token a sequence at ``position_offset [B]``."""
+        ``(y [B, T, H_q, D_v], cache)``; ``q [B, T, H_q, D]``, ``k_new [B,
+        T, H_kv, D]``, ``v_new [B, T, H_kv, D_v]`` (the layer's kind's
+        ``H_kv``), batch row b is slot b. ``position_offset=None`` is the
+        fresh prefill of ``lengths[b]`` real tokens (nothing read);
+        otherwise one new token a sequence at ``position_offset [B]``.
+        ``sink [H_q]`` joins the layer's softmax (``ops.gqa_attention``)."""
         B, T, Hq, D = q.shape
         windowed = self.windowed[layer]
         # this layer's place among the layers of its kind
@@ -135,12 +152,13 @@ class WindowedKVCache(struct.PyTreeNode):
         k, v = ((self.k_ring, self.v_ring) if windowed
                 else (self.k_full, self.v_full))
         depth = k.shape[2]
-        k_rows = k_new.reshape(B, T, -1).astype(k.dtype)
+        k_rows = gqa_attention.pack_keys(k_new).astype(k.dtype)
         v_rows = v_new.reshape(B, T, -1).astype(v.dtype)
         if position_offset is None:
             y = gqa_attention.prefill_attention(
                 q, k_new, v_new, window=depth if windowed else None,
-                kernel=gqa_attention.kernel_prefills(q))
+                sink=sink,
+                kernel=gqa_attention.kernel_prefills(q, k_new, v_new))
             if windowed:
                 # ring row r takes the newest real position p = r mod depth
                 r = jnp.arange(depth, dtype=jnp.int32)[None]
@@ -161,8 +179,8 @@ class WindowedKVCache(struct.PyTreeNode):
             k = k.at[at, slots, position_offset % depth].set(k_rows[:, 0])
             v = v.at[at, slots, position_offset % depth].set(v_rows[:, 0])
             y = gqa_attention.cached_read(
-                q[:, 0], k, v, at, position_offset + 1,
-                kernel=gqa_attention.kernel_reads(k, D))[:, None]
+                q[:, 0], k, v, at, position_offset + 1, sink=sink,
+                kernel=gqa_attention.kernel_reads(k, D, v))[:, None]
         if windowed:
             return y, self.replace(k_ring=k, v_ring=v)
         return y, self.replace(k_full=k, v_full=v)
@@ -189,13 +207,18 @@ class WindowedKVCache(struct.PyTreeNode):
         """A fresh one-slot cache whose full layers are ``n_positions``
         deep: what a prompt of ``length`` real tokens is prefilled into
         before ``write_slot`` lands it."""
-        def rows(a, depth):
-            return jnp.zeros((a.shape[0], 1, depth, a.shape[3]), a.dtype)
+        def rows(k, v, depth):
+            """Zeros for one slot of ``k`` and of ``v``: one array where
+            they are of one width (a traced block is donated by no one)."""
+            k_rows = jnp.zeros((k.shape[0], 1, depth, k.shape[3]), k.dtype)
+            if v.shape[3] == k.shape[3]:
+                return k_rows, k_rows
+            return k_rows, jnp.zeros(k_rows.shape[:3] + v.shape[3:], v.dtype)
 
-        full = rows(self.k_full, n_positions)
-        ring = rows(self.k_ring, self.window)
+        k_full, v_full = rows(self.k_full, self.v_full, n_positions)
+        k_ring, v_ring = rows(self.k_ring, self.v_ring, self.window)
         return self.replace(
-            k_full=full, v_full=full, k_ring=ring, v_ring=ring,
+            k_full=k_full, v_full=v_full, k_ring=k_ring, v_ring=v_ring,
             lengths=jnp.full((1,), length, jnp.int32))
 
     def write_slot(self, slot, block: "WindowedKVCache", length

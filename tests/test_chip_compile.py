@@ -735,6 +735,100 @@ def test_hybrid_prefill_bucket_4096_fits_beside_the_resident_state_for_v5e(
     _grouped_kernels(text, layers=7)
 
 
+# -- K and V heads of unequal width: MiMo-V2.5's cell at its real sizes -------
+
+def _mimo_engine(v5e_device, monkeypatch):
+    """The engine of ``mimo-v2.5.serve-code-agent`` (the configuration file
+    as it is: published widths, bfloat16, 7 layers, 16 of 256 experts; the
+    traffic file's slots and positions) over described shapes."""
+    from chipbench import cells
+    from chipbench.families import mimo_v2 as family
+    from pytorch_distributed_tpu.ops import decode_attention
+    from pytorch_distributed_tpu.serving import InferenceEngine
+
+    monkeypatch.setattr(decode_attention, "_platform", lambda: "tpu")
+    cell = cells.resolve(cells.load_benchmark(), "mimo-v2.5.serve-code-agent")
+    model = family.build_model(cell.config)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=v5e_device), tree)
+
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    engine = InferenceEngine(model, params, n_slots=cell.traffic["n_slots"],
+                             max_len=cell.traffic["max_len"])
+    cache = described(jax.eval_shape(engine.init_cache))
+    rng = described(jax.eval_shape(lambda: jax.random.key(0)))
+    return engine, described(params), cache, rng
+
+
+def test_uneven_decode_program_reads_what_the_slots_hold_for_v5e(
+        v5e_device, monkeypatch):
+    """3.43 G parameters, two full layers of 768 | 512-wide rows (5.03 GB at
+    40 slots) and five rings of 1,536 | 1,024 (131 MB) donated: the step
+    keeps under a hundredth of the cache in temporaries, aliases every K/V
+    leaf and the lengths to an output, and reads with seven calls of ONE
+    Mosaic kernel traced once a depth and sink."""
+    from pytorch_distributed_tpu.analysis.ir.hlo import aliased_param_indices
+
+    engine, params, cache, rng = _mimo_engine(v5e_device, monkeypatch)
+    assert _bytes(params) == 6_872_497_408
+    slots = cache.k_full.shape[1]
+    assert cache.k_full.shape == (2, slots, 24576, 768)
+    assert cache.v_full.shape == (2, slots, 24576, 512)
+    assert cache.k_ring.shape == (5, slots, 128, 1536)
+    assert cache.v_ring.shape == (5, slots, 128, 1024)
+    # held as KVCache holds rows, seven layers of 5,120 B would be 35 GB
+    assert _bytes(cache) < 1.001 * slots * (2 * 24576 * 2560 + 5 * 128 * 5120)
+    compiled = engine._decode.lower(
+        params, cache,
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_device),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e_device), rng,
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < _bytes(cache) / 100
+    text = compiled.as_text()
+    first = len(jax.tree_util.tree_leaves(params))
+    assert aliased_param_indices(text) == list(range(first, first + 5))
+    kernels = re.findall(r"[^\n]*gqa_attention_read/pallas_call[^\n]*", text)
+    assert len([k for k in kernels if "tpu_custom_call" in k]) == 7
+    slab = slots * 24576 * 512
+    computations, _ = _computations(text)
+    relayouts = [line for body in computations.values()
+                 for _, opcode, elements, line in body
+                 if opcode in ("copy", "transpose") and elements >= slab]
+    assert not relayouts, relayouts[:3]
+
+
+def test_uneven_prefill_bucket_24576_fits_beside_the_resident_state_for_v5e(
+        v5e_device, monkeypatch):
+    """The longest bucket at the slots the traffic file states: its
+    temporaries beside the weights and the cache leave a gigabyte of the
+    chip free, both full layers attend by the Mosaic kernel at 16 query
+    heads a K/V head and no array of scores has 24,576 x 24,576 elements."""
+    engine, params, cache, rng = _mimo_engine(v5e_device, monkeypatch)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e_device)
+    compiled = engine._prefill.lower(
+        params, cache,
+        jax.ShapeDtypeStruct((1, 24576), jnp.int32, sharding=v5e_device),
+        i32, i32, rng).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    resident = _bytes(params) + _bytes(cache)
+    assert 12.0e9 < resident < 12.1e9
+    assert resident + temp < V5E_BYTES_LIMIT - 1.0e9, (resident, temp)
+    text = compiled.as_text()
+    kernels = re.findall(r"[^\n]*gqa_attention_prefill/pallas_call[^\n]*",
+                         text)
+    assert len([k for k in kernels if "tpu_custom_call" in k]) == 2
+    _grouped_kernels(text, layers=6)
+    computations, _ = _computations(text)
+    assert not [line for body in computations.values()
+                for _, _, elements, line in body
+                if elements >= 24576 * 24576 and "= f32[" in line]
+
+
+
 # -- the experts' grouped products: one kernel, lowered once a shape ----------
 
 #: (the engine of a cell, the prefill bucket lowered beside the decode
@@ -748,6 +842,7 @@ _GROUPED = {
     "xing4": (functools.partial(_xing4_engine, n_layer=2), 2048, 1),
     "exaone": (_exaone_engine, 8192, 2),
     "kimi": (_kimi_engine, 4096, 2),
+    "mimo": (_mimo_engine, 8192, 2),
 }
 
 

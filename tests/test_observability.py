@@ -726,11 +726,15 @@ def test_every_metric_of_the_waits_names_a_reader_and_its_cells():
     bench = cells.load_benchmark()
     serve = ["gpt2-125m.serve-chat", "xing4.0-29b-a4b.serve-docqa",
              "k-exaone-236b-a23b.serve-mixed-len",
-             "kimi-linear-48b-a3b.serve-long-answer"]
+             "kimi-linear-48b-a3b.serve-long-answer",
+             "mimo-v2.5.serve-code-agent"]
+    # cell 8's 8 s of trace hold 9 admissions at 1.12/s, under the tail
+    # metrics' ``min_spans`` of 10: it is not on their lists
+    tails = serve[:4]
     expected = {
         "ttft_wait_prefill_ms_mean": serve, "ttft_wait_decode_ms_mean": serve,
         "ttft_wait_other_ms_mean": serve, "ttft_admit_ms_mean": serve,
-        "ttft_tail_wait_prefill_pct": serve, "ttft_tail_admit_pct": serve,
+        "ttft_tail_wait_prefill_pct": tails, "ttft_tail_admit_pct": tails,
         "prefill_ms_per_ktok": serve, "prefill_real_tokens_pct": serve,
         "decode_dispatch_inputs_ms_p50": serve[:1],
         "decode_dispatch_call_ms_p50": serve[:1]}
