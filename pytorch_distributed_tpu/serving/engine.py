@@ -123,6 +123,17 @@ def _default_buckets(prefill_len: int) -> Tuple[int, ...]:
     return tuple(buckets)
 
 
+def _step_key(rng):
+    """A step's key from its program's last argument, the pair
+    ``InferenceEngine._next_rng`` makes on the host: (the engine's base key,
+    the step's counter). Folded HERE, inside the compiled program, so that
+    no program of its own runs for it between two steps; the base key stays
+    an argument, so a seed is no literal in the program's text. A caller
+    that lowers a program with a lone key of its own (``chipbench/tests/
+    test_serve_chat_fits.py``) has it taken as the step's key."""
+    return jax.random.fold_in(*rng) if isinstance(rng, tuple) else rng
+
+
 def _slot_prefill(apply_fn, params, cache, tokens, slot, prompt_len):
     """Run ``tokens [1, bucket]`` through ``apply_fn`` into one slot of
     ``cache``: ``(logits, cache)`` with ``lengths[slot] = prompt_len``. The
@@ -319,6 +330,7 @@ class InferenceEngine:
             return tok, jax.nn.softmax(filtered, axis=-1)
 
         def prefill_fn(params, cache, tokens, slot, prompt_len, rng):
+            rng = _step_key(rng)
             logits, cache = _slot_prefill(
                 model_apply, params, cache, tokens, slot, prompt_len
             )
@@ -336,6 +348,7 @@ class InferenceEngine:
             pages supply its K/V through the block table. The page pools
             are sequence-agnostic, so unlike the slotted path there is no
             per-slot slice; B=1 comes from viewing one table row."""
+            rng = _step_key(rng)
             row = jax.lax.dynamic_slice_in_dim(
                 cache.block_tables, slot, 1, axis=0
             )
@@ -356,6 +369,7 @@ class InferenceEngine:
             return cache, tok
 
         def decode_fn(params, cache, last_tokens, active, rng):
+            rng = _step_key(rng)
             logits, new_cache = model_apply(
                 params, last_tokens[:, None], deterministic=True,
                 kv_cache=cache, position_offset=cache.lengths,
@@ -406,6 +420,7 @@ class InferenceEngine:
             """Self-drafting: k truncated-layer forwards into the SAME
             cache's scratch positions, then one full verify that rewrites
             every drafted position for all layers."""
+            rng = _step_key(rng)
             base = cache.lengths
             tok = last_tokens
             draft, d_probs = [], []
@@ -432,6 +447,7 @@ class InferenceEngine:
             [prev, last] at positions len-1, len — rewriting an
             already-cached position is idempotent, and after a full accept
             it fills the one position the draft never processed."""
+            rng = _step_key(rng)
             base = cache.lengths
             refeed = jnp.stack([prev_tokens, last_tokens], axis=1)
             dlogits, dcache = draft_apply(
@@ -493,7 +509,8 @@ class InferenceEngine:
                                             sharding=self.cache_sharding)
 
             cache = cache.replace(k=placed(cache.k), v=placed(cache.v))
-        params, rng = shapes_of(self.params), shapes_of(self._rng)
+        params = shapes_of(self.params)
+        rng = (shapes_of(self._rng), jax.ShapeDtypeStruct((), jnp.uint32))
         i32 = jax.ShapeDtypeStruct((), jnp.int32)
         slots = jax.ShapeDtypeStruct((self.n_slots,), jnp.int32)
         active = jax.ShapeDtypeStruct((self.n_slots,), jnp.bool_)
@@ -596,9 +613,12 @@ class InferenceEngine:
             cost = cost + cost_d
         return cost
 
-    def _next_rng(self) -> jax.Array:
+    def _next_rng(self) -> Tuple[jax.Array, np.uint32]:
+        """The last argument of the next step's program: the engine's base
+        key and the step's counter, which the program folds into it
+        (``_step_key``). A host value: nothing runs on the device here."""
         self._rng_calls += 1
-        return jax.random.fold_in(self._rng, self._rng_calls)
+        return self._rng, np.uint32(self._rng_calls)
 
     # -- steps -------------------------------------------------------------
     def prefill_bucket(self, n: int) -> int:
@@ -672,15 +692,13 @@ class InferenceEngine:
                     whole.set_metadata(bucket=padded.shape[1], n_real=n_real)
                     scalars = (slot, n_real) if self.cache_kind != "paged" \
                         else (slot, cached_len, n_real)
-                    tokens = jnp.asarray(padded)
-                    scalars = [jnp.int32(i) for i in scalars]
+                    # typed on the host: a Python int would be a weak type,
+                    # and a second executable beside the described one
+                    scalars = [np.int32(i) for i in scalars]
                     rng = self._next_rng()
                 with span("engine.prefill.dispatch.call"):
                     cache, tok = self._prefill(
-                        self.params, cache, tokens,
-                        *scalars, rng,
-                    )
-                del tokens, scalars, rng  # as in decode: not after the read
+                        self.params, cache, padded, *scalars, rng)
                 dispatch.set_metadata(executables=self._prefill._cache_size())
             with span("engine.prefill.read"):
                 tok = int(tok)  # waits for the device
@@ -693,8 +711,8 @@ class InferenceEngine:
             raise RuntimeError("no separate draft model configured")
         padded, n = self._pad_prompt(prompt)
         return self._draft_prefill(
-            self.draft_params, draft_cache, jnp.asarray(padded),
-            jnp.int32(slot), jnp.int32(n),
+            self.draft_params, draft_cache, padded,
+            np.int32(slot), np.int32(n),
         )
 
     def decode(self, cache: KVCache, last_tokens: np.ndarray,
@@ -705,20 +723,12 @@ class InferenceEngine:
         with span("engine.decode") as whole:
             with span("engine.decode.dispatch") as dispatch:
                 with span("engine.decode.dispatch.inputs"):
-                    last = jnp.asarray(np.asarray(last_tokens, np.int32))
-                    act = jnp.asarray(np.asarray(active, bool))
+                    last = np.asarray(last_tokens, np.int32)
+                    act = np.asarray(active, bool)
                     rng = self._next_rng()
-                call = span("engine.decode.dispatch.call").__enter__()
-                cache, toks = self._decode(
-                    self.params, cache,
-                    last,
-                    act,
-                    rng,
-                )
-                # left BY HAND: a ``with`` moves the call's column, and the
-                # cache's key with it (C19); the inputs go NOW, not after .read
-                call.__exit__(None, None, None)
-                del last, act, rng
+                with span("engine.decode.dispatch.call"):
+                    cache, toks = self._decode(
+                        self.params, cache, last, act, rng)
                 dispatch.set_metadata(executables=self._decode._cache_size())
             with span("engine.decode.read"):
                 toks = np.asarray(toks)  # waits for the device, copies back
@@ -751,9 +761,9 @@ class InferenceEngine:
         with span("engine.decode"):
             with span("engine.decode.dispatch") as dispatch:
                 with span("engine.decode.dispatch.inputs"):
-                    last = jnp.asarray(np.asarray(last_tokens, np.int32))
-                    prev = jnp.asarray(np.asarray(prev_tokens, np.int32))
-                    act = jnp.asarray(np.asarray(active, bool))
+                    last = np.asarray(last_tokens, np.int32)
+                    prev = np.asarray(prev_tokens, np.int32)
+                    act = np.asarray(active, bool)
                     rng = self._next_rng()
                 with span("engine.decode.dispatch.call"):
                     if self.draft_model is None:

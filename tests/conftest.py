@@ -44,3 +44,43 @@ def mesh24():
     from pytorch_distributed_tpu.mesh import init_device_mesh
 
     return init_device_mesh((2, 4), ("dp", "tp"))
+
+
+@pytest.fixture()
+def keys_folded_on_the_host():
+    """``keys_folded_on_the_host(engine)`` makes an ``InferenceEngine`` hand
+    its programs each step's key as it did before the programs folded it
+    themselves: ``fold_in(base key, n)`` run eagerly, for n = 1, 2, ... in
+    call order. The programs take such a lone key as it is, so the engine is
+    the reference for the stream of keys: same seed, same tokens."""
+    def patch(engine):
+        def next_rng():
+            engine._rng_calls += 1
+            return jax.random.fold_in(engine._rng, engine._rng_calls)
+
+        engine._next_rng = next_rng
+        return engine
+
+    return patch
+
+
+@pytest.fixture()
+def served_tokens():
+    """``served_tokens(engine, n_requests, longest_prompt)``: that many
+    requests of mixed lengths through the engine's slots under a scheduler
+    (joins and evictions, every slot reused); each request's tokens by id."""
+    import numpy as np
+
+    from pytorch_distributed_tpu.serving import Request, Scheduler
+
+    def serve(engine, n_requests, longest_prompt):
+        rng = np.random.default_rng(3)
+        sched = Scheduler(engine, emit_events=False)
+        for _ in range(n_requests):
+            sched.submit(Request(
+                prompt=rng.integers(
+                    0, 97, int(rng.integers(2, longest_prompt + 1))),
+                max_new_tokens=int(rng.integers(3, 9))))
+        return {f.request_id: f.tokens for f in sched.run()}
+
+    return serve

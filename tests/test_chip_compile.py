@@ -142,6 +142,14 @@ def test_decode_attention_kernel_compiles_for_v5e(v5e_device, heads, T):
     )
 
 
+def _step_rng():
+    """The shapes of a serving program's last argument, as
+    ``InferenceEngine._next_rng`` makes it: (the engine's base key, the
+    step's counter), which the program folds into the step's key."""
+    return (jax.eval_shape(lambda: jax.random.key(0)),
+            jax.ShapeDtypeStruct((), jnp.uint32))
+
+
 def _computations(hlo_text):
     """``{computation: [(name, opcode, elements, line), ...]}`` of a
     compiled module's text, and the names of the computations that are
@@ -198,7 +206,7 @@ def test_decode_program_writes_the_cache_in_place_for_v5e(v5e_device,
         described(params), cache,
         jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_device),
         jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e_device),
-        described(jax.eval_shape(lambda: jax.random.key(0))),
+        described(_step_rng()),
     ).compile()
 
     slab = slots * max_len * cfg.n_embd
@@ -270,7 +278,7 @@ def _xing4_engine(v5e_device, monkeypatch, n_layer):
         lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
     engine = InferenceEngine(model, params, n_slots=48, max_len=8192)
     cache = described(jax.eval_shape(engine.init_cache))
-    rng = described(jax.eval_shape(lambda: jax.random.key(0)))
+    rng = described(_step_rng())
     return engine, described(params), cache, rng
 
 
@@ -565,7 +573,7 @@ def _exaone_engine(v5e_device, monkeypatch):
     engine = InferenceEngine(model, params, n_slots=cell.traffic["n_slots"],
                              max_len=cell.traffic["max_len"])
     cache = described(jax.eval_shape(engine.init_cache))
-    rng = described(jax.eval_shape(lambda: jax.random.key(0)))
+    rng = described(_step_rng())
     return engine, described(params), cache, rng
 
 
@@ -659,7 +667,7 @@ def _kimi_engine(v5e_device, monkeypatch):
     engine = InferenceEngine(model, params, n_slots=cell.traffic["n_slots"],
                              max_len=cell.traffic["max_len"])
     cache = described(jax.eval_shape(engine.init_cache))
-    rng = described(jax.eval_shape(lambda: jax.random.key(0)))
+    rng = described(_step_rng())
     return engine, described(params), cache, rng
 
 
@@ -760,7 +768,7 @@ def _mimo_engine(v5e_device, monkeypatch):
     engine = InferenceEngine(model, params, n_slots=cell.traffic["n_slots"],
                              max_len=cell.traffic["max_len"])
     cache = described(jax.eval_shape(engine.init_cache))
-    rng = described(jax.eval_shape(lambda: jax.random.key(0)))
+    rng = described(_step_rng())
     return engine, described(params), cache, rng
 
 

@@ -392,7 +392,7 @@ def test_serving_decode_donation_realized():
     last = jnp.zeros((2,), jnp.int32)
     active = jnp.ones((2,), bool)
     lowered = engine._decode.lower(
-        engine.params, cache, last, active, jax.random.key(0)
+        engine.params, cache, last, active, engine._next_rng()
     )
     compiled = lowered.compile()
     paths = [

@@ -335,8 +335,10 @@ class TestSpans:
                    for s in _named(spans, "sched.consume"))
         assert done == len(_named(spans, "sched.evict")) == 7
         # no executable was added while serving: each dispatch says so
-        decodes = _named(spans, "engine.decode.dispatch")
-        assert {s.stats["executables"] for s in decodes} == {1}
+        # (slots and prompt lengths change: their host types trace nothing)
+        for dispatch in ("engine.decode.dispatch", "engine.prefill.dispatch"):
+            assert {s.stats["executables"]
+                    for s in _named(spans, dispatch)} == {1}
 
     def test_queue_wait_counts_from_arrival(self, served):
         """Two slots, seven requests submitted at once: the third waits

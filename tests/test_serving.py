@@ -14,6 +14,7 @@ import sys
 from pathlib import Path
 
 import functools
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -317,6 +318,113 @@ def test_prefill_bucket_parity(tiny):
                                  prefill_len=16, prefill_buckets=buckets)
         _, got = engine_greedy(engine, engine.init_cache(), 0, prompt, 8)
         assert got == oracle, f"buckets {buckets} diverged"
+
+
+# -- a step's inputs are host values, its key is folded in the program -----
+SAMPLED = SamplingParams(temperature=0.9, top_k=20, top_p=0.95)
+
+
+@pytest.mark.parametrize("cache_kind", ["slotted", "paged"])
+def test_sampled_tokens_are_those_of_keys_folded_on_the_host(
+        tiny, cache_kind, keys_folded_on_the_host, served_tokens):
+    """Prefills and decode steps share one counter, and the key a program
+    folds from (base key, counter) is the key ``fold_in`` gives eagerly:
+    with ``temperature > 0`` every token of every request is the one
+    sampled under the eagerly folded stream, and another seed's are not."""
+    model, variables = tiny
+
+    def make(seed):
+        return InferenceEngine(
+            model, variables, n_slots=2, max_len=32, prefill_len=16,
+            sampling=SAMPLED, seed=seed, cache_kind=cache_kind, page_size=4)
+
+    engine = make(11)
+    got = served_tokens(engine, 7, 14)
+    want = served_tokens(keys_folded_on_the_host(make(11)), 7, 14)
+    assert got == want and len(got) == 7
+    assert engine._rng_calls > 7        # prefills AND decode steps counted
+    assert got != served_tokens(make(12), 7, 14)    # they were sampled
+
+
+def test_the_seed_is_no_literal_of_a_program(tiny):
+    """The base key is an ARGUMENT of the decode and prefill programs: two
+    engines that differ in ``seed`` only lower the same text, so a new seed
+    is no cold compile."""
+    model, variables = tiny
+
+    def texts(seed):
+        engine = InferenceEngine(model, variables, n_slots=2, max_len=32,
+                                 prefill_len=8, sampling=SAMPLED, seed=seed)
+        cache = jax.eval_shape(engine.init_cache)
+        decode = engine._decode.lower(
+            engine.params, cache, np.zeros(2, np.int32), np.ones(2, bool),
+            engine._next_rng())
+        prefill = engine._prefill.lower(
+            engine.params, cache, np.zeros((1, 8), np.int32), np.int32(0),
+            np.int32(3), engine._next_rng())
+        return decode.as_text(), prefill.as_text()
+
+    assert texts(1) == texts(2)
+
+
+def test_steps_add_no_executable_after_the_warm_up(tiny):
+    """One executable for decode and one a prefill bucket, whatever the
+    slot, the prompt's length or the types the caller holds (Python ints,
+    lists, int64 arrays): the engine types them on the host, so no weak
+    type traces a second program. These are the counts the ``executables``
+    stat of ``pdt.engine.decode.dispatch`` / ``.prefill.dispatch`` reports."""
+    model, variables = tiny
+    engine = InferenceEngine(model, variables, n_slots=3, max_len=32,
+                             prefill_len=16, sampling=SAMPLED)
+    assert engine.prefill_buckets == (8, 16)
+    cache = engine.init_cache()
+    for slot, n in ((0, 3), (1, 12)):           # the warm-up: each bucket
+        cache, _ = engine.prefill(cache, slot, np.arange(1, n + 1))
+    cache, toks = engine.decode(cache, np.zeros(3, np.int32),
+                                np.array([True, True, False]))
+    assert engine._decode._cache_size() == 1
+    assert engine._prefill._cache_size() == 2
+    for slot, n in ((2, 8), (0, 9), (1, 1), (2, 16), (np.int64(1), 5)):
+        cache, tok = engine.prefill(cache, slot, list(range(1, n + 1)))
+        active = np.arange(3) != slot
+        cache, toks = engine.decode(
+            cache, [int(t) for t in toks], list(map(bool, active)))
+        cache, toks = engine.decode(cache, toks.astype(np.int64), active)
+    assert engine._decode._cache_size() == 1
+    assert engine._prefill._cache_size() == 2
+
+
+@pytest.mark.parametrize("cache_kind", ["slotted", "paged"])
+def test_a_warm_step_launches_its_program_and_nothing_else(tiny, cache_kind):
+    """Between two steps no device program runs for a step's inputs: the
+    only ``jax.Array`` among the arguments of the one compiled call are the
+    weights, the cache and the engine's base key; tokens, mask, scalars and
+    the step's counter are typed NumPy values that the call's own argument
+    path copies (an eager ``jnp.asarray`` / ``jnp.int32`` / ``fold_in`` would
+    each have been a program or a transfer of its own)."""
+    model, variables = tiny
+    engine = InferenceEngine(model, variables, n_slots=2, max_len=32,
+                             prefill_len=8, sampling=SAMPLED,
+                             cache_kind=cache_kind, page_size=4)
+    key, counter = engine._next_rng()
+    assert key is engine._rng and type(counter) is np.uint32
+    sched = Scheduler(engine, emit_events=False)
+    sched.submit(Request(prompt=[5, 17, 3], max_new_tokens=4))
+    sched.step()                                    # warm: both compiled
+    engine._prefill = mock.Mock(wraps=engine._prefill)
+    engine._decode = mock.Mock(wraps=engine._decode)
+    sched.submit(Request(prompt=[9, 44], max_new_tokens=3))
+    sched.run()
+    *_, tokens, active, (base, counter) = engine._decode.call_args.args
+    assert base is engine._rng and type(counter) is np.uint32
+    assert (type(tokens), tokens.dtype) == (np.ndarray, np.int32)
+    assert (type(active), active.dtype) == (np.ndarray, np.bool_)
+    _, _, tokens, *scalars, (base, counter) = engine._prefill.call_args.args
+    assert base is engine._rng and type(counter) is np.uint32
+    assert (type(tokens), tokens.dtype) == (np.ndarray, np.int32)
+    # slot, (start,) n_real
+    assert [type(i) for i in scalars] == [np.int32] * (
+        3 if cache_kind == "paged" else 2)
 
 
 # -- scheduler: continuous batching ----------------------------------------

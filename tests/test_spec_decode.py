@@ -10,6 +10,7 @@ arithmetic, or the scheduler's span consumption breaks the equality.
 """
 
 import functools
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -290,6 +291,50 @@ def test_stochastic_spec_decode_smoke(tiny):
     # last token (position lengths) is not yet — so after consuming
     # `total` tokens past the prefill, lengths = prompt_len + total
     assert int(np.asarray(cache.lengths)[0]) == 3 + total
+
+
+@pytest.mark.parametrize("separate_draft", [False, True])
+def test_sampled_spec_tokens_are_those_of_keys_folded_on_the_host(
+        tiny, tiny_draft, separate_draft, keys_folded_on_the_host,
+        served_tokens):
+    """The speculative programs fold the step's key from (base key, counter)
+    themselves, as decode and prefill do: drafts, acceptances and bonus
+    tokens under ``temperature > 0`` are those of the eagerly folded stream,
+    and the programs' inputs are host values (the only ``jax.Array`` among
+    the arguments are weights, caches and the base key)."""
+    model, variables = tiny
+    draft = dict(draft_model=tiny_draft[0], draft_params=tiny_draft[1]) \
+        if separate_draft else dict(draft_layers=1)
+
+    def make(seed):
+        return InferenceEngine(
+            model, variables, n_slots=2, max_len=48, prefill_len=8,
+            sampling=SamplingParams(temperature=0.9, top_k=20, top_p=0.95),
+            spec_k=2, seed=seed, **draft)
+
+    def churn(engine):
+        return served_tokens(engine, 5, 7)
+
+    engine = make(5)
+    got = churn(engine)
+    assert got == churn(keys_folded_on_the_host(make(5))) and len(got) == 5
+    assert got != churn(make(6))
+    assert engine._spec._cache_size() == 1
+
+    engine._spec = mock.Mock(wraps=engine._spec)
+    if separate_draft:
+        engine._draft_prefill = mock.Mock(wraps=engine._draft_prefill)
+    churn(engine)
+    n_device = 4 if separate_draft else 2       # weights and caches
+    *host, (base, counter) = engine._spec.call_args.args[n_device:]
+    assert base is engine._rng and type(counter) is np.uint32
+    assert [(type(a), a.dtype) for a in host] == [
+        (np.ndarray, np.int32)] * (len(host) - 1) + [(np.ndarray, np.bool_)]
+    assert len(host) == (3 if separate_draft else 2)    # last, (prev,) active
+    if separate_draft:
+        tokens, slot, n = engine._draft_prefill.call_args.args[2:]
+        assert (type(tokens), tokens.dtype) == (np.ndarray, np.int32)
+        assert (type(slot), type(n)) == (np.int32, np.int32)
 
 
 # -- scheduler integration -------------------------------------------------
