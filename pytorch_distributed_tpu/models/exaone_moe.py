@@ -40,6 +40,14 @@ float32 copy of the queries 1 GB. An expert sublayer's arrays are the rows
 of the pairs it HOLDS (``ops.dropless_experts.share_rows``: twice the
 expected share, a quarter of the tokens' eight pairs for 16 of 128), so it
 takes chunks of its own, up to ``_EXPERT_CHUNK`` tokens (``ExpertShare``).
+A fresh prefill of ONE padded prompt (the engine's) runs those loops only
+as far as the chunk that holds the prompt's last real token
+(``fresh_prompt_len``, ``computed_tokens``: the bound is a value the
+one-slot cache carries, so a bucket is still one program): the positions
+after it are ZEROS in q, k, v and in every sublayer's output, finite
+wherever a dense read of the cache multiplies them by a masked zero, and no
+real token's arithmetic changes, since it attends earlier positions only
+and its own chunk is computed whole.
 
 ONE BLOCK, CONFIGURED (``ROADMAP.md`` R1). The defaults of
 ``ExaoneMoEConfig`` are the equations above; its last group of fields
@@ -154,17 +162,50 @@ class ExaoneMoEConfig:
         return tuple(t == "sliding_attention" for t in self.layer_types)
 
 
-def _by_chunks(fn, *xs, chunk=_TOKEN_CHUNK):
+def computed_tokens(n, n_real=None, chunk=None):
+    """The positions, of ``n`` in a row of which the first ``n_real`` hold
+    real tokens, that a loop over chunks of ``chunk`` (``_TOKEN_CHUNK``)
+    runs: the chunks up to the one with the last real token where it loops
+    (more than one chunk, whole chunks), all ``n`` where it does not or
+    ``n_real`` is None. ``n_real`` a traced scalar or a host's integer: the
+    loop's bound (``_by_chunks``) and the count the engine's
+    ``engine.prefill`` span carries (``ExaoneMoE.prefill_computed``) are
+    this one expression."""
+    chunk = chunk or _TOKEN_CHUNK
+    if n_real is None or n <= chunk or n % chunk:
+        return n
+    return -(-n_real // chunk) * chunk
+
+
+def fresh_prompt_len(kv_cache, position_offset, B):
+    """The real length of the ONE prompt a fresh prefill runs (``kv_cache``
+    a one-slot cache carrying it, no ``position_offset``: ``serving.engine.
+    _slot_prefill``), a traced int32 scalar; None for every other forward
+    (no cache, a decode or verify step, several sequences), whose loops
+    over chunks run to their static end."""
+    if kv_cache is None or position_offset is not None or B != 1:
+        return None
+    return kv_cache.lengths[0]
+
+
+def _by_chunks(fn, *xs, chunk=None, n_real=None):
     """``fn(*xs)`` over the tokens (leading axis) of the arrays ``xs``,
-    ``chunk`` at a time where there are more (one program, run in a loop):
-    what ``fn`` gives per token comes back whole, what it gives per call (a
-    scalar) as a vector over the calls."""
+    ``chunk`` (``_TOKEN_CHUNK``) at a time where there are more (one
+    program, run in a loop): what ``fn`` gives per token comes back whole,
+    what it gives per call (a scalar) as a vector over the calls. Of rows
+    whose first ``n_real`` alone hold real tokens (``fresh_prompt_len``)
+    the loop ends with the last real token's chunk
+    (``computed_tokens``): the chunks after it are not run and read zero,
+    per token and per call."""
+    chunk = chunk or _TOKEN_CHUNK
     n = xs[0].shape[0]
     if n <= chunk or n % chunk:
         return jax.tree_util.tree_map(
             lambda a: a if a.ndim else a[None], fn(*xs))
-    out = jax.lax.map(lambda part: fn(*part), tuple(
-        x.reshape((n // chunk, chunk) + x.shape[1:]) for x in xs))
+    out = gqa_attention.map_upto(
+        lambda part: fn(*part),
+        tuple(x.reshape((n // chunk, chunk) + x.shape[1:]) for x in xs),
+        computed_tokens(n, n_real, chunk) // chunk)
     return jax.tree_util.tree_map(
         lambda a: a.reshape((n,) + a.shape[2:]) if a.ndim > 1 else a, out)
 
@@ -205,7 +246,8 @@ class Attention(_Weights):
     windowed: bool = False
 
     @nn.compact
-    def __call__(self, h, positions, cache, layer, position_offset, gain):
+    def __call__(self, h, positions, cache, layer, position_offset, gain,
+                 n_real=None):
         cfg = self.cfg
         B, T = positions.shape
         d = h.shape[-1]
@@ -253,7 +295,7 @@ class Attention(_Weights):
 
         with jax.named_scope("attn/proj"):
             q, k, v = (a.reshape((B, T) + a.shape[1:]) for a in _by_chunks(
-                project, h, positions.reshape(B * T)))
+                project, h, positions.reshape(B * T), n_real=n_real))
         with jax.named_scope("attn/window" if self.windowed else "attn/full"):
             if cache is None:
                 y = gqa_attention.blockwise_attention(
@@ -263,7 +305,8 @@ class Attention(_Weights):
                 y, cache = cache.attend(layer, q, k, v, position_offset,
                                         **kwargs)
         with jax.named_scope("attn/proj"):
-            return _by_chunks(output, y.reshape(B * T, Hq * Dv), h), cache
+            return _by_chunks(output, y.reshape(B * T, Hq * Dv), h,
+                              n_real=n_real), cache
 
 
 class ExpertShare(_Weights):
@@ -288,9 +331,9 @@ class ExpertShare(_Weights):
     3.9: PERF.md, PR 41.)"""
 
     @nn.compact
-    def __call__(self, h, gain):
+    def __call__(self, h, gain, n_real=None):
         cfg = self.cfg
-        d = h.shape[-1]
+        n, d = h.shape
         E, F = cfg.num_experts, cfg.moe_intermediate_size
         first, held = cfg.held_experts
         router = self.w("router", (d, E), jnp.float32)
@@ -307,12 +350,17 @@ class ExpertShare(_Weights):
                  if share_rows(_EXPERT_CHUNK * k, held, E) <= _TOKEN_CHUNK * k
                  else _TOKEN_CHUNK)
 
-        def tokens(h):
+        def tokens(h, computed=None):
             x = _rms(h, gain, cfg.rms_norm_eps) if cfg.norm_first else h
             with jax.named_scope("moe/route"):
                 experts, gates = held_share(*route_sigmoid_topk(
                     x, router, bias, k, cfg.routed_scaling_factor),
                     first, held)
+                if computed is not None:
+                    # zeros all route alike: they would crowd one expert's
+                    # group and fill passes of their own; no one's pairs
+                    experts = jnp.where(computed[:, None], experts, held)
+                    gates = jnp.where(computed[:, None], gates, 0.0)
                 pairs, passes = share_passes(experts, held, E)
             with jax.named_scope("moe/experts"):
                 y, hit = dropless_experts(x, experts, gates, w_gate, w_up,
@@ -325,7 +373,14 @@ class ExpertShare(_Weights):
                 y = _rms(y, gain, cfg.rms_norm_eps)
             return h + y, hit, fill, jnp.maximum(passes - 1, 0)
 
-        h, hit, fill, spill = _by_chunks(tokens, h, chunk=chunk)
+        xs = (h,)
+        computed = computed_tokens(n, n_real)
+        if chunk > _TOKEN_CHUNK and not isinstance(computed, int):
+            # a chunk here may reach past the last chunk the tokenwise
+            # loops ran, into rows they left zero
+            xs += (jnp.arange(n) < computed,)
+        h, hit, fill, spill = _by_chunks(tokens, *xs, chunk=chunk,
+                                         n_real=n_real)
         return h, (hit.max(), fill.max(), spill.sum())
 
 
@@ -342,6 +397,11 @@ class ExaoneMoE(nn.Module):
         )
 
         return WindowedKVCache
+
+    #: ``(bucket, n_real)`` -> the positions a fresh prefill's tokenwise
+    #: loops run (``serving.engine``: the ``engine.prefill`` span's
+    #: ``n_computed``)
+    prefill_computed = staticmethod(computed_tokens)
 
     @nn.compact
     def __call__(self, tokens, deterministic: bool = True, *, kv_cache=None,
@@ -368,11 +428,12 @@ class ExaoneMoE(nn.Module):
                                cfg.param_dtype)
             h = embed[tokens.reshape(B * T)].astype(cfg.dtype)
         hit = fill = spill = jnp.zeros((), jnp.int32)
+        n_real = fresh_prompt_len(kv_cache, position_offset, B)
         for i in range(cfg.n_layer):
             h, kv_cache = Attention(
                 cfg, windowed=cfg.layer_windowed[i], name=f"layer_{i}_attn")(
                     h, positions, kv_cache, i, position_offset,
-                    gain(f"layer_{i}_attn_norm"))
+                    gain(f"layer_{i}_attn_norm"), n_real)
             mlp_gain = gain(f"layer_{i}_mlp_norm")
             if cfg.mlp_layer_types[i] == "dense":
                 mlp = GatedMLPWeights(cfg, width=cfg.intermediate_size,
@@ -384,10 +445,10 @@ class ExaoneMoE(nn.Module):
                     return x + _rms(_gated_mlp(x, *mlp), mlp_gain, eps)
 
                 with jax.named_scope("mlp"):
-                    h = _by_chunks(dense, h)
+                    h = _by_chunks(dense, h, n_real=n_real)
             else:
                 h, layer = ExpertShare(cfg, name=f"layer_{i}_moe")(
-                    h, mlp_gain)
+                    h, mlp_gain, n_real)
                 hit, fill, spill = (hit + layer[0],
                                     jnp.maximum(fill, layer[1]),
                                     spill + layer[2])

@@ -60,6 +60,8 @@ from pytorch_distributed_tpu.models.exaone_moe import (
     GatedMLPWeights,
     _by_chunks,
     _gated_mlp,
+    computed_tokens,
+    fresh_prompt_len,
 )
 from pytorch_distributed_tpu.models.xing4 import _rms, _Weights
 from pytorch_distributed_tpu.ops import kda
@@ -226,7 +228,7 @@ class ExpertShare(_Weights):
     chosen as there."""
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, n_real=None):
         cfg = self.cfg
         d = x.shape[-1]
         E, F = cfg.num_experts, cfg.moe_intermediate_size
@@ -257,7 +259,8 @@ class ExpertShare(_Weights):
             fill = 100 * pairs // share_rows(experts.size, held, E)
             return y, hit, fill, jnp.maximum(passes - 1, 0)
 
-        y, hit, fill, spill = _by_chunks(tokens, x, chunk=chunk)
+        y, hit, fill, spill = _by_chunks(tokens, x, chunk=chunk,
+                                         n_real=n_real)
         return y, (hit.max(), fill.max(), spill.sum())
 
 
@@ -274,6 +277,9 @@ class KimiLinear(nn.Module):
         )
 
         return HybridStateCache
+
+    #: as ``models.exaone_moe.ExaoneMoE.prefill_computed``
+    prefill_computed = staticmethod(computed_tokens)
 
     @nn.compact
     def __call__(self, tokens, deterministic: bool = True, *, kv_cache=None,
@@ -296,6 +302,7 @@ class KimiLinear(nn.Module):
                                cfg.param_dtype)
             h = embed[tokens].astype(cfg.dtype)
         hit = fill = spill = jnp.zeros((), jnp.int32)
+        n_real = fresh_prompt_len(kv_cache, position_offset, B)
         for i in range(cfg.n_layer):
             x = _rms(h, gain(f"layer_{i}_attn_norm"), eps)
             if cfg.layer_recurrent[i]:
@@ -314,9 +321,11 @@ class KimiLinear(nn.Module):
                 mlp = GatedMLPWeights(cfg, width=cfg.intermediate_size,
                                       name=f"layer_{i}_mlp")(d)
                 with jax.named_scope("mlp"):
-                    y = _by_chunks(lambda x: _gated_mlp(x, *mlp), x)
+                    y = _by_chunks(lambda x: _gated_mlp(x, *mlp), x,
+                                   n_real=n_real)
             else:
-                y, layer = ExpertShare(cfg, name=f"layer_{i}_moe")(x)
+                y, layer = ExpertShare(cfg, name=f"layer_{i}_moe")(
+                    x, n_real)
                 hit, fill, spill = (hit + layer[0],
                                     jnp.maximum(fill, layer[1]),
                                     spill + layer[2])

@@ -68,7 +68,8 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["blockwise_attention", "prefill_attention", "cached_read",
-           "kernel_reads", "kernel_prefills", "rope_inv_freq", "pack_keys"]
+           "kernel_reads", "kernel_prefills", "rope_inv_freq", "pack_keys",
+           "map_upto"]
 
 #: queries of a full layer attended at a time, and the keys of one step of
 #: their running softmax: 8 K/V heads x 8,192 x 1,024 float32 scores, 268 MB
@@ -133,6 +134,30 @@ def _unpack_keys(rows: jax.Array, D: int) -> jax.Array:
 # -------------------------------------------------------------------------
 # Prefill: queries in blocks, scores never T x T
 # -------------------------------------------------------------------------
+def map_upto(fn, xs, upto=None):
+    """``jax.lax.map(fn, xs)`` over the first ``upto`` entries of the
+    leading axis only (a traced int32 scalar; None: all of them), ZEROS in
+    the place of the others' results. One loop whose bound is a value, not
+    a shape: a padded prompt's blocks past its last real token cost nothing
+    and no program is compiled for the length. The results are the carry,
+    each written in place at its index."""
+    n = jax.tree_util.tree_leaves(xs)[0].shape[0]
+
+    def entry(i):
+        return jax.tree_util.tree_map(
+            lambda x: jax.lax.dynamic_index_in_dim(x, i, keepdims=False), xs)
+
+    def step(i, out):
+        return jax.tree_util.tree_map(
+            lambda o, a: jax.lax.dynamic_update_index_in_dim(o, a, i, 0),
+            out, fn(entry(i)))
+
+    out = jax.tree_util.tree_map(
+        lambda a: jnp.zeros((n,) + a.shape, a.dtype),
+        jax.eval_shape(fn, entry(0)))
+    return jax.lax.fori_loop(0, n if upto is None else upto, step, out)
+
+
 def _softmax_pv(scores, visible, v, dtype, sink=None):
     """One block's masked float32 softmax times ``v``, normalised after the
     product: ``scores [B, H, G, q, s]``, ``v [B, H, s, D_v]``, ``sink [H,
@@ -151,11 +176,14 @@ def _softmax_pv(scores, visible, v, dtype, sink=None):
 
 def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         window: Optional[int] = None,
-                        sink: Optional[jax.Array] = None) -> jax.Array:
+                        sink: Optional[jax.Array] = None,
+                        n_real: Optional[jax.Array] = None) -> jax.Array:
     """Causal attention among T tokens at positions ``0..T-1``: ``q [B, T,
     H_q, D]``, ``k [B, T, H_kv, D]``, ``v [B, T, H_kv, D_v]`` -> ``[B, T,
     H_q, D_v]`` in q's dtype. ``window``: a query sees its last ``window``
-    positions only. ``sink [H_q]``: module docstring."""
+    positions only. ``sink [H_q]``: module docstring. ``n_real`` (a traced
+    int32 scalar): only the first ``n_real`` positions hold real tokens;
+    the query blocks past them are not attended and give zeros."""
     B, T, Hq, D = q.shape
     Hkv, Dv = v.shape[2:]
     G = Hq // Hkv
@@ -232,8 +260,8 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             return (acc / l).astype(dtype)
 
     blocks = jnp.arange(n_blocks, dtype=jnp.int32)
-    out = one((blocks[0], q[0]))[None] if n_blocks == 1 else jax.lax.map(
-        one, (blocks, q))
+    out = one((blocks[0], q[0]))[None] if n_blocks == 1 else map_upto(
+        one, (blocks, q), None if n_real is None else -(-n_real // block))
     # [n_blocks, B, H_kv, G, block, D_v] -> [B, T, H_q, D_v]
     out = out.transpose(1, 0, 4, 2, 3, 5).reshape(B, T + pad, Hq, Dv)
     return out[:, :T]
@@ -266,15 +294,18 @@ def kernel_prefills(q: jax.Array, k: Optional[jax.Array] = None,
 
 def prefill_attention(q, k, v, *, window: Optional[int] = None,
                       sink: Optional[jax.Array] = None,
+                      n_real: Optional[jax.Array] = None,
                       kernel: bool = False, interpret: bool = False):
     """``blockwise_attention``; a full layer's by the Pallas kernel where
     ``kernel`` (the caller has asked ``kernel_prefills``; ``interpret`` runs
-    it in the Pallas interpreter). A window layer's band stays in
+    it in the Pallas interpreter; its grid is the causal half of all T
+    positions whatever ``n_real``). A window layer's band stays in
     ``jax.numpy``: its scores are small enough to cost little in memory,
     and a kernel walking the band pair by pair was slower (14.2 against
     8.0 ms at 32,768 tokens: my chip run, PR 40)."""
     if window or sink is not None or not kernel:
-        return blockwise_attention(q, k, v, window=window, sink=sink)
+        return blockwise_attention(q, k, v, window=window, sink=sink,
+                                   n_real=n_real)
     B, T, Hq, D = q.shape
     Hkv, Dv = v.shape[2:]
     G = Hq // Hkv

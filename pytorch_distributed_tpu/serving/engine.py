@@ -689,7 +689,7 @@ class InferenceEngine:
             with span("engine.prefill.dispatch") as dispatch:
                 with span("engine.prefill.dispatch.inputs"):
                     padded, n_real = self._pad_prompt(prompt[cached_len:])
-                    whole.set_metadata(bucket=padded.shape[1], n_real=n_real)
+                    whole.set_metadata(**self._counts(padded.shape[1], n_real))
                     scalars = (slot, n_real) if self.cache_kind != "paged" \
                         else (slot, cached_len, n_real)
                     # typed on the host: a Python int would be a weak type,
@@ -782,6 +782,20 @@ class InferenceEngine:
                 out = (np.asarray(emitted), np.asarray(counts),
                        np.asarray(prev_next))
         return (cache, dcache, *out)
+
+    def _counts(self, bucket: int, n_real: int) -> dict:
+        """What the ``engine.prefill`` span says of a prompt of ``n_real``
+        tokens padded to ``bucket``: both, and ``n_computed``, the
+        positions the model's tokenwise loops run for it. A model whose
+        loops end with the prompt's last real token says how far they go
+        (``model.prefill_computed``, the expression that bounds the loop
+        itself: ``models.exaone_moe.computed_tokens``); any other computes
+        the bucket. (Down here, below every function that a compiled
+        kernel's recorded call stack passes through: a line added above
+        them moves every serving program's compile-cache key.)"""
+        computed = getattr(self.model, "prefill_computed", None)
+        return dict(bucket=bucket, n_real=n_real, n_computed=int(
+            computed(bucket, n_real)) if computed else bucket)
 
 
 def _slotted_cache_class(model, cache_kind, cache_sharding, spec_k):

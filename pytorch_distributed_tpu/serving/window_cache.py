@@ -157,7 +157,7 @@ class WindowedKVCache(struct.PyTreeNode):
         if position_offset is None:
             y = gqa_attention.prefill_attention(
                 q, k_new, v_new, window=depth if windowed else None,
-                sink=sink,
+                sink=sink, n_real=self.lengths[0] if B == 1 else None,
                 kernel=gqa_attention.kernel_prefills(q, k_new, v_new))
             if windowed:
                 # ring row r takes the newest real position p = r mod depth

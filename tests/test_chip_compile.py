@@ -840,17 +840,20 @@ def test_uneven_prefill_bucket_24576_fits_beside_the_resident_state_for_v5e(
 # -- the experts' grouped products: one kernel, lowered once a shape ----------
 
 #: (the engine of a cell, the prefill bucket lowered beside the decode
-#: program, the traces of the experts' function a program holds: a holder of
-#: a share multiplies its first pass in line and the later ones in a loop's
-#: body, and JAX lowers a jitted function called from both places twice (it
-#: rewrites an in-line call's jaxpr when it prunes a program's unused
-#: inputs and not one inside a loop, so its cache of lowered functions sees
-#: two), copying the ONE lowered kernel into each)
+#: program, the traces of the experts' function the decode and the prefill
+#: program hold: a holder of a share multiplies its first pass in line and
+#: the later ones in a loop's body, and JAX lowers a jitted function called
+#: from both places twice (it rewrites an in-line call's jaxpr when it
+#: prunes a program's unused inputs and not one inside a loop, so its cache
+#: of lowered functions sees two), copying the ONE lowered kernel into each.
+#: A bucket of several chunks has both calls inside the loop over chunks,
+#: whose bound is a value since PR 51 (a ``while``, which nothing prunes):
+#: one trace)
 _GROUPED = {
-    "xing4": (functools.partial(_xing4_engine, n_layer=2), 2048, 1),
-    "exaone": (_exaone_engine, 8192, 2),
-    "kimi": (_kimi_engine, 4096, 2),
-    "mimo": (_mimo_engine, 8192, 2),
+    "xing4": (functools.partial(_xing4_engine, n_layer=2), 2048, (1, 1)),
+    "exaone": (_exaone_engine, 8192, (2, 1)),
+    "kimi": (_kimi_engine, 4096, (2, 2)),
+    "mimo": (_mimo_engine, 8192, (2, 1)),
 }
 
 
@@ -886,6 +889,7 @@ def test_grouped_products_lower_one_kernel_a_shape_for_v5e(
     edit lets the call sites multiply, not on the chip as a set-up 5 s
     longer (PR 47)."""
     build, bucket, traces = _GROUPED[family]
+    traces = traces[program == "prefill"]
     engine, params, cache, rng = build(v5e_device, monkeypatch)
     lowerings = _mosaic_lowerings(monkeypatch)
     i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e_device)

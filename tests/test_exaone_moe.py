@@ -40,6 +40,7 @@ from pytorch_distributed_tpu.serving import (
     Scheduler,
     WindowedKVCache,
 )
+from tests import _real_chunks
 
 TOL = 1e-4
 WINDOW = 8
@@ -456,6 +457,91 @@ def test_a_prompt_in_chunks_is_the_reference(served, monkeypatch):
     assert (stats["experts_spill"] > 0) == (stats["experts_fill_pct"] > 100)
 
 
+class _Spans:
+    """``serving.engine.span`` replaced: what each span was told."""
+
+    def __init__(self):
+        self.seen = {}
+
+    def __call__(self, name, **stats):
+        seen = self.seen.setdefault(name, {})
+        seen.update(stats)
+
+        class Span:
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *a):
+                return False
+
+            def set_metadata(self, **stats):
+                seen.update(stats)
+
+        return Span()
+
+
+@_real_chunks.CASES
+def test_a_prefill_ends_at_the_last_real_token(served, n_real):
+    """``tests/_real_chunks.py``; and what no loop ran is zero: the K rows
+    of the full layer from the first chunk without a real token on."""
+    block = _real_chunks.check_a_prefill_ends_at_the_last_real_token(
+        served[0], n_real)
+    ran = -(-n_real // _real_chunks.CHUNK) * _real_chunks.CHUNK
+    rows = np.abs(np.asarray(block.k_full[0, 0])).max(axis=-1)
+    assert (rows[:ran] > 0).all() and (rows[ran:] == 0).all()
+
+
+def test_rows_that_no_chunk_computed_are_no_ones_pairs():
+    """An expert sublayer's chunk of 16 reaches past the one tokenwise
+    chunk of 8 that ran, into rows left zero. Zeros all choose experts
+    0..3, and a holder of THOSE (the cells hold from 0) would find the
+    eight rows' 32 pairs crowding its buffer of 32 into a second pass: they
+    are taken out of the routing, and the real tokens' sums stay in the
+    order of one pass."""
+    model = family.build_model(CONFIG | dict(held_experts_first=0))
+    block = _real_chunks.check_a_prefill_ends_at_the_last_real_token(model, 8)
+    stats = dict(zip(block.STEP_STATS, block.step_stats.tolist()))
+    assert stats["experts_spill"] == 0 and stats["experts_fill_pct"] <= 100
+
+
+@pytest.mark.parametrize("which,n_real,bucket,computed", [
+    ("looped", 9, 32, 16), ("looped", 32, 32, 32), ("one_chunk", 3, 8, 8),
+    ("gpt2", 9, 32, 32)], ids=lambda v: str(v))
+def test_the_prefill_span_counts_what_the_loops_ran(
+        served, monkeypatch, which, n_real, bucket, computed):
+    """``n_computed`` on ``pdt.engine.prefill`` is the loop's own trip
+    count times the chunk: the rows the prefill left in the slot's full
+    layer are zero exactly where no chunk ran (the loop's carry starts as
+    zeros). A bucket of one chunk has no loop, GPT-2 no chunks: both
+    compute the bucket."""
+    from pytorch_distributed_tpu.serving import engine as engine_module
+
+    spans = _Spans()
+    monkeypatch.setattr(engine_module, "span", spans)
+    if which == "gpt2":
+        from pytorch_distributed_tpu.models.gpt2 import GPT2, GPT2Config
+
+        model = GPT2(GPT2Config(vocab_size=97, n_positions=64, n_embd=48,
+                                n_layer=2, n_head=4, dtype=jnp.float32))
+        engine = InferenceEngine(
+            model, model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)),
+            n_slots=2, max_len=64, prefill_buckets=(bucket,))
+    elif which == "one_chunk":
+        engine = InferenceEngine(*served, n_slots=2, max_len=16,
+                                 prefill_buckets=(bucket,))
+    else:
+        engine = _real_chunks.programs(served[0])[0]
+    with pytest.MonkeyPatch.context() as patch:
+        _real_chunks.small_sizes(patch)     # the host's expression reads it
+        cache, _ = engine.prefill(engine.init_cache(), 1,
+                                  _real_chunks.prompt_of(n_real))
+    stats = spans.seen["engine.prefill"]
+    assert (stats["bucket"], stats["n_real"]) == (bucket, n_real)
+    k = cache.k_full[0, 1] if which != "gpt2" else cache.k[0, 1]
+    ran = int((np.abs(np.asarray(k)).max(axis=-1) > 0).sum())
+    assert stats["n_computed"] == ran == computed
+
+
 # -- the engine and the scheduler ---------------------------------------------
 
 def test_a_mixed_length_trace_through_the_scheduler_is_the_references(served):
@@ -504,29 +590,15 @@ def test_decode_span_carries_the_steps_counts(served, monkeypatch):
     from pytorch_distributed_tpu.serving import engine as engine_module
 
     model, variables = served
-    seen = {}
-
-    class Span:
-        def __init__(self, name, **stats):
-            self.name = name
-
-        def __enter__(self):
-            return self
-
-        def __exit__(self, *a):
-            return False
-
-        def set_metadata(self, **stats):
-            seen.setdefault(self.name, {}).update(stats)
-
-    monkeypatch.setattr(engine_module, "span", Span)
+    spans = _Spans()
+    monkeypatch.setattr(engine_module, "span", spans)
     engine = InferenceEngine(model, variables, n_slots=2, max_len=32)
     cache = engine.init_cache()
     cache, tok = engine.prefill(cache, 0, _tokens(3, 11))
     cache, toks = engine.decode(cache, np.array([tok, 0], np.int32),
                                 np.array([True, False]))
     assert toks.shape == (2,)
-    stats = seen["engine.decode"]
+    stats = spans.seen["engine.decode"]
     # four expert layers, four held of sixteen, one token of four choices
     assert 0 <= stats["experts_hit"] <= 16
     # at most its four choices are held; one buffer holds them in one pass

@@ -48,6 +48,7 @@ from pytorch_distributed_tpu.serving import (
     Request,
     Scheduler,
 )
+from tests import _real_chunks
 
 TOL = 1e-4
 STATE_TOL = 1e-5
@@ -435,6 +436,15 @@ def test_an_mla_layer_takes_no_positions(served):
             variables["params"][f"layer_{layer}_attn"], x[0], sizes,
             round_to=None, rotate_mla=False)
     assert float(jnp.abs(plain[0] - want).max()) < TOL
+
+
+@_real_chunks.CASES
+def test_a_prefill_ends_at_the_last_real_token(served, n_real):
+    """``tests/_real_chunks.py``: the dense MLP's and the experts' chunks
+    past the last real token are not run; every layer's state after the
+    last REAL token, the convolutions' tails and the latent rows below it
+    are what they are when every chunk runs."""
+    _real_chunks.check_a_prefill_ends_at_the_last_real_token(served[0], n_real)
 
 
 # -- the engine and the scheduler ---------------------------------------------

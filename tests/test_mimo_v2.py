@@ -36,6 +36,7 @@ from pytorch_distributed_tpu.serving import (
     Scheduler,
     WindowedKVCache,
 )
+from tests import _real_chunks
 
 TOL = 1e-4
 WINDOW = 8
@@ -414,6 +415,14 @@ def test_a_prompt_in_chunks_is_the_reference(served, monkeypatch):
     logits, cache = _prefilled(model, variables, cache, 0, tokens, 48)
     assert float(jnp.abs(logits - _reference(variables, tokens)[-1]).max()) \
         < TOL
+
+
+@_real_chunks.CASES
+def test_a_prefill_ends_at_the_last_real_token(served, n_real):
+    """``tests/_real_chunks.py``: the sink and the heads of two widths and
+    two groupings through the band's bounded loop; a ring of wider keys
+    than values holds real tokens' rows alone."""
+    _real_chunks.check_a_prefill_ends_at_the_last_real_token(served[0], n_real)
 
 
 # -- the engine and the scheduler ---------------------------------------------
