@@ -67,16 +67,6 @@ def parse_args(argv=None):
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--prefetch", type=int, default=2,
                    help="loader prefetch depth (0 = synchronous)")
-    p.add_argument(
-        "--comm-hook", default=None,
-        choices=["allreduce", "bf16_compress", "fp16_compress",
-                 "reduce_scatter", "ring_allreduce"],
-        help="manual-DDP gradient sync hook; 'ring_allreduce' lowers the "
-             "sync as ppermute ring hops — the op class the TPU "
-             "scheduler overlaps with backward compute (BASELINE.md "
-             "'DP gradient-sync overlap'); default None = GSPMD "
-             "global-view all-reduce",
-    )
     return p.parse_args(argv)
 
 
@@ -156,7 +146,6 @@ def main(argv=None) -> int:
         policy=args.policy,
         grad_accum_steps=args.grad_accum,
         clip_norm=args.clip_norm,
-        comm_hook=args.comm_hook,
     )
 
     sampler = DistributedSampler(

@@ -2,8 +2,8 @@
 closures.
 
 Each :class:`StepProgram` is one (strategy × AMP policy) train step over
-the probe MLP (the same model ``perf/memory_probe.py`` accounts), built
-on a real mesh over however many devices the platform exposes — on CPU
+the probe MLP, built on a real mesh over however many devices the
+platform exposes — on CPU
 the CLI provisions virtual host devices, so the whole grid compiles
 device-free on a laptop exactly like the dryrun gate. The registry is
 the seam between the auditor and the trainer stack: checks consume the

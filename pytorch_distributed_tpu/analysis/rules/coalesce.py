@@ -6,8 +6,7 @@ round trip — launch latency, small-message bandwidth, one host sync —
 *per parameter tensor*. A GPT-2 has hundreds of leaves; the coalesced
 form (flatten once, bucket or stack the leaves, one collective, unflatten
 — what ``broadcast_coalesced`` and the bucketed DDP reducers do) is an
-order of magnitude cheaper and is why this repo's ``average_parameters``
-batches its transfer. In-jit collectives (``lax.psum`` under ``jit``/
+order of magnitude cheaper. In-jit collectives (``lax.psum`` under ``jit``/
 ``shard_map``) are exempt: XLA fuses those across leaves by itself.
 
 The rule fires only when the loop demonstrably iterates tree leaves (a

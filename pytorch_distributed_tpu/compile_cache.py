@@ -9,9 +9,9 @@ the cache goes to one fixed directory inside the checkout — never to a
 temporary name, a process id or a timestamp, because a cache that moves
 never hits.
 
-Entry points (``chip_smoke.py``, ``bench.py``, ``benchmarks.matrix``, the
-example mains) call :func:`enable_compile_cache` before their first
-compile. Library code and the test suite do not.
+Entry points (``chip_smoke.py``, ``chipbench.run``, the example mains) call
+:func:`enable_compile_cache` before their first compile. Library code and
+the test suite do not.
 """
 
 from __future__ import annotations

@@ -78,7 +78,7 @@ class GPT2Config:
     # the compute dtype with fp32 ACCUMULATION (preferred_element_type) —
     # the MXU-native path; on v5e the fp32-input head matmul runs well
     # below bf16 peak, so bf16 inputs are the measured-perf choice for
-    # bf16 models (perf/xent_ab.py).
+    # bf16 models.
     head_in_fp32: bool = True
 
 

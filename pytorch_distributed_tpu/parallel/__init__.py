@@ -84,22 +84,8 @@ __all__ = [
     "ScheduleLoopedBFS",
     "ScheduleZBVZeroBubble",
     "ScheduleZeroBubble",
-    "allreduce_hook", "bf16_compress", "fp16_compress", "get_comm_hook",
-    "make_bucketed_rs_hook", "reduce_scatter_hook",
-    "make_ring_allreduce_hook", "ring_allreduce_hook",
     "gpipe_spmd",
 ]
-
-from pytorch_distributed_tpu.parallel.comm_hooks import (  # noqa: F401,E402
-    allreduce_hook,
-    bf16_compress,
-    fp16_compress,
-    get_comm_hook,
-    make_bucketed_rs_hook,
-    make_ring_allreduce_hook,
-    reduce_scatter_hook,
-    ring_allreduce_hook,
-)
 
 from pytorch_distributed_tpu.parallel.expert import (  # noqa: F401,E402
     ExpertDataParallel,
@@ -108,16 +94,3 @@ from pytorch_distributed_tpu.parallel.expert import (  # noqa: F401,E402
 )
 
 __all__ += ["ExpertDataParallel", "ExpertParallel", "MoEMLP"]
-
-from pytorch_distributed_tpu.parallel.averagers import (  # noqa: F401,E402
-    EMAAverager,
-    PeriodicModelAverager,
-    average_parameters,
-)
-
-__all__ += ["EMAAverager", "PeriodicModelAverager", "average_parameters"]
-
-from pytorch_distributed_tpu.parallel.powersgd import (  # noqa: F401,E402
-    PowerSGD,
-)
-__all__.append("PowerSGD")

@@ -64,8 +64,8 @@ finishes, so the paired B can overlap below Python — the full schedule
 family torch ships is expressible in this executor (the r4 "cannot
 express" stance was retired by measurement; see ScheduleDualPipeV).
 On the SPMD perf path, overlap remains the XLA latency-hiding
-scheduler's job (observed in the compiled schedule — see
-perf/overlap_aot_probe.py), not a hand-written stream's.
+scheduler's job (observed in the compiled schedule), not a
+hand-written stream's.
 """
 
 from __future__ import annotations
@@ -484,8 +484,7 @@ class EagerPipelineExecutor:
         #: most 2 recvs are ever outstanding (current + lookahead) in the
         #: 4-thread PG pool, and sends complete against the store/TCP
         #: server independent of the receiver, so queued sends always
-        #: drain. ``async_p2p=False`` restores blocking P2P (the A/B
-        #: lever perf/eager_microbench.py measures).
+        #: drain. ``async_p2p=False`` restores blocking P2P.
         self.async_p2p = bool(async_p2p)
         self.stage_fn = stage_fn
         #: one params pytree per LOCAL chunk; plain (non-interleaved) use

@@ -19,13 +19,11 @@ updated params; the latency-hiding scheduler overlaps both collectives with
 neighboring compute. This recovers — declaratively — what the torch stack
 builds by hand: ZeroRedundancyOptimizer's rank partitioning + broadcast,
 FSDP's FlatParameter unshard/reshard, and the bucketed reduce-scatter comm
-hook (``comm_hooks.make_bucketed_rs_hook``), while keeping
-``AsyncRunner.programs_per_step`` at 1.
+hook, while keeping ``AsyncRunner.programs_per_step`` at 1.
 
 Everything here is pure spec/tracer plumbing: the helpers only read pytree
 paths and ``.shape``, so they work identically on concrete arrays, jit
-tracers, and ``jax.eval_shape`` outputs (which is what lets
-``perf/memory_probe.py`` account the 1/dp win on a devices-free host).
+tracers, and ``jax.eval_shape`` outputs.
 """
 
 from __future__ import annotations

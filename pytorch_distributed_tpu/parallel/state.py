@@ -38,10 +38,6 @@ class TrainState(struct.PyTreeNode):
       model_state: mutable collections (batch_stats, ...); {} if none.
       opt_state: optax optimizer state.
       scaler: loss-scaler state (amp.GradScalerState) or None.
-      comm_state: stateful comm-hook state (e.g. PowerSGD's Q factors and
-        per-rank error-feedback buffers) or None. torch keeps this in a
-        Python ``PowerSGDState`` object the hook mutates; under jit it is
-        a pytree threaded through the step like everything else.
     """
 
     step: jax.Array
@@ -49,7 +45,6 @@ class TrainState(struct.PyTreeNode):
     model_state: Any
     opt_state: Any
     scaler: Optional[Any] = None
-    comm_state: Optional[Any] = None
 
 
 def _path_str(path) -> str:
@@ -123,13 +118,6 @@ def make_state_specs(
             None
             if state_shapes.scaler is None
             else jtu.tree_map_with_path(scalar_spec, state_shapes.scaler)
-        ),
-        # default replicated; stateful hooks override via their own
-        # state_pspec (Trainer.init)
-        comm_state=(
-            None
-            if state_shapes.comm_state is None
-            else jtu.tree_map_with_path(scalar_spec, state_shapes.comm_state)
         ),
     )
 

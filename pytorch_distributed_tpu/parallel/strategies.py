@@ -339,19 +339,17 @@ class ZeRO1(DataParallel):
     replicated params/grads in the forward/backward, optimizer state AND
     the weight update sharded over the dp axis.
 
-    With ``sharded_update=True`` (the default) this is the full
-    cross-replica sharded weight update of arXiv 2004.13336: the trainer
-    constrains grads into the 1/dp ``update_pspec`` layout (SPMD lowers
-    the dp all-reduce into a reduce-scatter), the optimizer step runs on
-    the shard next to its sharded state, and the updated params are
-    constrained back to replicated (the all-gather) — the torch
-    rank-partitioned step + broadcast, without the hand-written
-    partitioning cache, and without leaving the one fused step program.
-
-    ``sharded_update=False`` recovers the older opt-state-pspecs-only
-    behavior (XLA still keeps the state sharded via ``out_shardings`` but
-    the update math itself runs replicated).
+    This is the full cross-replica sharded weight update of arXiv
+    2004.13336: the trainer constrains grads into the 1/dp
+    ``update_pspec`` layout (SPMD lowers the dp all-reduce into a
+    reduce-scatter), the optimizer step runs on the shard next to its
+    sharded state, and the updated params are constrained back to
+    replicated (the all-gather) — the torch rank-partitioned step +
+    broadcast, without the hand-written partitioning cache, and without
+    leaving the one fused step program.
     """
+
+    sharded_update = True
 
     def __init__(
         self,
@@ -359,11 +357,9 @@ class ZeRO1(DataParallel):
         dp_axis: str = "dp",
         *,
         min_shard_size: int = 1024,
-        sharded_update: bool = True,
     ):
         super().__init__(mesh, dp_axis)
         self.min_shard_size = min_shard_size
-        self.sharded_update = bool(sharded_update)
 
     def opt_pspec(self, path: str, shape) -> PartitionSpec:
         return _shard_largest_divisible_dim(
@@ -377,8 +373,7 @@ class ZeRO1(DataParallel):
 
     def collective_signature(self) -> dict:
         sig = super().collective_signature()
-        if self.sharded_update:
-            # the delta all-gather of arXiv 2004.13336: per sharded-update
-            # leaf, full-param bytes — never one monolithic gather
-            sig["param_gather"] = "delta"
+        # the delta all-gather of arXiv 2004.13336: per sharded-update
+        # leaf, full-param bytes — never one monolithic gather
+        sig["param_gather"] = "delta"
         return sig

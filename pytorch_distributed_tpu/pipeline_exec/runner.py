@@ -175,7 +175,6 @@ class AsyncRunner:
             out_shardings=(
                 trainer.state_shardings, replicated, replicated,
             ),
-            compiler_options=trainer.compiler_options,
         )
 
     def start(self, state, sample_batch, rng=None) -> "AsyncRunner":

@@ -81,10 +81,10 @@ def classification_loss(model, variables, batch, train: bool, rngs=None):
     metrics, or gradients; the mean divides by the REAL example count.
     Caveats: in train mode padded rows still enter BatchNorm batch
     statistics (pad with representative rows, or run the final partial
-    batch in eval mode, for bit-exactness); with grad accumulation or a
-    comm_hook, microbatch/shard means are averaged uniformly, so a padded
-    microbatch's real examples weigh slightly more than others' — spread
-    padding evenly across microbatches for an exact global mean."""
+    batch in eval mode, for bit-exactness); with grad accumulation,
+    microbatch means are averaged uniformly, so a padded microbatch's real
+    examples weigh slightly more than others' — spread padding evenly
+    across microbatches for an exact global mean."""
     if len(batch) == 3:
         x, y, mask = batch
         mask = mask.astype(jnp.float32)
@@ -233,8 +233,6 @@ class Trainer:
         grad_accum_steps: int = 1,
         scaler: Optional[GradScaler] = None,
         clip_norm: Optional[float] = None,
-        compiler_options: Optional[dict] = None,
-        comm_hook=None,
     ):
         self.model = model
         self.optimizer = optimizer
@@ -246,29 +244,6 @@ class Trainer:
             scaler = GradScaler()
         self.scaler = scaler
         self.clip_norm = clip_norm
-        self.compiler_options = compiler_options
-        self.comm_hook = comm_hook
-        #: stateful hooks (PowerSGD) carry state through
-        #: TrainState.comm_state instead of being pure functions
-        self.comm_hook_stateful = bool(
-            getattr(comm_hook, "stateful", False)
-        )
-        if comm_hook is not None:
-            from pytorch_distributed_tpu.parallel import (
-                DataParallel as _DP,
-            )
-
-            if not isinstance(strategy, _DP):
-                raise ValueError(
-                    "Trainer comm_hook supports the DataParallel strategy "
-                    "only (replicated params, batch sharded on dp_axis) — "
-                    "the manual-DDP structure the hook contract assumes. "
-                    "For the HSDP inter-slice (DCN) gradient compression, "
-                    "apply parallel.comm_hooks.bf16_compress inside your "
-                    "own shard_map over the dcn axis (see "
-                    "tests/test_comm_hooks_uneven.py::test_hybrid_mesh_"
-                    "dcn_hook)."
-                )
         self._step_fn = None
         self._eval_fn = None
         self.state_shardings: Optional[TrainState] = None
@@ -287,40 +262,17 @@ class Trainer:
             variables = self.model.init(rng, x, **init_kwargs)
             params = variables["params"]
             model_state = {k: v for k, v in variables.items() if k != "params"}
-            comm_state = None
-            if self.comm_hook_stateful:
-                comm_state = self.comm_hook.init(
-                    params, self.strategy.mesh.size(self.strategy.dp_axis)
-                )
             return TrainState(
                 step=jnp.int32(0),
                 params=params,
                 model_state=model_state,
                 opt_state=self.optimizer.init(params),
                 scaler=self.scaler.init() if self.scaler else None,
-                comm_state=comm_state,
             )
 
         shapes = jax.eval_shape(init_fn, rng)
         self.state_shardings = make_state_shardings(shapes, self.strategy)
-        if self.comm_hook_stateful and shapes.comm_state is not None:
-            # hook-defined placement: Q replicated, error buffers sharded
-            # over the dp axis (each device owns its own residual)
-            mesh = self.strategy.mesh.jax_mesh
-            comm_specs = self.comm_hook.state_pspec(
-                shapes.comm_state, self.strategy.dp_axis
-            )
-            self.state_shardings = self.state_shardings.replace(
-                comm_state=jtu.tree_map(
-                    lambda s: NamedSharding(mesh, s), comm_specs,
-                    is_leaf=lambda x: isinstance(x, PartitionSpec),
-                )
-            )
-        return jax.jit(
-            init_fn,
-            out_shardings=self.state_shardings,
-            compiler_options=self.compiler_options,
-        )(rng)
+        return jax.jit(init_fn, out_shardings=self.state_shardings)(rng)
 
     # -- the step ----------------------------------------------------------
     def _make_step_fn(self) -> Callable:
@@ -374,8 +326,8 @@ class Trainer:
         grad_fn = jax.grad(forward, has_aux=True)
 
         def compute_grads(params, model_state, batch, scale, step_rng):
-            """Local (unhooked) gradient computation incl. accumulation:
-            returns (grads, loss, new_model_state, metrics)."""
+            """Gradient computation incl. accumulation: returns
+            (grads, loss, new_model_state, metrics)."""
             if accum > 1:
                 def micro(carry, xs):
                     mb, mb_idx = xs
@@ -411,81 +363,6 @@ class Trainer:
             )
             return grads, loss, new_ms, metrics
 
-        stateful_hook = self.comm_hook_stateful
-        if self.comm_hook is not None:
-            # manual-DDP structure (the torch comm-hook contract): grads
-            # computed PER dp-SHARD inside shard_map with no automatic
-            # sync, then the hook performs the one explicit all-reduce —
-            # compressed hooks put a bf16/fp16 (or PowerSGD low-rank)
-            # operand on the wire. Accumulation happens before the hook
-            # (no_sync semantics: one reduction per step, not per
-            # microbatch).
-            from pytorch_distributed_tpu.parallel.comm_hooks import (
-                get_comm_hook,
-            )
-
-            dp_axis = self.strategy.dp_axis
-            hook = (
-                self.comm_hook if stateful_hook
-                else get_comm_hook(self.comm_hook)
-            )
-
-            def hooked(params, model_state, batch, scale, step_rng,
-                       comm_state, step):
-                # decorrelate per-shard dropout
-                step_rng = jax.random.fold_in(
-                    step_rng, jax.lax.axis_index(dp_axis)
-                )
-                g, loss, ms, metrics = compute_grads(
-                    params, model_state, batch, scale, step_rng
-                )
-                with jax.named_scope("grad_sync"):
-                    if stateful_hook:
-                        comm_state, g = hook.apply(
-                            comm_state, g, dp_axis, step)
-                    else:
-                        g = hook(g, dp_axis)
-                loss = jax.lax.pmean(loss, dp_axis)
-                metrics = jtu.tree_map(
-                    lambda m: jax.lax.pmean(m, dp_axis), metrics
-                )
-                # per-shard batch stats average to the global-mean running
-                # stats (SyncBN-flavored; torch DDP keeps them per-rank)
-                ms = jtu.tree_map(
-                    lambda s: jax.lax.pmean(s, dp_axis)
-                    if jnp.issubdtype(s.dtype, jnp.floating) else s,
-                    ms,
-                )
-                return g, loss, ms, metrics, comm_state
-
-            if stateful_hook:
-                if self.state_shardings is None or (
-                    self.state_shardings.comm_state is None
-                ):
-                    raise ValueError(
-                        "stateful comm_hook needs comm_state — create the "
-                        "state via Trainer.init()"
-                    )
-                comm_spec = jtu.tree_map(
-                    lambda ns: ns.spec, self.state_shardings.comm_state,
-                    is_leaf=lambda x: isinstance(x, NamedSharding),
-                )
-            else:
-                comm_spec = P()
-            compute = jax.shard_map(
-                hooked, mesh=mesh,
-                in_specs=(P(), P(), batch_spec, P(), P(), comm_spec, P()),
-                out_specs=(P(), P(), P(), P(), comm_spec),
-                check_vma=False,
-            )
-        else:
-            def compute(params, model_state, batch, scale, step_rng,
-                        comm_state, step):
-                g, loss, ms, metrics = compute_grads(
-                    params, model_state, batch, scale, step_rng
-                )
-                return g, loss, ms, metrics, comm_state
-
         def step_fn(state: TrainState, batch, rng):
             batch = jtu.tree_map(
                 lambda x: jax.lax.with_sharding_constraint(
@@ -500,9 +377,8 @@ class Trainer:
                 state.scaler.scale if use_scaling else jnp.float32(1.0)
             )
 
-            grads, loss, new_model_state, metrics, new_comm_state = compute(
-                state.params, state.model_state, batch, scale, step_rng,
-                state.comm_state, state.step,
+            grads, loss, new_model_state, metrics = compute_grads(
+                state.params, state.model_state, batch, scale, step_rng
             )
 
             if use_sharded_update:
@@ -549,9 +425,6 @@ class Trainer:
                 model_state=new_model_state,
                 opt_state=pick(new_opt_state, state.opt_state),
                 scaler=new_scaler,
-                # hook state advances even on skipped steps (matches
-                # torch: the hook runs before GradScaler's inf check)
-                comm_state=new_comm_state,
             )
             out_metrics = {
                 "loss": loss,
@@ -577,10 +450,7 @@ class Trainer:
             metric_sharding = NamedSharding(mesh, P())  # scalars, replicated
             out_shardings = (self.state_shardings, metric_sharding)
         return jax.jit(
-            step_fn,
-            donate_argnums=(0,),
-            out_shardings=out_shardings,
-            compiler_options=self.compiler_options,
+            step_fn, donate_argnums=(0,), out_shardings=out_shardings
         )
 
     def _ensure_shardings(self, state: TrainState) -> None:
@@ -626,8 +496,8 @@ class Trainer:
         XLA executable (``compiled(state, placed_batch, rng)`` runs the step;
         ``compiled.as_text()`` is its optimized HLO). This is the supported
         surface for inspecting the compiled step — the multi-chip dryrun
-        gate's collective assertions and the perf toolkit use it instead of
-        reaching into the jit internals."""
+        gate's collective assertions use it instead of reaching into the
+        jit internals."""
         self._ensure_built(state)
         if rng is None:
             rng = jax.random.key(0)
@@ -666,7 +536,7 @@ class Trainer:
                 )
             return {"loss": loss, **metrics}
 
-        return jax.jit(eval_fn, compiler_options=self.compiler_options)
+        return jax.jit(eval_fn)
 
     def eval_step(self, state: TrainState, batch) -> Dict:
         if self._eval_fn is None:

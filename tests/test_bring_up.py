@@ -4,8 +4,7 @@ and for a missing chip to be an error, never a quiet CPU run.
   * one process per chip — importing the package or the launcher touches
     no backend (``tpurun`` is a parent of the workers that need the chip);
   * the compile cache is placed from outside or at one fixed path;
-  * ``chip_smoke.py`` / ``bench.py`` / the benchmark matrix refuse a
-    machine without a TPU;
+  * ``chip_smoke.py`` refuses a machine without a TPU;
   * the kernels never turn into the interpreter because a backend failed;
   * utilization divides by a published peak or not at all;
   * the native library is rebuilt from source CONTENT, not file times.
@@ -43,7 +42,6 @@ _IMPORTED = [
     "pytorch_distributed_tpu.serving",
     "pytorch_distributed_tpu.elastic.agent",
     "pytorch_distributed_tpu.elastic.run",
-    "benchmarks.matrix",
     "chip_smoke",
 ]
 
@@ -108,50 +106,6 @@ def test_chip_smoke_refuses_the_cpu():
     assert '"ok": true' not in r.stdout
 
 
-def test_bench_refuses_the_cpu():
-    r = _run(["bench.py"], JAX_PLATFORMS="cpu")
-    assert r.returncode != 0
-    out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out["metric"] == "bench_error" and out["value"] == 0
-    assert "images_per_sec" not in r.stdout
-
-
-def test_matrix_full_size_needs_a_tpu():
-    """Smoke shapes are reachable only on request; a full-size config that
-    finds no chip raises instead of shrinking."""
-    from benchmarks import matrix
-
-    assert matrix._full_size(True) is False
-    with pytest.raises(RuntimeError, match="needs a TPU"):
-        matrix._full_size(False)
-    with pytest.raises(RuntimeError, match="needs a TPU"):
-        matrix.CONFIGS[1]()
-
-
-def test_matrix_main_exits_nonzero_on_a_failed_config(tmp_path, monkeypatch):
-    from benchmarks import matrix
-    from pytorch_distributed_tpu import compile_cache
-
-    def boom(smoke=False):
-        raise RuntimeError("config blew up")
-
-    # results are written beside the module: point that at a scratch dir
-    monkeypatch.setattr(matrix, "__file__", str(tmp_path / "matrix.py"))
-    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: None)
-    monkeypatch.setattr(matrix, "_memory_per_chip_stamp", dict)
-    monkeypatch.setattr(matrix, "_ir_audit_stamp", dict)
-    monkeypatch.setattr(matrix, "CONFIGS", {
-        1: boom,
-        2: lambda smoke=False: {"config": 2, "smoke": smoke},
-    })
-    assert matrix.main(["--smoke"]) == 1
-    res = json.loads(next(tmp_path.glob("results_*.json")).read_text())
-    assert "config blew up" in res["configs"]["1"]["error"]
-    assert res["configs"]["2"] == {"config": 2, "smoke": True}  # still ran
-    monkeypatch.setattr(matrix, "CONFIGS", {2: matrix.CONFIGS[2]})
-    assert matrix.main(["--smoke"]) == 0
-
-
 # -- kernels never fall back to the interpreter ----------------------------
 @pytest.mark.parametrize("module", ["flash_attention", "paged_attention"])
 def test_interpret_default_propagates_backend_error(module, monkeypatch):
@@ -172,7 +126,7 @@ def test_interpret_default_propagates_backend_error(module, monkeypatch):
 
 # -- the peaks table -------------------------------------------------------
 def test_peak_table_knows_v5e_and_rejects_unknown_kinds():
-    from benchmarks.peaks import peak_bf16_flops
+    from chipbench.peaks import peak_bf16_flops
 
     assert peak_bf16_flops("TPU v5 lite") == peak_bf16_flops("TPU v5e") == 197e12
     for kind in ("cpu", "TPU v9 hypothetical", ""):

@@ -282,25 +282,3 @@ class TestMetricHistory:
         assert h.first() == 3.0
         assert h.last() == 1.0
         np.testing.assert_array_equal(h["loss"], [3.0, 2.0, 1.0])
-
-
-class TestDispatchProbe:
-    def test_probe_smoke_cpu(self):
-        import importlib.util
-        import pathlib
-
-        path = (pathlib.Path(__file__).parent.parent
-                / "perf" / "dispatch_probe.py")
-        spec = importlib.util.spec_from_file_location("dispatch_probe", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        out = mod.probe(steps=2, batch=2, hw=16, classes=10)
-        assert out["platform"] == "cpu"
-        assert out["dispatch_ms_per_program"] >= 0
-        assert out["programs_per_step"]["runner"] == 1.0
-        budget = out["step_budget"]
-        for k in ("enqueue_ms_per_step", "chained_ms_per_step",
-                  "blocking_ms_per_step", "runner_ms_per_step",
-                  "blocking_extra_ms"):
-            assert isinstance(budget[k], float)
-        assert out["host_fetches_per_step"]["runner"] < 1.0

@@ -590,3 +590,24 @@ class TestBatchOpsAndShrink:
             np.testing.assert_allclose(np.asarray(out), np.ones(2))
         finally:
             dist.destroy_process_group()
+
+
+class TestCollectiveEvents:
+    """Per-collective trace events (ParamCommsUtils role, SURVEY §5.1)."""
+
+    def test_events_recorded_per_collective(self):
+        from pytorch_distributed_tpu.observability.logging_utils import (
+            recent_events,
+        )
+
+        def fn(rank, pg):
+            pg.all_reduce(np.ones(8)).result()
+            pg.barrier().result()
+            return True
+
+        run_ranks(2, fn)
+        evs = [e for e in recent_events(200) if e.name == "collective"]
+        ops = {e.metadata["op"] for e in evs if e.metadata}
+        assert "all_reduce" in ops and "barrier" in ops
+        ar = [e for e in evs if e.metadata and e.metadata["op"] == "all_reduce"]
+        assert all("duration_ms" in e.metadata for e in ar)

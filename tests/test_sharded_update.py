@@ -4,7 +4,7 @@ The oracle (ISSUE 18): the sharded update must be a pure *layout* change.
 Same seeds, same data → the loss trace and final params are IDENTICAL
 (float32 bit-equality, not allclose) to the unsharded update, including
 through the AMP GradScaler and through the pipelined executor's donation
-chain. The memory win is asserted separately by the dryrun probe test.
+chain. The memory win is asserted separately (``TestMemoryPerChip``).
 
 Fast subset runs tier-1; the full strategy × AMP × clip grid is `slow`.
 
@@ -112,7 +112,7 @@ def assert_params_equal(pa, pb, **tol):
 
 class TestShardSpecReasons:
     """Every replication fallback is explicit and named — no silent
-    replication left for the memory probe to mis-account."""
+    replication."""
 
     def test_scalar(self):
         assert shard_spec_with_reason((), "dp", 8, 0) == (P(), "scalar")
@@ -185,7 +185,6 @@ class TestBitExactFast:
     def test_sharded_update_flag_defaults(self, mesh8):
         mesh_f = init_device_mesh((8,), ("fsdp",))
         assert ZeRO1(mesh8).sharded_update is True
-        assert ZeRO1(mesh8, sharded_update=False).sharded_update is False
         assert FullyShardedDataParallel(mesh_f).sharded_update is True
         assert DataParallel(mesh8).sharded_update is False
         assert NoShard(mesh8).sharded_update is False
@@ -197,8 +196,6 @@ def _grid_strategies(mesh8):
     mesh_f = init_device_mesh((8,), ("fsdp",))
     return {
         "zero1_update": ZeRO1(mesh8, min_shard_size=8),
-        "zero1_optstate_only": ZeRO1(
-            mesh8, min_shard_size=8, sharded_update=False),
         "fsdp": FullyShardedDataParallel(mesh_f, min_shard_size=8),
     }
 
@@ -207,8 +204,7 @@ def _grid_strategies(mesh8):
 class TestStrategyGridSlow:
     @pytest.mark.parametrize("policy", ["fp32", "fp16"])
     @pytest.mark.parametrize("clip", [None, 1.0])
-    @pytest.mark.parametrize(
-        "name", ["zero1_update", "zero1_optstate_only", "fsdp"])
+    @pytest.mark.parametrize("name", ["zero1_update", "fsdp"])
     def test_grid_vs_dp(self, mesh8, name, policy, clip):
         strat = _grid_strategies(mesh8)[name]
         dp = run_trace(DataParallel(mesh8), policy=policy, clip=clip)
@@ -315,60 +311,42 @@ class TestShardedDonationSafety:
         assert_params_equal(sp, pp)
 
 
-# -- memory probe (satellite 1) ----------------------------------------------
+# -- the memory win: bytes on a chip, from the shardings Trainer.init pins ----
 
-def _load_memory_probe():
-    import importlib.util
-    import os
+def _bytes_per_chip(strategy):
+    """``{params, opt}`` bytes one chip holds of ResNet-18's state under
+    ``strategy``: the shard shapes of the layout ``Trainer.init`` derives
+    (shapes only, nothing is placed)."""
+    from pytorch_distributed_tpu.models import resnet18
+    from pytorch_distributed_tpu.trainer import classification_loss
 
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "perf", "memory_probe.py",
-    )
-    spec = importlib.util.spec_from_file_location("memory_probe", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    trainer = Trainer(resnet18(num_classes=10, cifar_stem=True),
+                      optax.sgd(0.1, momentum=0.9), strategy,
+                      loss_fn=classification_loss)
+    sample = (np.zeros((1, 32, 32, 3), np.float32), np.zeros((1,), np.int32))
+    shapes = jax.eval_shape(lambda k: trainer.init(k, sample),
+                            jax.random.key(0))
+
+    def held(part):
+        return sum(
+            int(np.prod(s.shard_shape(a.shape))) * a.dtype.itemsize
+            for a, s in zip(jax.tree.leaves(getattr(shapes, part)),
+                            jax.tree.leaves(getattr(trainer.state_shardings,
+                                                    part))))
+
+    return {"params": held("params"), "opt": held("opt_state")}
 
 
-class TestMemoryProbe:
-    def test_resnet_opt_state_is_one_over_dp(self):
-        """Acceptance: optimizer-state bytes/chip on the ResNet path at
-        ~1/dp vs DataParallel (within rounding from min_shard_size
-        replication of tiny BN params), with programs_per_step still 1."""
-        import json
-
-        probe = _load_memory_probe()
-        res = probe.probe(model="resnet18", dp=8)
-        rows = res["bytes_per_chip"]
-        assert rows["dp"]["opt"] == rows["noshard"]["opt"]
-        ratio = rows["zero1_update"]["opt_ratio_vs_dp"]
-        assert 1 / 8 <= ratio <= 1.25 / 8, ratio
-        # grads at the update shrink with it; params stay replicated
-        assert rows["zero1_update"]["grads"] == rows["zero1_update"]["opt"]
-        assert rows["zero1_update"]["params"] == rows["dp"]["params"]
-        # opt-state-only ZeRO1 keeps full-size grads
-        assert rows["zero1_optstate_only"]["grads"] == rows["dp"]["grads"]
-        # FSDP also shards the resident params
-        assert rows["fsdp"]["params"] < rows["dp"]["params"] / 6
-        assert res["programs_per_step"] == 1.0
-        json.dumps(res)  # the stamp must be JSON-cleanly serializable
-
-    def test_fallback_reasons_surface(self):
-        probe = _load_memory_probe()
-        res = probe.probe(model="mlp", dp=8, min_shard_size=1024)
-        fb = res["bytes_per_chip"]["zero1_update"]["fallbacks"]
-        assert fb.get("sharded", 0) >= 1
-        assert fb.get("small", 0) >= 1  # the 10-unit head bias replicates
-
-    def test_spec_mesh_needs_no_devices(self):
-        probe = _load_memory_probe()
-        m = probe.SpecMesh(dp=256)
-        assert m.size("dp") == 256 and m.axis_names == ("dp",)
-        with pytest.raises(RuntimeError):
-            m.jax_mesh
-        # dp=256 pod accounting from a devices-free host
-        res = probe.probe(model="mlp", dp=256, min_shard_size=8)
-        assert res["bytes_per_chip"]["zero1_update"]["opt"] < (
-            res["bytes_per_chip"]["dp"]["opt"]
-        )
+class TestMemoryPerChip:
+    def test_resnet_opt_state_is_one_over_dp(self, mesh8):
+        """Optimizer-state bytes a chip on the ResNet path at ~1/dp of
+        DataParallel's (within rounding from min_shard_size replication of
+        tiny BN params); FSDP also shards the resident params."""
+        dp = _bytes_per_chip(DataParallel(mesh8))
+        zero = _bytes_per_chip(ZeRO1(mesh8))
+        fsdp = _bytes_per_chip(FullyShardedDataParallel(
+            init_device_mesh((8,), ("fsdp",))))
+        assert dp == _bytes_per_chip(NoShard(mesh8))
+        assert 1 / 8 <= zero["opt"] / dp["opt"] <= 1.25 / 8, (zero, dp)
+        assert zero["params"] == dp["params"]
+        assert fsdp["params"] < dp["params"] / 6
