@@ -17,7 +17,10 @@ and T = 5 at random offsets, held to a float32 reference that EXPANDS K and
 V from the rows as stored (no absorption). Run it BEFORE a cell, after any
 change to a kernel (``slotted`` or ``latent`` alone runs that half):
 
-    chiprun --chips 1 -- python3 chip_kernel_parity.py [slotted|latent|gqa [prefill|share]|gqa_uneven|kda|grouped [sweep]]
+    chiprun --chips 1 -- python3 chip_kernel_parity.py [slotted|latent|gqa [prefill|share]|gqa_uneven|kda|grouped [sweep]|latent_paged]
+
+``latent_paged`` (alone; PR 54) is ``ops.latent_paged_attention`` at the
+``sarvam-105b.serve-doc-sessions`` cell's shapes (``latent_paged_cases``).
 
 ``gqa_uneven`` (alone; PR 49) is ``ops.gqa_attention`` at the
 ``mimo-v2.5.serve-code-agent`` cell's shapes (``gqa_uneven_cases``): K heads
@@ -874,6 +877,189 @@ def grouped_cases(sweep=None):
     return ok
 
 
+# -- the paged latent pool: ops.latent_paged_attention ------------------------
+P_S, P_H, P_LAYERS, P_PAGES, P_PAGE, P_MAX = 24, 64, 5, 4096, 128, 256
+
+
+def _expanded_reference(q, rows, kv_b, pos, scale):
+    """float32 attention of queries ``q [T, H, 192]`` at positions ``pos
+    [T]`` over ``rows [n, 640]`` (a chain's rows in order), K and V EXPANDED
+    from them: no absorption, no block, no page."""
+    hi = jax.lax.Precision.HIGHEST
+    rows = rows.astype(jnp.float32)
+    seen = jnp.arange(rows.shape[0])[None, :] <= pos[:, None]
+    out = []
+    for first in range(0, q.shape[1], 8):    # 575 x 28,735 x 64 scores: 4 GB
+        heads = slice(first, first + 8)
+        kv = jnp.einsum("sc,chn->shn", rows[:, :D_C],
+                        kv_b[:, heads].astype(jnp.float32), precision=hi)
+        k = jnp.concatenate([kv[..., :D_N], jnp.broadcast_to(
+            rows[:, None, D_C:D_C + D_R], (rows.shape[0], 8, D_R))], -1)
+        scores = jnp.einsum("thd,shd->hts", q[:, heads].astype(jnp.float32),
+                            k, precision=hi) * scale
+        probs = jax.nn.softmax(jnp.where(seen[None], scores, -jnp.inf), -1)
+        out.append(jnp.einsum("hts,shd->thd", probs, kv[..., D_N:],
+                              precision=hi))
+    return jnp.concatenate(out, axis=1)
+
+
+def latent_paged_cases():
+    """``ops.latent_paged_attention`` at the ``sarvam-105b.serve-doc-sessions``
+    cell's shapes (64 heads, a pool ``[5, 4096, 128, 640]`` bf16, 24 slots of
+    256 table entries): the paged read kernel and its dense twin over chains
+    of 1, 37 and 256 pages (and idle slots; page ids shuffled, two slots
+    sharing a prefix, every row past a chain's length large and stale)
+    against the float32 expanded reference; the cold prompt's attention
+    against the T x T softmax at 2,048 tokens and timed at 32,768; a tail of
+    575 in a bucket of 1,024 behind 28,160 cached rows."""
+    from pytorch_distributed_tpu.ops import latent_paged_attention as paged
+
+    ok = True
+    scale = latent_attention.yarn_softmax_scale(D_N + D_R, 40.0, 1.0)
+    rng = np.random.default_rng(54)
+    ids = rng.permutation(np.arange(1, P_PAGES))
+    lengths = np.zeros(P_S, np.int64)
+    lengths[:9] = [100, 128, 127, 37 * 128 - 1, 37 * 128, 37 * 128 - 64,
+                   P_MAX * 128 - 1, P_MAX * 128 - 300, 20000]
+    tables = np.zeros((P_S, P_MAX), np.int32)
+    at = 0
+    for s in range(P_S):
+        n = -(-(int(lengths[s]) + 1) // P_PAGE) if lengths[s] else 0
+        n = min(n, P_MAX)
+        tables[s, :n] = ids[at:at + n]
+        at += n
+    tables[8, :100] = tables[7, :100]          # a shared prefix
+    kq, kl, kb, kc = jax.random.split(jax.random.key(54), 4)
+    kv_b = (jax.random.normal(kb, (D_C, P_H, D_N + D_V), jnp.float32)
+            * D_C ** -0.5).astype(jnp.bfloat16)
+    width = latent_attention.row_width(D_C, D_R)
+    pool = jax.random.normal(kc, (P_LAYERS, P_PAGES, P_PAGE, width),
+                             jnp.bfloat16)
+    pool = pool * (jnp.arange(width) < D_C + D_R).astype(jnp.bfloat16)
+    # large where no query may look: past each chain's length, page by page
+    for s in range(P_S):
+        last = int(lengths[s]) // P_PAGE
+        if lengths[s] and last < P_MAX and s != 7:
+            keep = (jnp.arange(P_PAGE) <= lengths[s] % P_PAGE)[:, None]
+            pool = pool.at[:, tables[s, last]].multiply(
+                jnp.where(keep, 1.0, 30.0).astype(jnp.bfloat16))
+    q = jax.random.normal(kq, (P_S, 1, P_H, D_N + D_R), jnp.bfloat16)
+    latent = jax.random.normal(kl, (P_S, 1, D_C + D_R), jnp.bfloat16)
+    offset = jnp.asarray(np.minimum(lengths, P_MAX * P_PAGE - 1), jnp.int32)
+    tables_d = jnp.asarray(tables)
+    read = jax.jit(functools.partial(
+        paged.paged_read, d_c=D_C, d_n=D_N, scale=scale),
+        static_argnums=5, static_argnames=("kernel",))
+    dense, pd = read(q, latent, kv_b, pool, tables_d, LAYER, offset)
+    kern, pk = read(q, latent, kv_b, pool, tables_d, LAYER, offset,
+                    kernel=True)
+    worst = {"kernel": 0.0, "dense": 0.0}
+    within = True
+    for s in np.flatnonzero(lengths):
+        n = int(offset[s]) + 1
+        chain = pd[LAYER][tables_d[s]].reshape(-1, width)[:n]
+        ref = np.asarray(_expanded_reference(
+            q[s], chain, kv_b, offset[s][None], scale))
+        for name, got in (("kernel", kern), ("dense", dense)):
+            diff = np.abs(np.asarray(got[s], np.float32) - ref).max()
+            worst[name] = max(worst[name], float(diff))
+        within &= bool(np.allclose(np.asarray(kern[s], np.float32), ref,
+                                   rtol=RTOL, atol=ATOL))
+    line = {"op": "latent_paged_read", "slots_live": int((lengths > 0).sum()),
+            "positions_held": int(lengths.sum()),
+            "kernel_vs_reference": worst["kernel"],
+            "dense_vs_reference": worst["dense"],
+            "kernel_vs_dense": float(np.abs(
+                np.asarray(kern, np.float32)
+                - np.asarray(dense, np.float32)).max()),
+            "kernel_within_tolerance": within,
+            "same_pool_written": bool(jnp.array_equal(pd, pk)),
+            "finite": bool(np.isfinite(np.asarray(kern, np.float32)).all())}
+    del pd, pk
+
+    def token(kernel):
+        def all_layers(q, latent, kv_b, pool, tables, offset):
+            total = 0.0          # every layer's read is used: none is dead
+            for layer in range(P_LAYERS):
+                out, pool = paged.paged_read(
+                    q, latent, kv_b, pool, tables, layer, offset, d_c=D_C,
+                    d_n=D_N, scale=scale, kernel=kernel)
+                total = total + out.astype(jnp.float32)
+            return total, pool
+        return jax.jit(all_layers, donate_argnums=3)
+
+    for name, kernel in (("dense_token_ms", False), ("kernel_token_ms", True)):
+        step = token(kernel)
+        p1 = pool + 0
+        _, p1 = step(q, latent, kv_b, p1, tables_d, offset)
+        jax.block_until_ready(p1)
+        t0 = time.perf_counter()
+        for _ in range(20):
+            out, p1 = step(q, latent, kv_b, p1, tables_d, offset)
+        jax.block_until_ready(out)
+        line[name] = (time.perf_counter() - t0) / 20 * 1e3
+        del p1
+    line["bytes_must_read_a_token"] = int(
+        P_LAYERS * (lengths[lengths > 0] + 1).sum() * (D_C + D_R) * 2)
+    line["kernel_pct_of_hbm_peak"] = 100 * line[
+        "bytes_must_read_a_token"] / (line["kernel_token_ms"] * 1e-3) / 819e9
+    ok &= (line["kernel_within_tolerance"] and line["finite"]
+           and line["same_pool_written"])
+    print(json.dumps(line), flush=True)
+
+    # -- a cold prompt's attention ------------------------------------------
+    cold = jax.jit(functools.partial(paged.cold_prefill, d_c=D_C, d_n=D_N,
+                                     scale=scale))
+    for T, check in ((2048, True), (32768, False)):
+        k1, k2 = jax.random.split(jax.random.key(T))
+        qT = jax.random.normal(k1, (1, T, P_H, D_N + D_R), jnp.bfloat16)
+        rows = jax.random.normal(k2, (1, T, width), jnp.bfloat16) * (
+            jnp.arange(width) < D_C + D_R).astype(jnp.bfloat16)
+        line = {"op": "latent_cold_prefill", "T": T,
+                "ms_a_layer": _timed(cold, qT, rows, kv_b, calls=3)}
+        line["pct_of_bf16_peak"] = 100 * (
+            T * (T + 1) * P_H * (D_N + D_R + D_V)) / (
+                line["ms_a_layer"] * 1e-3) / 197e12
+        if check:
+            got = np.asarray(cold(qT, rows, kv_b)[0], np.float32)
+            ref = np.asarray(_expanded_reference(
+                qT[0], rows[0], kv_b, jnp.arange(T), scale))
+            line.update(
+                vs_reference=float(np.abs(got - ref).max()),
+                reference_range=float(ref.max() - ref.min()),
+                within_tolerance=bool(np.allclose(got, ref, rtol=RTOL,
+                                                  atol=ATOL)))
+            ok &= line["within_tolerance"]
+        print(json.dumps(line), flush=True)
+        del qT, rows
+
+    # -- a tail behind cached rows ------------------------------------------
+    start, n_new, T = 220 * P_PAGE, 575, 1024
+    # pages no slot above holds: plain rows, none of the large stale ones
+    chain = jnp.asarray(np.concatenate(
+        [ids[at:at + P_MAX - 16], np.zeros(16, np.int64)]).astype(np.int32))
+    qT = jax.random.normal(jax.random.key(575), (1, T, P_H, D_N + D_R),
+                           jnp.bfloat16)
+    tail = jax.jit(functools.partial(paged.tail_prefill, d_c=D_C, d_n=D_N,
+                                     scale=scale), static_argnums=4)
+    got = np.asarray(tail(qT, kv_b, pool, chain, LAYER, jnp.int32(start),
+                          jnp.int32(n_new))[0, :n_new], np.float32)
+    rows = pool[LAYER][chain].reshape(-1, width)[:start + n_new]
+    ref = np.asarray(_expanded_reference(
+        qT[0, :n_new], rows, kv_b, start + jnp.arange(n_new), scale))
+    line = {"op": "latent_tail_prefill", "T": T, "n_new": n_new,
+            "start": start, "vs_reference": float(np.abs(got - ref).max()),
+            "reference_range": float(ref.max() - ref.min()),
+            "within_tolerance": bool(np.allclose(got, ref, rtol=RTOL,
+                                                 atol=ATOL)),
+            "ms_a_layer": _timed(tail, qT, kv_b, pool, chain, LAYER,
+                                 jnp.int32(start), jnp.int32(n_new), calls=5)}
+    ok &= line["within_tolerance"]
+    print(json.dumps(line), flush=True)
+    return ok
+
+
+
 def main():
     device = jax.devices()[0]
     if device.platform != "tpu":
@@ -881,9 +1067,9 @@ def main():
                           "kernel's arithmetic exists only on a TPU"}))
         return 1
     which = sys.argv[1] if len(sys.argv) > 1 else "both"
-    if which in ("gqa", "gqa_uneven", "kda", "grouped"):
+    if which in ("gqa", "gqa_uneven", "kda", "grouped", "latent_paged"):
         ok = {"gqa": gqa_cases, "gqa_uneven": gqa_uneven_cases,
-              "kda": kda_cases,
+              "kda": kda_cases, "latent_paged": latent_paged_cases,
               "grouped": grouped_cases}[which](*sys.argv[2:3])
         print(json.dumps({"ok": ok, "device": {
             "platform": device.platform, "kind": device.device_kind}}))

@@ -1,16 +1,15 @@
 """The ``kimi_linear`` decoder in flax.linen: a HYBRID stack. Three layers
 in four keep a fixed-size recurrent state (Kimi Delta Attention, KDA), the
-fourth attends latent rows with no positional encoding (MLA, NoPE), and
-all but the first layer's MLP are a SHARE of the routed experts.
+fourth attends latent rows (MLA), and all but the first layer's MLP are a
+SHARE of the routed experts. ONE BLOCK, CONFIGURED (``ROADMAP.md`` R1): with
+no KDA layer and ``rope_theta`` set it is ``sarvam_mla``'s block
+(``sarvamai/sarvam-105b``: every layer MLA, rotated under YaRN).
 
-The architecture of ``moonshotai/Kimi-Linear-48B-A3B-Instruct``
-(``config.json``, ``model_type`` ``kimi_linear``; where it is silent, the
-released ``fla`` layer ``KimiDeltaAttention``). The equations are written
-out in ``chipbench/references/kimi_linear.py``, the plain float32 reference
-this forward is held to. Pre-norm residual blocks::
+The architecture of ``moonshotai/Kimi-Linear-48B-A3B-Instruct`` (where
+its ``config.json`` is silent, the released ``fla`` layer), written out in
+``chipbench/references/kimi_linear.py`` (and ``sarvam_mla.py``)::
 
     x <- x + mixer(RMSNorm(x));   x <- x + mlp(RMSNorm(x))
-
     KDA mixer (``kda_layers``, numbered from 1; H heads of d):
       q = unit(silu(conv(W_q x))) / sqrt(d),  k = unit(silu(conv(W_k x))),
       v = silu(conv(W_v x))                  conv: causal, depthwise, K taps
@@ -20,35 +19,28 @@ this forward is held to. Pre-norm residual blocks::
       y = W_o (RMSNorm_d(o) * sigmoid(g_b(g_a(x))))
     MLA mixer (``full_attn_layers``): q = W_q x (no query latent),
       [c | k_pe] = W_kva x, c <- RMSNorm(c), [k_nope | v] = W_kvb c a head,
-      k = [k_nope | k_pe]; NO rotation; causal softmax at (d_n + d_r)^-1/2
+      k = [k_nope | k_pe]; causal softmax at (d_n + d_r)^-1/2. NO rotation
+      (``rope_theta`` None); or q's and k's ``d_r`` columns turned by the
+      position under YaRN and the scale times ``m^2`` (``_turned``)
 
 ``mlp`` is a gated MLP in the first ``first_k_dense_replace`` layers and,
-in the others, ``sum_i g_i FFN_i(x) + FFN_shared(x)`` over the
-``num_experts_per_token`` experts that ``ops.dropless_experts
-.route_sigmoid_topk`` chooses among ALL ``num_experts``; a model holds
-``held_experts = (first, count)`` of them, as ``models.exaone_moe`` does
-(the others are another chip's part of an expert-parallel deployment,
-whose exchange is not in this file).
+in the others, ``sum_i g_i FFN_i(x) + FFN_shared(x)`` over the experts
+``route_sigmoid_topk`` chooses among ALL ``num_experts``; a model holds
+``held_experts = (first, count)`` of them (``models.exaone_moe``).
 
-The forward contract is ``models.xing4``'s: ``model.apply(variables, tokens,
-deterministic=True, kv_cache=, position_offset=) -> (logits, cache)``, and
-``logits`` alone without a cache; ``model.cfg``; ``model.cache_class`` names
-``serving.state_cache.HybridStateCache`` (touched through ``cache.attend``
-and ``cache.counted``: a KDA layer hands it its projections and gets ``o``
-back, an MLA layer what ``LatentCache.attend`` takes); a FRESH prefill
-through a cache returns the logits of each sequence's last real position
-only, ``[B, 1, V]``.
-
-Dtypes: weights and compute ``param_dtype`` / ``dtype`` (bfloat16 when
-served); router, norms' statistics, the decay, ``beta``, the state and its
-update, softmax, and the sum over a token's experts in float32.
-"""
+The forward contract is ``models.xing4``'s; ``model.cache_class`` names
+``serving.state_cache.HybridStateCache``, or ``LatentCache`` (which has a
+paged twin) where no layer keeps a state. A FRESH prefill through a cache,
+and a prompt's tail through a paged view (``_last_only``), return the
+logits of the last real position only, ``[B, 1, V]``. Dtypes: weights and
+compute ``param_dtype`` / ``dtype``; router, norms' statistics, angles,
+decay, ``beta``, state, softmax, the sum over experts in float32."""
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Tuple
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -112,6 +104,14 @@ class KimiLinearConfig:
     rms_norm_eps: float = 1e-5
     initializer_range: float = 0.02
     held_experts: Tuple[int, int] = (0, 256)
+    #: None: no rotation (``mla_use_nope``); else the rotary base, with
+    #: YaRN's numbers (``ops.latent_attention.yarn_inv_freq``)
+    rope_theta: Optional[float] = None
+    rope_factor: float = 1.0
+    rope_original_max_position_embeddings: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 1.0
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
@@ -193,7 +193,7 @@ class DeltaAttention(_Weights):
 
 class LatentAttention(_Weights):
     """The MLA mixer of one layer over ``x [B, T, d]`` (normed): no query
-    latent, no rotation (``mla_use_nope``)."""
+    latent; rotation and YaRN's scale where the configuration has them."""
 
     @nn.compact
     def __call__(self, x, cache, layer, position_offset):
@@ -202,7 +202,7 @@ class LatentAttention(_Weights):
         H, d_n, d_r = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                        cfg.qk_rope_head_dim)
         d_c, d_v = cfg.kv_lora_rank, cfg.v_head_dim
-        scale = (d_n + d_r) ** -0.5
+        scale = _softmax_scale(cfg)
         q = (x @ self.w("q", (d, H * (d_n + d_r)))).reshape(
             B, T, H, d_n + d_r)
         kv = x @ self.w("kv_a", (d, d_c + d_r))
@@ -211,9 +211,9 @@ class LatentAttention(_Weights):
                   cfg.rms_norm_eps), kv[..., d_c:]], -1)
         kv_b = self.w("kv_b", (d_c, H * (d_n + d_v))).reshape(
             d_c, H, d_n + d_v)
+        q, latent = _turned(cfg, q, latent, position_offset)
         if cache is None:
-            y = mla.expanded_attention(q, latent, kv_b, d_c=d_c, d_n=d_n,
-                                       scale=scale)
+            y = _uncached(q, latent, kv_b, scale)
         else:
             y, cache = cache.attend(layer, q, latent, kv_b,
                                     position_offset=position_offset,
@@ -272,11 +272,11 @@ class KimiLinear(nn.Module):
 
     @property
     def cache_class(self):
-        from pytorch_distributed_tpu.serving.state_cache import (
-            HybridStateCache,
-        )
+        from pytorch_distributed_tpu.serving import kv_cache, state_cache
 
-        return HybridStateCache
+        # no state to keep: the latent rows alone, which may lie in pages
+        return (state_cache.HybridStateCache if self.cfg.kda_layers
+                else kv_cache.LatentCache)
 
     #: as ``models.exaone_moe.ExaoneMoE.prefill_computed``
     prefill_computed = staticmethod(computed_tokens)
@@ -331,8 +331,8 @@ class KimiLinear(nn.Module):
                                     spill + layer[2])
             h = h + y.reshape(B, T, d)
         with jax.named_scope("head"):
-            if kv_cache is not None and position_offset is None:
-                # fresh prefill: only the last real position is sampled from
+            if _last_only(kv_cache, position_offset):
+                # a prompt: only the last real position is sampled from
                 last = (kv_cache.lengths - 1) % T
                 h = jnp.take_along_axis(h, last[:, None, None], axis=1)
             h = _rms(h, gain("norm"), eps)
@@ -342,3 +342,59 @@ class KimiLinear(nn.Module):
             return logits, kv_cache.counted(
                 experts_hit=hit, experts_fill_pct=fill, experts_spill=spill)
         return logits
+
+
+# -------------------------------------------------------------------------
+# What a configuration may add to the MLA mixer (down here: the lines above
+# are in the call stacks that the served programs' kernels record)
+# -------------------------------------------------------------------------
+def _softmax_scale(cfg) -> float:
+    """``(d_n + d_r)^-1/2``, times YaRN's ``m^2`` where it rotates."""
+    d_qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    if cfg.rope_theta is None:
+        return d_qk ** -0.5
+    return mla.yarn_softmax_scale(d_qk, cfg.rope_factor,
+                                  cfg.rope_mscale_all_dim)
+
+
+def _turned(cfg, q, latent, position_offset):
+    """``q [B, T, H, d_n + d_r]`` and ``latent [B, T, d_c + d_r]`` with
+    their last ``d_r`` columns rotated by the tokens' positions
+    (``position_offset [B]`` + 0..T-1; None: from 0), pairs ``(i, i + d_r /
+    2)``; as they came where the configuration does not rotate."""
+    if cfg.rope_theta is None:
+        return q, latent
+    B, T = q.shape[:2]
+    d_r = cfg.qk_rope_head_dim
+    positions = jnp.arange(T, dtype=jnp.int32)[None]
+    if position_offset is not None:
+        positions = position_offset[:, None] + positions
+    positions = jnp.broadcast_to(positions, (B, T))
+    inv_freq = mla.yarn_inv_freq(
+        d_r, cfg.rope_theta, cfg.rope_factor,
+        cfg.rope_original_max_position_embeddings, cfg.rope_beta_fast,
+        cfg.rope_beta_slow)
+
+    def turn(x):
+        return jnp.concatenate(
+            [x[..., :-d_r], mla.rotate(x[..., -d_r:], positions, inv_freq)],
+            axis=-1)
+
+    return turn(q), turn(latent)
+
+
+def _uncached(q, latent, kv_b, scale):
+    """The forward without a cache: the tokens attend each other."""
+    d_c = kv_b.shape[0]
+    return mla.expanded_attention(
+        q, latent, kv_b, d_c=d_c, d_n=q.shape[-1] - (latent.shape[-1] - d_c),
+        scale=scale)
+
+
+def _last_only(kv_cache, position_offset) -> bool:
+    """Whether this forward is ONE prompt's (or the tail of one, through a
+    paged cache's view: ``serving.paging.PagedLatentCache.prompt``), whose
+    ``kv_cache.lengths`` counts the real new tokens: the head then runs
+    over the last of them alone."""
+    return kv_cache is not None and (
+        position_offset is None or getattr(kv_cache, "prompt", False))

@@ -169,8 +169,7 @@ class InferenceEngine:
       cache_sharding: optional NamedSharding for the K/V arrays (the TP
         serving layout from ``serving.sharding.kv_cache_sharding``, or
         ``paged_kv_cache_sharding`` for ``cache_kind="paged"`` — heads on
-        tp in both layouts, so decode keeps training's Megatron collective
-        pattern).
+        tp in both: decode keeps training's Megatron collective pattern).
       seed: RNG seed for stochastic sampling.
       spec_k: speculative-decoding draft depth; 0 disables speculation.
       draft_layers: self-drafting — run the first N target layers (plus
@@ -183,15 +182,15 @@ class InferenceEngine:
       cache_kind: ``"slotted"`` (per-slot ``max_len`` reservation) or
         ``"paged"`` (``serving.paging`` page pool + block tables; the
         scheduler drives the allocator/radix control plane). The decode
-        and speculative programs are cache-kind agnostic — the model
-        reaches either cache through ``cache.attend`` — only prefill
-        differs.
+        and speculative programs are cache-kind agnostic (the model
+        reaches either through ``cache.attend``); only prefill differs.
         A separate draft model keeps a slotted cache either way (its
         scratch K/V has no sharing story and costs k small layers).
       page_size / n_pages: paged-cache geometry. ``n_pages`` defaults to
-        slotted-equivalent capacity + the trash page; pass a smaller pool
-        to oversubscribe slots against physical pages (admission then
-        backpressures on free pages — the capacity win at mixed lengths).
+        slotted-equivalent capacity + the trash page; a smaller pool
+        oversubscribes slots (admission backpressures on free pages).
+      tail_len: paged only (``_PagedPrefill``): the longest bucket of the
+        program that prefills a prompt's tail behind its chain's pages.
     """
 
     def __init__(
@@ -214,6 +213,7 @@ class InferenceEngine:
         cache_kind: str = "slotted",
         page_size: int = 16,
         n_pages: Optional[int] = None,
+        tail_len: int = 1024,
     ):
         cfg = model.cfg
         if getattr(cfg, "moe_experts", 0) > 0:
@@ -266,11 +266,11 @@ class InferenceEngine:
             n_pages = self.n_slots * self.max_pages + 1  # + trash page
         self.n_pages = int(n_pages) if n_pages is not None else 0
         # the resident cache's constructor: the kind's class and geometry
+        served = _paged_twin(slotted) if paged else slotted
         self._create_cache = functools.partial(
-            PagedKVCache.create, page_size=self.page_size,
-            n_pages=self.n_pages,
+            served.create, page_size=self.page_size, n_pages=self.n_pages,
         ) if paged else slotted.create
-        self._step_stats = tuple(getattr(slotted, "STEP_STATS", ()))
+        self._step_stats = tuple(getattr(served, "STEP_STATS", ()))
 
         # -- speculative configuration -------------------------------------
         self.spec_k = int(spec_k)
@@ -340,33 +340,33 @@ class InferenceEngine:
             return cache, tok
 
         def paged_prefill_fn(params, cache, tokens, slot, start, n_real,
-                             rng):
+                             rng, cold=False):
             """Prefill ``tokens [1, bucket]`` (the UNCACHED tail of a
             prompt) into one slot's page chain at global positions
             ``start..``: a radix prefix hit sets ``start = cached_len`` and
             skips the shared span's compute entirely — the chain's shared
-            pages supply its K/V through the block table. The page pools
-            are sequence-agnostic, so unlike the slotted path there is no
-            per-slot slice; B=1 comes from viewing one table row."""
+            pages supply its K/V through the block table. The view is ONE
+            table row over the whole pool (``cache.one_chain``) and carries
+            ``n_real``: a model may give the last real position's logits
+            alone. ``cold`` (static; ``_PagedPrefill`` says which buckets):
+            a prompt from position 0 with nothing cached runs as a FRESH
+            sequence, no position offset, and reads nothing of the pool."""
             rng = _step_key(rng)
-            row = jax.lax.dynamic_slice_in_dim(
-                cache.block_tables, slot, 1, axis=0
-            )
-            view = cache.replace(
-                block_tables=row, lengths=jnp.zeros((1,), jnp.int32)
-            )
-            logits, new_view = model_apply(
+            offset = None if cold else jnp.full((1,), start, jnp.int32)
+            logits, view = model_apply(
                 params, tokens, deterministic=True,
-                kv_cache=view,
-                position_offset=jnp.full((1,), start, jnp.int32),
+                kv_cache=cache.one_chain(slot, n_real),
+                position_offset=offset,
             )
-            cache = cache.replace(
-                k=new_view.k, v=new_view.v,
-                lengths=cache.lengths.at[slot].set(start + n_real),
-            )
-            last = logits[0, n_real - 1]
+            cache = cache.write_chain(slot, view, start + n_real)
+            whole = logits.shape[1] == tokens.shape[1]
+            last = logits[0, n_real - 1] if whole else logits[0, 0]
             tok = sample_tokens(last[None], rng, sp)[0]
             return cache, tok
+
+
+
+
 
         def decode_fn(params, cache, last_tokens, active, rng):
             rng = _step_key(rng)
@@ -381,9 +381,9 @@ class InferenceEngine:
             # their (masked, overwritten-on-admit) cache rows don't move
             return new_cache.advance(1, active), next_tok
 
-        self._prefill = jax.jit(
-            paged_prefill_fn if paged else prefill_fn, donate_argnums=(1,)
-        )
+        self._prefill = _PagedPrefill(
+            paged_prefill_fn, self.prefill_bucket, int(tail_len),
+        ) if paged else jax.jit(prefill_fn, donate_argnums=(1,))
         self._decode = jax.jit(decode_fn, donate_argnums=(1,))
 
         # -- speculative programs ------------------------------------------
@@ -658,10 +658,9 @@ class InferenceEngine:
         ``cached_len`` (paged cache only) marks a radix prefix hit: the
         first ``cached_len`` positions are already resident in the slot's
         attached page chain, so only the tail ``prompt[cached_len:]`` runs
-        through the prefill program (padded to ITS bucket — a hit on a long
-        prompt prefills through a much smaller compiled bucket, which is
-        the cached-prefix TTFT win). ``request_id`` only labels the
-        ``pdt.engine.prefill`` span."""
+        through the prefill program (padded to ITS bucket: a hit on a long
+        prompt prefills through a much smaller compiled one, the cached-
+        prefix TTFT win). ``request_id`` labels ``pdt.engine.prefill``."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         n = prompt.shape[0]
         cached_len = int(cached_len)
@@ -689,12 +688,13 @@ class InferenceEngine:
             with span("engine.prefill.dispatch") as dispatch:
                 with span("engine.prefill.dispatch.inputs"):
                     padded, n_real = self._pad_prompt(prompt[cached_len:])
-                    whole.set_metadata(**self._counts(padded.shape[1], n_real))
-                    scalars = (slot, n_real) if self.cache_kind != "paged" \
-                        else (slot, cached_len, n_real)
-                    # typed on the host: a Python int would be a weak type,
-                    # and a second executable beside the described one
-                    scalars = [np.int32(i) for i in scalars]
+                    whole.set_metadata(
+                        **self._counts(padded.shape[1], n_real, cached_len))
+                    # typed on the host: a weak-typed Python int would be
+                    # a second executable beside the described one
+                    scalars = [np.int32(i) for i in (
+                        (slot, n_real) if self.cache_kind != "paged"
+                        else (slot, cached_len, n_real))]
                     rng = self._next_rng()
                 with span("engine.prefill.dispatch.call"):
                     cache, tok = self._prefill(
@@ -783,35 +783,46 @@ class InferenceEngine:
                        np.asarray(prev_next))
         return (cache, dcache, *out)
 
-    def _counts(self, bucket: int, n_real: int) -> dict:
+    def _counts(self, bucket: int, n_real: int,
+                cached_len: int = 0) -> dict:
         """What the ``engine.prefill`` span says of a prompt of ``n_real``
         tokens padded to ``bucket``: both, and ``n_computed``, the
         positions the model's tokenwise loops run for it. A model whose
         loops end with the prompt's last real token says how far they go
         (``model.prefill_computed``, the expression that bounds the loop
         itself: ``models.exaone_moe.computed_tokens``); any other computes
-        the bucket. (Down here, below every function that a compiled
-        kernel's recorded call stack passes through: a line added above
-        them moves every serving program's compile-cache key.)"""
+        the bucket. A paged engine adds ``cached_len`` (the positions
+        before these that were served from shared pages) and ``cold``,
+        which program ran (``_PagedPrefill``). (Down here, below every
+        function that a compiled kernel's recorded call stack passes
+        through: a line added above them moves every serving program's
+        compile-cache key.)"""
         computed = getattr(self.model, "prefill_computed", None)
-        return dict(bucket=bucket, n_real=n_real, n_computed=int(
+        counts = dict(bucket=bucket, n_real=n_real, n_computed=int(
             computed(bucket, n_real)) if computed else bucket)
+        if self.cache_kind == "paged":
+            counts.update(cached_len=cached_len, cold=int(
+                self._prefill.is_cold(bucket, cached_len)))
+        return counts
 
 
 def _slotted_cache_class(model, cache_kind, cache_sharding, spec_k):
     """The class of the slotted cache a model is served from: ``KVCache``
     unless the model names its own (``model.cache_class``; ``models.xing4``
     names ``LatentCache``, ``models.exaone_moe`` ``WindowedKVCache``,
-    ``models.kimi_linear`` ``HybridStateCache``). What such a cache does not
-    support raises here, at construction, with a sentence (the class's
-    ``UNSUPPORTED_BECAUSE``). A class's ``STEP_STATS`` name the
-    counts its model leaves in ``cache.step_stats`` each step: the decode
-    program sends them to the host behind the step's tokens, in the one
-    read the step makes anyway, onto the ``pdt.engine.decode`` span."""
+    ``models.kimi_linear`` ``HybridStateCache`` or, with no recurrent
+    layer, ``LatentCache``). What such a cache does not support raises
+    here, at construction, with a sentence (the class's
+    ``UNSUPPORTED_BECAUSE``); ``cache_kind='paged'`` is supported where the
+    class names a paged twin (``_paged_twin``). A class's ``STEP_STATS``
+    name the counts its model leaves in ``cache.step_stats`` each step: the
+    decode program sends them to the host behind the step's tokens, in the
+    one read the step makes anyway, onto the ``pdt.engine.decode`` span."""
     slotted = getattr(model, "cache_class", KVCache)
     if slotted is not KVCache:
         unsupported = [what for what, asked in (
-            ("cache_kind='paged'", cache_kind == "paged"),
+            ("cache_kind='paged'",
+             cache_kind == "paged" and not hasattr(slotted, "paged_class")),
             ("cache_sharding", cache_sharding is not None),
             ("spec_k > 0", spec_k > 0)) if asked]
         if unsupported:
@@ -823,5 +834,63 @@ def _slotted_cache_class(model, cache_kind, cache_sharding, spec_k):
     return slotted
 
 
-_LATENT_LEFT = ("a paged latent pool, a tensor-parallel plan and drafting "
-                "are ROADMAP items")
+_LATENT_LEFT = ("a tensor-parallel plan and drafting for a latent cache, "
+                "pages for rings, are ROADMAP items")
+
+
+def _paged_twin(slotted):
+    """The class that holds in pages what ``slotted`` holds in slots: the
+    one a cache class names itself (``LatentCache.paged_class``), and
+    ``PagedKVCache`` for ``KVCache``."""
+    return PagedKVCache if slotted is KVCache else slotted.paged_class()
+
+
+class _PagedPrefill:
+    """The two prefill programs of a paged engine, standing where the
+    slotted engine's one ``jax.jit`` stands (``__call__``, ``lower`` and
+    ``_cache_size`` are what the engine and its tests use of it), and the
+    choice between them, made on the host where ``start`` (the cached
+    length) is known:
+
+      * a bucket up to ``tail_len`` runs the TAIL program at ``start``:
+        the new rows behind whatever the chain's pages hold, nothing or a
+        shared prefix;
+      * a longer bucket at ``start == 0`` runs the COLD program: a fresh
+        sequence that reads nothing of the pool;
+      * a longer tail behind a prefix (a document whose last pages were
+        reclaimed) goes through the tail program ``tail_len`` tokens at a
+        time; the last piece gives the token.
+
+    So a bucket is one program, and ``prefill/<bucket>`` names it."""
+
+    def __init__(self, fn, bucket_of, tail_len: int):
+        self.tail = jax.jit(fn, donate_argnums=(1,))
+        # under the function's own name: a trace finds a prefill's runs by it
+        self.cold = jax.jit(
+            functools.wraps(fn)(functools.partial(fn, cold=True)),
+            donate_argnums=(1,))
+        self.bucket_of, self.tail_len = bucket_of, tail_len
+
+    def is_cold(self, bucket: int, start: int) -> bool:
+        return bucket > self.tail_len and start == 0
+
+    def _cache_size(self) -> int:
+        return self.tail._cache_size() + self.cold._cache_size()
+
+    def lower(self, params, cache, tokens, *rest):
+        program = self.cold if tokens.shape[1] > self.tail_len else self.tail
+        return program.lower(params, cache, tokens, *rest)
+
+    def __call__(self, params, cache, tokens, slot, start, n_real, rng):
+        whole = self.tail if tokens.shape[1] <= self.tail_len else \
+            self.cold if start == 0 else None
+        if whole is not None:
+            return whole(params, cache, tokens, slot, start, n_real, rng)
+        tok = None
+        for at in range(0, int(n_real), self.tail_len):
+            n = min(self.tail_len, int(n_real) - at)
+            piece = np.zeros((1, self.bucket_of(n)), np.int32)
+            piece[0, :n] = tokens[0, at:at + n]
+            cache, tok = self.tail(params, cache, piece, slot,
+                                   np.int32(start + at), np.int32(n), rng)
+        return cache, tok

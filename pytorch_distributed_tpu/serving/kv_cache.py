@@ -313,3 +313,12 @@ class LatentCache(struct.PyTreeNode):
 
     def rollback(self, lengths) -> "LatentCache":
         return self.replace(lengths=jnp.asarray(lengths, jnp.int32))
+
+    @staticmethod
+    def paged_class():
+        """The class that holds these rows in pages (``InferenceEngine(
+        cache_kind="paged")``; down here so that ``attend`` keeps its
+        line, which Xing4.0's read kernel records)."""
+        from pytorch_distributed_tpu.serving.paging import PagedLatentCache
+
+        return PagedLatentCache

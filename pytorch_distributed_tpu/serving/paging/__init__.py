@@ -4,7 +4,8 @@ Three pieces, one discipline:
 
 * :class:`PagedKVCache` (device) — ``[L, n_pages, page_size, H, D]`` K/V
   pools + per-slot block tables, donated through the jitted serving steps
-  exactly like the slotted cache.
+  exactly like the slotted cache; :class:`PagedLatentCache` is the same
+  pool of a latent-attention model's 640-wide rows.
 * :class:`PageAllocator` (host) — free list, refcounted copy-on-write
   pages, worst-case admission reservations so an admitted sequence can
   always grow.
@@ -25,6 +26,9 @@ from pytorch_distributed_tpu.serving.paging.kv_cache import (  # noqa: F401
     PagedKVCache,
     fork_pages,
 )
+from pytorch_distributed_tpu.serving.paging.latent_cache import (  # noqa: F401
+    PagedLatentCache,
+)
 from pytorch_distributed_tpu.serving.paging.radix import (  # noqa: F401
     RadixTree,
 )
@@ -33,6 +37,7 @@ __all__ = [
     "CapacityError",
     "PageAllocator",
     "PagedKVCache",
+    "PagedLatentCache",
     "RadixTree",
     "TRASH_PAGE",
     "fork_pages",

@@ -150,6 +150,29 @@ class PagedKVCache(struct.PyTreeNode):
             k=self.k.at[layer].set(k), v=self.v.at[layer].set(v)
         )
 
+    # -- a prompt into one chain -------------------------------------------
+    def one_chain(self, slot, n_new) -> "PagedKVCache":
+        """The view a prompt (or its uncached tail) is prefilled through:
+        the pools are sequence-agnostic, so unlike the slotted cache there
+        is no per-slot slice; B=1 comes from viewing ``slot``'s table row
+        alone. (``n_new``, the tokens that are real, is what a latent
+        pool's view carries; this one's model gives every position's
+        logits.)"""
+        row = jax.lax.dynamic_slice_in_dim(self.block_tables, slot, 1, axis=0)
+        return self.replace(block_tables=row,
+                            lengths=jnp.zeros((1,), jnp.int32))
+
+    def write_chain(self, slot, view: "PagedKVCache", length
+                    ) -> "PagedKVCache":
+        """The pools as ``view`` left them, ``lengths[slot] = length``."""
+        return self.replace(k=view.k, v=view.v,
+                            lengths=self.lengths.at[slot].set(length))
+
+    def fork(self, src, dst) -> "PagedKVCache":
+        """Page ``src`` copied into ``dst`` across all layers (K and V)."""
+        return self.replace(k=self.k.at[:, dst].set(self.k[:, src]),
+                            v=self.v.at[:, dst].set(self.v[:, src]))
+
     # -- lifecycle (lengths/table bookkeeping; page ownership is host-side) -
     def evict(self, slot) -> "PagedKVCache":
         """Free a slot: zero its length AND its table row, so the slot's
@@ -185,20 +208,17 @@ class PagedKVCache(struct.PyTreeNode):
         return self.replace(lengths=jnp.asarray(lengths, jnp.int32))
 
 
-def _fork_impl(cache: PagedKVCache, src, dst) -> PagedKVCache:
-    src = jnp.asarray(src, jnp.int32)
-    dst = jnp.asarray(dst, jnp.int32)
-    return cache.replace(
-        k=cache.k.at[:, dst].set(cache.k[:, src]),
-        v=cache.v.at[:, dst].set(cache.v[:, src]),
-    )
+def _fork_impl(cache, src, dst):
+    return cache.fork(jnp.asarray(src, jnp.int32),
+                      jnp.asarray(dst, jnp.int32))
 
 
 # Module-level jitted entry point, imported by the scheduler: graftlint's
 # cross-file jit-binding resolution carries the donation spec to callers.
 fork_pages = jax.jit(_fork_impl, donate_argnums=(0,))
 fork_pages.__doc__ = """Copy-on-write fork: duplicate page ``src`` into
-``dst`` across all layers (K and V). Called before a write would land in a
+``dst`` across all layers (``cache.fork``: a ``PagedKVCache``'s K and V, a
+``PagedLatentCache``'s rows). Called before a write would land in a
 shared (refcount > 1) page — the writer re-points its table entry at
 ``dst`` and the shared original stays frozen. Donates the cache, so the
 copy is an in-place HBM page copy, not a pool realloc."""

@@ -21,6 +21,7 @@ block-table row plus refcounts.
 
 from __future__ import annotations
 
+import heapq
 from typing import Dict, List, Optional, Sequence, Tuple
 
 __all__ = ["RadixTree"]
@@ -114,15 +115,13 @@ class RadixTree:
     # -- memory pressure ---------------------------------------------------
     def _leaves(self) -> List[Tuple[int, int, _Node]]:
         out: List[Tuple[int, int, _Node]] = []
-
-        def walk(node: _Node):
-            for key, child in node.children.items():
+        stack = [self._root]
+        while stack:                 # no recursion: a chain is 200 deep
+            for key, child in stack.pop().children.items():
                 if child.children:
-                    walk(child)
+                    stack.append(child)
                 else:
                     out.append((child.last_use, key, child))
-
-        walk(self._root)
         return out
 
     def reclaim(self, allocator, n_pages: int) -> int:
@@ -130,22 +129,28 @@ class RadixTree:
         back to the free list. Only leaves whose sole reference is the
         tree's pin are touched — a leaf shared with a live sequence frees
         nothing, so detaching it would destroy future sharing for zero
-        pages. Returns pages actually freed."""
+        pages. A node whose last child went becomes a leaf itself and
+        takes its place in the order at once: a match touches a whole
+        chain, so the least recently used PROMPT goes page by page from its
+        end before a page of any other does (leaf by leaf over all prompts,
+        as until PR 54, every cached document lost its last pages to each
+        new one, and its next ask found a prefix cut short). Returns pages
+        actually freed."""
+        leaves = [(use, key, id(node), node)
+                  for use, key, node in self._leaves()]
+        heapq.heapify(leaves)
         freed = 0
-        while freed < n_pages:
-            leaves = sorted(self._leaves(), key=lambda t: (t[0], t[1]))
-            progressed = False
-            for _, key, node in leaves:
-                if freed >= n_pages:
-                    break
-                if allocator.refcount[node.page] != 1:
-                    continue
-                node.parent.children.pop(key)
-                if allocator.deref(node.page):
-                    freed += 1
-                progressed = True
-            if not progressed:
-                break  # nothing reclaimable
+        while freed < n_pages and leaves:
+            _, key, _, node = heapq.heappop(leaves)
+            if allocator.refcount[node.page] != 1:
+                continue             # and none of its ancestors is a leaf
+            parent = node.parent
+            parent.children.pop(key)
+            if allocator.deref(node.page):
+                freed += 1
+            if parent is not self._root and not parent.children:
+                heapq.heappush(leaves, (parent.last_use, hash(parent.key),
+                                        id(parent), parent))
         return freed
 
     def clear(self, allocator) -> None:

@@ -133,9 +133,8 @@ class Scheduler:
         self.tokens_per_forward = RatioTracker()  # decode tokens / forwards
         self._next_id = 0
         # paged-cache control plane (engine.cache_kind == "paged"): the
-        # allocator owns page ownership/reservations, the radix tree maps
-        # prompt prefixes to live page chains; both are host-side — the
-        # device only ever sees the resulting block tables
+        # allocator owns pages and reservations, the radix tree maps prompt
+        # prefixes to live chains; the device only sees the block tables
         if engine.cache_kind == "paged":
             self.allocator: Optional[PageAllocator] = PageAllocator(
                 n_pages=engine.n_pages, page_size=engine.page_size,
@@ -147,6 +146,7 @@ class Scheduler:
             self.radix = None
         self.prefill_tokens_total = 0   # prompt tokens across admissions
         self.prefill_tokens_cached = 0  # of those, served from the radix
+        self.pages_reclaimed = 0        # pages the radix tree gave back
         # host-clock (t0, t1, was_prefill) of the newest engine calls (16 s
         # of 3.9 ms steps; bounded, so nothing grows): what ``_waited``
         # accounts a wait from
@@ -187,8 +187,8 @@ class Scheduler:
 
         Returns the requests that completed during this step.
         """
-        with span("sched.step", step=self.steps, n_active=self._n_active,
-                  queued=len(self.queue), kv_rows=self._kv_rows):
+        with span("sched.step", n_active=self._n_active,
+                  queued=len(self.queue), **self._step_counts()):
             self.steps += 1
             return self._step()
 
@@ -376,7 +376,7 @@ class Scheduler:
         matched, need = _need()
         short = need - alloc.available_pages
         if short > 0:
-            self.radix.reclaim(alloc, short)
+            self.pages_reclaimed += self.radix.reclaim(alloc, short)
             matched, need = _need()  # reclaim may have dropped matched pages
         if need > alloc.available_pages:
             return None
@@ -584,6 +584,27 @@ class Scheduler:
                 queue_s, min(prefill_s, queue_s), min(decode_s, queue_s))
 
     # -- stats -------------------------------------------------------------
+    def _step_counts(self) -> Dict[str, int]:
+        """What ``pdt.sched.step`` says beside the queue and the active
+        slots: the step's number, the rows the active sequences hold and,
+        of a paged cache, the pages there are, those free (reservations
+        apart) and those held, the pages the radix tree gave back since the
+        step before began, and its hits and misses so far. (Down here: a
+        line above ``_step`` and ``_admit`` moves every serving program's
+        compile-cache key.)"""
+        counts = {"step": self.steps, "kv_rows": self._kv_rows}
+        if self.allocator is not None:
+            free = int(self.allocator.free_pages)
+            pages = self.allocator.n_pages - 1    # but the trash page
+            counts.update(
+                pages=pages, pages_free=free, pages_held=pages - free,
+                pages_reclaimed=self.pages_reclaimed - self._reclaimed_seen,
+                radix_hits=self.radix.hits, radix_misses=self.radix.misses)
+            self._reclaimed_seen = self.pages_reclaimed
+        return counts
+
+    _reclaimed_seen = 0
+
     @property
     def free_pages(self) -> int:
         """Admission capacity in pages — the multihost load snapshot's
@@ -624,6 +645,7 @@ class Scheduler:
             out["n_pages"] = float(self.allocator.n_pages)
             out["radix_hits"] = float(self.radix.hits)
             out["radix_misses"] = float(self.radix.misses)
+            out["pages_reclaimed"] = float(self.pages_reclaimed)
             out["prefill_tokens_total"] = float(self.prefill_tokens_total)
             out["prefill_tokens_cached"] = float(self.prefill_tokens_cached)
         return out

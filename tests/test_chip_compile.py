@@ -917,3 +917,103 @@ def test_grouped_products_lower_one_kernel_a_shape_for_v5e(
     assert [names.count(s) for s in shapes] == [traces, traces], names
     ours = [n for n in lowerings if "ops/grouped_matmul.py" in n]
     assert len(ours) == len(shapes), lowerings
+
+
+# -- a paged latent pool: sarvam-105b's cell at its real sizes ----------------
+
+def _sarvam_engine(v5e_device, monkeypatch):
+    """The engine of ``sarvam-105b.serve-doc-sessions`` (the configuration
+    file as it is: published widths, bfloat16, 5 layers, 32 of 128 experts;
+    the traffic file's slots, pages and buckets) over described shapes."""
+    from chipbench import cells
+    from chipbench.families import sarvam_mla as family
+    from pytorch_distributed_tpu.ops import decode_attention
+    from pytorch_distributed_tpu.serving import InferenceEngine
+
+    monkeypatch.setattr(decode_attention, "_platform", lambda: "tpu")
+    cell = cells.resolve(cells.load_benchmark(),
+                         "sarvam-105b.serve-doc-sessions")
+    traffic = cell.traffic
+    model = family.build_model(cell.config)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=v5e_device), tree)
+
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    engine = InferenceEngine(
+        model, params, n_slots=traffic["n_slots"], max_len=traffic["max_len"],
+        cache_kind="paged", page_size=traffic["page_size"],
+        n_pages=traffic["n_pages"], tail_len=traffic["tail_len"],
+        prefill_buckets=traffic["prefill_buckets"])
+    cache = described(jax.eval_shape(engine.init_cache))
+    return engine, described(params), cache, described(_step_rng())
+
+
+def test_paged_latent_decode_program_reads_through_the_tables_for_v5e(
+        v5e_device, monkeypatch):
+    """4.54 G parameters beside 3,584 pages of 128 x 640 in five layers
+    (2.94 GB) donated: the step keeps under a twentieth of the pool in
+    temporaries, aliases the pool to an output, and reads with five calls
+    of ONE Mosaic kernel (``latent_paged_read``)."""
+    from pytorch_distributed_tpu.analysis.ir.hlo import aliased_param_indices
+
+    engine, params, cache, rng = _sarvam_engine(v5e_device, monkeypatch)
+    assert abs(_bytes(params) - 9.07e9) < 0.01e9
+    assert cache.rows.shape == (5, 3584, 128, 640)
+    assert cache.block_tables.shape == (24, 256)
+    slots = engine.n_slots
+    compiled = engine._decode.lower(
+        params, cache,
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_device),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=v5e_device), rng,
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < _bytes(cache) / 20
+    text = compiled.as_text()
+    first = len(jax.tree_util.tree_leaves(params))
+    assert first in aliased_param_indices(text)          # the pool's rows
+    kernels = re.findall(r"[^\n]*latent_paged_read/pallas_call[^\n]*", text)
+    assert len([k for k in kernels if "tpu_custom_call" in k]) == 5
+    assert all("mla/" in k and "read_paged" in k for k in kernels)
+    assert len(_grouped_kernels(text, layers=4)) == 24
+    # no copy of the pool, nor of a layer of it
+    computations, _ = _computations(text)
+    layer = 3584 * 128 * 640
+    assert not [line for body in computations.values()
+                for _, opcode, elements, line in body
+                if opcode in ("copy", "transpose") and elements >= layer]
+
+
+@pytest.mark.parametrize("bucket,scope,room", [
+    (32768, "prefill", None), (1024, "tail", 2.5e9)])
+def test_paged_latent_prefill_buckets_fit_beside_the_pool_for_v5e(
+        v5e_device, monkeypatch, bucket, scope, room):
+    """The cold prompt's longest bucket and the tail's: each COMPILES for
+    the described chip beside 12.01 GB of weights and pool (the TPU compiler
+    refuses a program that does not fit), the cold one with little room to
+    spare (64 heads' expanded K, V and Q of 32,768 positions go four heads
+    at a time for it), and each runs its attention under its own scope:
+    the Pallas prefill kernel in the first, no kernel in the second."""
+    engine, params, cache, rng = _sarvam_engine(v5e_device, monkeypatch)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e_device)
+    compiled = engine._prefill.lower(
+        params, cache,
+        jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=v5e_device),
+        i32, i32, i32, rng).compile()
+    resident = _bytes(params) + _bytes(cache)
+    assert 12.00e9 < resident < 12.02e9
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "gqa_attention_prefill" in line]
+    if scope == "prefill":
+        assert len(kernels) == 5 * 16 and all("mla/" in k and "/prefill/"
+                                              in k for k in kernels)
+        assert "mla/layer_0_attn/tail" not in text
+    else:
+        assert not kernels and "mla/layer_0_attn/tail" in text
+        assert "/prefill/" not in text
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert resident + temp < V5E_BYTES_LIMIT - room, (resident, temp)
+    _grouped_kernels(text, layers=4)

@@ -733,6 +733,8 @@ def test_every_metric_of_the_waits_names_a_reader_and_its_cells():
     # cell 8's 8 s of trace hold 9 admissions at 1.12/s, under the tail
     # metrics' ``min_spans`` of 10: it is not on their lists
     tails = serve[:4]
+    # cell 9 (PR 54) likewise: 8 s at 1.28/s hold about ten admissions
+    serve = serve + ["sarvam-105b.serve-doc-sessions"]
     expected = {
         "ttft_wait_prefill_ms_mean": serve, "ttft_wait_decode_ms_mean": serve,
         "ttft_wait_other_ms_mean": serve, "ttft_admit_ms_mean": serve,

@@ -283,8 +283,33 @@ def test_engine_and_scheduler_churn_token_exact(served):
         assert len(done[rid]) == n
 
 
+def test_a_shared_prefix_is_served_from_pages_as_from_slots(served):
+    """``LatentCache`` names a paged twin (PR 54): the same requests through
+    ``cache_kind="paged"``, the second and third behind the first's pages,
+    give the slotted engine's tokens."""
+    model, variables = served
+    document = np.random.default_rng(3).integers(0, 97, 21)
+    prompts = [np.concatenate([document, tail]) for tail in (
+        [5, 9], [7], [5, 9, 11, 2, 40, 8, 8, 3, 1, 60])]
+
+    def tokens(**kwargs):
+        sched = Scheduler(InferenceEngine(
+            model, variables, n_slots=2, max_len=64, prefill_len=48,
+            **kwargs), emit_events=False)
+        out = []
+        for prompt in prompts:
+            sched.submit(Request(prompt=prompt, max_new_tokens=5))
+            out += [done.tokens for done in sched.run()]
+        return out, sched
+
+    slotted, _ = tokens()
+    paged, sched = tokens(cache_kind="paged", page_size=4, tail_len=8)
+    assert paged == slotted
+    assert sched.prefill_tokens_cached == 2 * 20
+    sched.allocator.check()
+
+
 @pytest.mark.parametrize("kwargs,named", [
-    (dict(cache_kind="paged"), "cache_kind='paged'"),
     (dict(spec_k=2, draft_layers=1), "spec_k > 0"),
     (dict(cache_sharding=object()), "cache_sharding"),
 ])
